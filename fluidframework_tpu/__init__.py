@@ -27,29 +27,18 @@ Architecture (TPU-first, NOT a port of the reference's TypeScript object graph):
 
 __version__ = "0.1.0"
 
-# jax<0.5 ships shard_map only under jax.experimental; every kernel module
-# calls the stable ``jax.shard_map`` spelling — alias it once here (the
-# package root imports before any submodule) so both jax generations work.
+# Persistent XLA compile cache, placeable from outside: when
+# JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and no code names
+# another directory; otherwise the cache lives in the checkout, at a path
+# that never moves (the path is part of the cache key — a temp, pid- or
+# time-derived directory would never hit). Every entry point gets this
+# through the import.
+import os as _os  # noqa: E402
+
 import jax as _jax  # noqa: E402
 
-if not hasattr(_jax, "shard_map"):
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def _compat_shard_map(*args, **kwargs):
-        # the stable API renamed check_rep -> check_vma
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    _jax.shard_map = _compat_shard_map
-
-if not hasattr(_jax.lax, "axis_size"):
-    def _axis_size(axis_name):
-        # newer jax's lax.axis_size; psum of a Python literal constant-
-        # folds to the STATIC mesh axis size on 0.4.x (usable in shapes)
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))

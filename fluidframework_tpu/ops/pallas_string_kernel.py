@@ -16,8 +16,9 @@ measures; property planes thread through untouched host-side) and props
 annotate-heavy workloads — rich text, config #5 — stay on the fused path).
 
 VMEM budget per tile: 7 planes × T×S int32 + op planes × T×O + live
-temporaries — T=128, S=384 measures fastest on v5e (2.2× the XLA scan at
-bench shapes); T=256 exceeds VMEM and fails to compile.
+temporaries — which tiles Mosaic accepts at which capacity is recorded at
+``string_store._VMEM_BUDGET_BYTES``; how fast each runs is not measured on
+the current code and machine.
 """
 
 from __future__ import annotations
@@ -139,9 +140,6 @@ def _kernel(*refs, compact: bool, n_props: int):
 
         def pick(key):
             tail = (1,) * (c[key].ndim - 1)
-            # int(): IntEnum members are not literal-eligible on older jax
-            # (exact-type check) and would be captured as kernel constants,
-            # which pallas<0.5 rejects
             is_ins = (k == int(OpKind.STR_INSERT)).reshape((-1,) + tail)
             is_rng = ((k == int(OpKind.STR_REMOVE)) |
                       (k == int(OpKind.STR_ANNOTATE))).reshape((-1,) + tail)

@@ -494,9 +494,8 @@ class ColumnarAlfred:
         self.pipeline_depth = pipeline_depth
         self.max_rx_bytes = max_rx_bytes
         self.read_chunk = read_chunk
-        if decode == "native" and not native_ingress.available():
-            raise RuntimeError("decode='native' but libingress.so "
-                               "unavailable")
+        if decode == "native":
+            native_ingress.require()   # raises the build error
         self._use_native = (native_ingress.available()
                             if decode == "auto" else decode == "native")
         self.evictions = 0

@@ -1,8 +1,8 @@
 """ctypes binding for the native C++ Deli sequencer.
 
 Same policies as ``server.deli.DeliSequencer`` (parity-tested); adds a batch
-API for the ingest hot path. Falls back to the Python sequencer when the
-native library cannot be built (``available()`` reports which one you got).
+API for the ingest hot path. ``available()`` says whether the library can be
+built here; constructing a sequencer without it raises the build error.
 """
 
 from __future__ import annotations
@@ -13,7 +13,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from ..native.build import ensure_built
+from ..native.build import NativeBuildError, ensure_built
 from ..utils.telemetry import REGISTRY
 from .deli import NackReason
 
@@ -31,10 +31,7 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    path = ensure_built("libdeli.so")
-    if path is None:
-        return None
-    lib = ctypes.CDLL(path)
+    lib = ctypes.CDLL(ensure_built("libdeli.so"))
     lib.deli_create.restype = ctypes.c_void_p
     lib.deli_destroy.argtypes = [ctypes.c_void_p]
     lib.deli_client_join.restype = ctypes.c_int64
@@ -77,7 +74,11 @@ def _load():
 
 
 def available() -> bool:
-    return _load() is not None
+    try:
+        _load()
+    except NativeBuildError:
+        return False
+    return True
 
 
 class NativeDeli:
@@ -92,8 +93,6 @@ class NativeDeli:
 
     def __init__(self, _handle=None):
         lib = _load()
-        if lib is None:
-            raise RuntimeError("native sequencer unavailable (no toolchain)")
         self._lib = lib
         self._lock = threading.Lock()
         self._h = _handle if _handle is not None else lib.deli_create()
@@ -212,8 +211,6 @@ class NativeDeli:
     @classmethod
     def restore(cls, blob: bytes) -> "NativeDeli":
         lib = _load()
-        if lib is None:
-            raise RuntimeError("native sequencer unavailable")
         h = lib.deli_restore(blob, len(blob))
         return cls(_handle=h)
 

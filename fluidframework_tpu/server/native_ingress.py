@@ -9,11 +9,9 @@ with the numpy implementations in ``columnar_ingress`` as the
 always-available fallback; same layering as ``native_deli`` /
 ``native_oplog``.
 
-``available()`` says whether the library built (and exports the expected
-symbols — the repo used to ship a stale ``libingress.so`` that nothing
-loaded; a symbol check keeps an old artifact from masquerading as the
-fast path). ``scan``/``gather`` raise RuntimeError when called without
-it; callers gate on ``available()``.
+``available()`` says whether the library can be built here;
+``scan``/``gather`` raise the build error when called without it, so
+callers gate on ``available()``.
 """
 
 from __future__ import annotations
@@ -23,10 +21,9 @@ from typing import List, Tuple
 
 import numpy as np
 
-from ..native.build import ensure_built
+from ..native.build import NativeBuildError, ensure_built
 
 _lib = None
-_tried = False
 
 #: defensive bound on one frame's payload (matches wire.MAX_FRAME)
 MAX_PAYLOAD = 64 * 1024 * 1024
@@ -40,33 +37,34 @@ _I32P = ctypes.POINTER(ctypes.c_int32)
 
 
 def _load():
-    global _lib, _tried
-    if _tried:
+    global _lib
+    if _lib is not None:
         return _lib
-    _tried = True
-    path = ensure_built("libingress.so")
-    if path is None:
-        return None
-    try:
-        lib = ctypes.CDLL(path)
-        lib.ingress_scan.restype = None
-        lib.ingress_scan.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p, _I64P, _I64P, _I32P]
-        lib.ingress_gather.restype = None
-        lib.ingress_gather.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-            ctypes.c_void_p] + [ctypes.c_void_p] * 7
-    except (OSError, AttributeError):
-        # stale/foreign .so without our symbols: numpy tier serves
-        return None
+    lib = ctypes.CDLL(ensure_built("libingress.so"))
+    lib.ingress_scan.restype = None
+    lib.ingress_scan.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, _I64P, _I64P, _I32P]
+    lib.ingress_gather.restype = None
+    lib.ingress_gather.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_void_p] + [ctypes.c_void_p] * 7
     _lib = lib
     return lib
 
 
 def available() -> bool:
-    return _load() is not None
+    try:
+        _load()
+    except NativeBuildError:
+        return False
+    return True
+
+
+def require() -> None:
+    """Raise the build error unless the library loads (``decode="native"``)."""
+    _load()
 
 
 def scan(buf) -> Tuple[List[Tuple[int, int, int]], int, int]:
@@ -80,8 +78,6 @@ def scan(buf) -> Tuple[List[Tuple[int, int, int]], int, int]:
     returned. Contract (and fallback) live in
     ``columnar_ingress.split_frames``."""
     lib = _load()
-    if lib is None:
-        raise RuntimeError("native ingress library unavailable")
     arr = np.frombuffer(buf, np.uint8)
     n = arr.size
     cap = n // 9 + 1  # min frame = 5B header + 4B crc
@@ -109,8 +105,6 @@ def gather(buf, runs: List[Tuple[int, int]]) -> dict:
     op frame, in frame order) into seven contiguous int32 planes.
     Returns ``{"row", "kind", "a0", "a1", "tidx", "cseq", "ref"}``."""
     lib = _load()
-    if lib is None:
-        raise RuntimeError("native ingress library unavailable")
     arr = np.frombuffer(buf, np.uint8)
     roff = np.array([r[0] for r in runs], np.int64)
     rcnt = np.array([r[1] for r in runs], np.int64)

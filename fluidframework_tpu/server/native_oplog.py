@@ -9,8 +9,9 @@ same API as ``oplog.PartitionedLog`` so the serving engines can take either
 (``NativePartitionedLog`` survives process crashes; the Python log is
 in-memory with optional JSONL spill).
 
-Falls back to nothing: ``available()`` says whether the library built; the
-serving engines default to the Python log.
+Falls back to nothing: ``available()`` says whether the library can be
+built here, opening a log without it raises the build error; the serving
+engines default to the Python log.
 """
 
 from __future__ import annotations
@@ -21,7 +22,7 @@ import struct
 from typing import Any, Callable, List, Optional
 
 from ..core.protocol import MessageType, SequencedDocumentMessage
-from ..native.build import ensure_built
+from ..native.build import NativeBuildError, ensure_built
 from ..utils.telemetry import REGISTRY
 from .oplog import (
     FencedWriterError, OplogCorruptionError, _FencedWriter, chain_step,
@@ -34,10 +35,7 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    path = ensure_built("liboplog.so")
-    if path is None:
-        return None
-    lib = ctypes.CDLL(path)
+    lib = ctypes.CDLL(ensure_built("liboplog.so"))
     lib.oplog_open.restype = ctypes.c_void_p
     lib.oplog_open.argtypes = [ctypes.c_char_p, ctypes.c_int32]
     lib.oplog_close.argtypes = [ctypes.c_void_p]
@@ -61,7 +59,11 @@ def _load():
 
 
 def available() -> bool:
-    return _load() is not None
+    try:
+        _load()
+    except NativeBuildError:
+        return False
+    return True
 
 
 def _is_columnar(record: Any) -> bool:
@@ -303,8 +305,6 @@ class NativePartitionedLog:
     def __init__(self, directory: str, n_partitions: int = 8,
                  verify: bool = True):
         lib = _load()
-        if lib is None:
-            raise RuntimeError("native oplog library unavailable")
         import os
         os.makedirs(directory, exist_ok=True)
         self._lib = lib

@@ -25,9 +25,9 @@ Three cooperating pieces:
 * device census — :func:`device_census` walks ``jax.live_arrays()``
   for the ground-truth HBM/backend-buffer total (the acceptance test
   pins ledger device totals to this number *exactly*) and reads the
-  global pjit compile-cache occupancy through a guarded private-API
-  probe (entry counts are available; jaxlib does not expose per-entry
-  bytes — reported as ``None``, never guessed).
+  global pjit compile-cache occupancy from jax's C++ pjit caches
+  (entry counts; jaxlib does not expose per-entry bytes — reported as
+  ``None``, never guessed).
 
 * :class:`IdleAgeTracker` — a monotonic last-touch clock per doc row.
   Both ingress doors touch it from their drain passes with ONE
@@ -159,29 +159,16 @@ def device_nbytes(tree: Any) -> int:
 
 
 def compile_cache_stats() -> Dict[str, Any]:
-    """Global pjit executable-cache occupancy.
-
-    Entry counts come from the private C++ cache objects (guarded —
-    any jaxlib that renames them degrades to zeros, never raises).
-    jaxlib exposes no per-entry byte size, so ``bytes`` is reported
-    as ``None`` rather than a fabricated number."""
-    entries = 0
-    capacity = 0
-    available = False
-    try:
-        from jax._src import pjit as _pjit
-        for attr in ("_cpp_pjit_cache_fun_only",
-                     "_cpp_pjit_cache_explicit_attributes"):
-            cache = getattr(_pjit, attr, None)
-            if cache is None:
-                continue
-            entries += int(cache.size())
-            capacity += int(cache.capacity())
-            available = True
-    except Exception:
-        available = False
-    return {"available": available, "entries": entries,
-            "capacity": capacity, "bytes": None}
+    """Global pjit executable-cache occupancy, read from jax's two C++
+    pjit caches. jaxlib exposes no per-entry byte size, so ``bytes`` is
+    reported as ``None`` rather than a fabricated number."""
+    from jax._src import pjit as _pjit
+    caches = (_pjit._cpp_pjit_cache_fun_only,
+              _pjit._cpp_pjit_cache_explicit_attributes)
+    return {"available": True,
+            "entries": sum(int(c.size()) for c in caches),
+            "capacity": sum(int(c.capacity()) for c in caches),
+            "bytes": None}
 
 
 def device_census() -> Dict[str, Any]:

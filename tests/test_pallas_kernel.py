@@ -1,5 +1,6 @@
 """Pallas VMEM-resident merge kernel vs the XLA scan path (interpret mode on
-the CPU mesh; the real-TPU run is covered by bench.py and the driver)."""
+the CPU mesh; the compiled kernel is checked against the scan on the chip
+by ``chip_smoke.py``)."""
 
 import numpy as np
 import pytest

@@ -77,15 +77,14 @@ def test_graft_entry_and_dryrun():  # driver runs dryrun_multichip itself
     import os
     import subprocess
     import sys
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
                           + " --xla_force_host_platform_device_count=8")
                .strip())
     for n in (8, 4):
         proc = subprocess.run(
             [sys.executable, "-c",
-             "import __graft_entry__ as g; "
-             f"g._ensure_virtual_devices({n}); g.dryrun_multichip({n})"],
+             f"import __graft_entry__ as g; g.dryrun_multichip({n})"],
             cwd=os.path.dirname(os.path.dirname(
                 os.path.abspath(__file__))),
             env=env, capture_output=True, text=True,

@@ -10,13 +10,13 @@ records. This tool is the mechanical judge (ISSUE 4 tentpole piece 4):
   shape-tolerant ``load_record``),
 - for each scalar metric in the NEWEST round, compare against the median
   of the prior rounds, with a variance band wide enough for the known
-  tunnel noise: ``band = max(rel_band·|median|, k_sigma·stdev(priors))``
+  round-to-round noise: ``band = max(rel_band·|median|, k_sigma·stdev(priors))``
   (defaults 10% / 3σ — the committed r01–r05 swings, including the −12%
   conflict-throughput dip, sit inside it; a real cliff does not),
 - emit one verdict per metric: ``regress`` / ``improve`` / ``flat``
   (plus ``new`` for metrics without enough history and ``info`` for
   metrics that must never fail the build — worst-case single samples,
-  environmental RTT, config constants),
+  the old records' dispatch round trip, config constants),
 - exit nonzero iff any metric regressed beyond its band.
 
 Direction is inferred from the name (``*ops_per_sec*`` up is good,
@@ -64,8 +64,9 @@ LOWER_BETTER_SUFFIXES = ("_ms", "_retries", "_round_trips", "_stalled")
 #: booleans that must stay truthy once they have held for >=1 prior round
 MUST_HOLD = {"digest_parity", "conflict_parity"}
 #: never-failing metrics: worst-case single samples are outliers by
-#: construction (the committed r05 carries a known 983 ms stall), RTT is
-#: the tunnel's property not the code's, and config constants are inputs
+#: construction (the committed r05 carries a known 983 ms stall), the
+#: r01–r05 records' dispatch round trip was their link's property not
+#: the code's, and config constants are inputs
 INFO_PATTERNS = ("worst",)
 INFO_EXACT = {"dispatch_rtt_ms", "docs", "total_ops", "contended"}
 
@@ -117,8 +118,7 @@ FLOOR_DECLARED_ROUND: Dict[str, int] = {
 #: headline moved 7.98M → 7.28M ops/s (−8.8%) with no change on the
 #: kernel path. That sits INSIDE the 10% rel_band by design: the
 #: per-suite ``headline_trials`` of a single record spread up to ~±15%
-#: (see ``headline_variance_band.spread_pct``) under test-tunnel
-#: latency noise, so a cross-round drift smaller than one record's own
+#: (see ``headline_variance_band.spread_pct``), so a cross-round drift smaller than one record's own
 #: in-run spread is noise, not regression. Compare
 #: ``headline_variance_band.median`` across rounds — not the
 #: best-of-suite ``value`` — before reading a drift as real.
