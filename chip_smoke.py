@@ -7,8 +7,9 @@ the native libraries from the committed ``.cpp``, serves 10,240 docs ×
 capacity 512 behind ``ColumnarAlfred`` to 8 socket clients, and checks
 what comes out against the Python oracle, a summary reload and the XLA
 scan (``fluidframework_tpu/testing/door_smoke.py`` is the body; tier-1
-runs it tiny on the CPU). The last line of stdout is one JSON object of
-smoke observations; exit 0 only if every assertion held.
+runs it tiny on the CPU). Stdout is two JSON lines: the smoke's
+observations, then ``{"ok": true, "device": {...}}`` and nothing else on
+the last line; exit 0 only if every assertion held.
 """
 
 import argparse
@@ -59,8 +60,8 @@ def main() -> int:
         used = [m["bytes_in_use"] for m in found["device_memory"]]
         assert max(used) <= 2 * min(used), \
             f"state not spread evenly over the mesh: bytes_in_use {used}"
-    print(json.dumps({"ok": True, "device": device, "versions": versions,
-                      "seed": args.seed, **found}))
+    print(json.dumps({"versions": versions, "seed": args.seed, **found}))
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

@@ -44,7 +44,7 @@ def test_door_smoke_body_tiny_interpret(tmp_path):
     c = found["compile"]
     assert c["store_jax_compiles"] >= c["unpack_programs"] \
         >= c["unpack_distinct_R"] >= 1
-    json.dumps(found)       # the root script prints it as one JSON line
+    json.dumps(found)       # the root script prints it as a JSON line
 
 
 def test_make_sequencer_native_is_native_or_an_error(monkeypatch):
