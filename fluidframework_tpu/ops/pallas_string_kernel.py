@@ -203,10 +203,14 @@ def apply_string_batch_pallas(state: StringState, kind, a0, a1, a2, seq,
     aliases = {n_lead + _OPS + i: i for i in range(np_ + 2)}
     lead = (jnp.asarray(min_seq, jnp.int32)[:, None],) if compact else ()
     prop_in = tuple(state.prop_val[:, :, i] for i in range(K))
+    # a stable name by variant, so that a device trace tells the plain
+    # merge from the one with the zamboni fused in
+    name = "string_merge" + ("_zamboni" if compact else "") \
+        + ("_props" if with_props else "")
     outs = pl.pallas_call(
         functools.partial(_kernel, compact=compact, n_props=K),
         grid_spec=grid_spec, out_shape=out_shape,
-        input_output_aliases=aliases, interpret=interpret,
+        input_output_aliases=aliases, interpret=interpret, name=name,
     )(*lead, kind, a0, a1, a2, seq, client, ref_seq,
       *(getattr(state, k) for k in _PLANES), *prop_in,
       state.count[:, None], state.overflow[:, None])

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.constants import NOT_REMOVED
+from ..utils import tracing
 from ..utils.telemetry import REGISTRY
 from .merge_tree_kernel import (
     MAX_CLIENTS, PROP_HANDLE_BITS, StringState, _PLANES, apply_string_batch,
@@ -309,10 +310,12 @@ def _columnar_merge_jit(state, planes, min_seq, use_pallas, tile,
             state, *planes, tile=tile, interpret=interpret,
             min_seq=min_seq if fuse_compact else None,
             with_props=with_props)
-    out = apply_string_batch(state, *planes, with_props=with_props)
+    with jax.named_scope("string_merge"):
+        out = apply_string_batch(state, *planes, with_props=with_props)
     if fuse_compact:
         from .merge_tree_kernel import compact_string_state
-        out = compact_string_state(out, min_seq, with_props)
+        with jax.named_scope("string_zamboni"):
+            out = compact_string_state(out, min_seq, with_props)
     return out
 
 
@@ -896,7 +899,8 @@ class TensorStringStore(StringOpInterner):
 
     def apply_planes(self, rows, kind, a0, a1, seq_base, client_id, ref_seq,
                      text: str = "", min_seq=None, texts=None, tidx=None,
-                     props=None, min_ops=None, prepacked=None) -> None:
+                     props=None, min_ops=None, prepacked=None,
+                     rec: Optional[dict] = None) -> None:
         """Columnar apply: dense (R, O) already-sequenced op planes for the
         subset of doc rows ``rows`` (R,) — the ingest hot path (no per-op
         Python objects anywhere). Ops per doc apply in column order (the
@@ -932,270 +936,287 @@ class TensorStringStore(StringOpInterner):
         the device state AT the crossing, via one fused gather for every
         crossing doc. Without ``min_ops`` the floor is assumed not to
         advance inside the batch (removes still feed the tombstone heaps,
-        so a later ``advance_min_seq``/``compact`` slides correctly)."""
-        _t0 = time.perf_counter()
-        rows = np.ascontiguousarray(rows, np.int32)
-        R, O = kind.shape
-        if len(np.unique(rows)) != R:
-            raise ValueError("duplicate rows in columnar batch (the device "
-                             "scatter would silently drop ops)")
-        kind = np.asarray(kind, np.int32)
-        ins = kind == int(OpKind.STR_INSERT)
-        a0 = np.asarray(a0, np.int32)
-        a1 = np.asarray(a1, np.int32)
-        # payload/props side of the pack: either handed in by the
-        # pipelined executor's pack worker (``prepacked``, built
-        # concurrent with the previous wave's dispatch) or built inline
-        # right here — identical code either way (_pack_payload_tables)
-        pp = prepacked
-        if pp is None:
-            pp = self._pack_payload_tables(rows, kind, a0, a1, text,
-                                           texts, tidx, props)
-        rich = pp.rich
-        rich_mode = pp.rich_mode
-        a2_np = pp.a2_np
-        tab_a2, tab_len, tab_n = pp.tab_a2, pp.tab_len, pp.tab_n
-        tidx_eff = pp.tidx_eff
-        a1 = pp.a1
+        so a later ``advance_min_seq``/``compact`` slides correctly).
 
-        # vectorized client interning. Fast path: one writer per doc row in
-        # this batch (the common live-collaboration window) — R dict hits,
-        # no materialized (R·O) key array — with a one-entry cache: steady
-        # serving re-presents the SAME (rows, client) pairing every batch,
-        # which a memcmp detects without touching the dicts. General path:
-        # one dict hit per UNIQUE (row, client) pair via a packed int64 key
-        # (np.unique on a 1-D int key is ~10× faster than axis=0 row
-        # dedup); nacked/NOOP slots never mint an index there.
-        valid = kind != int(OpKind.NOOP)
-        cidx = np.zeros((R, O), np.int32)
-        cid = np.asarray(client_id, np.int32)
-        cmax = 0
-        if (cid == cid[:, :1]).all():
-            cid0 = np.ascontiguousarray(cid[:, 0])
-            rkey, ckey = rows.tobytes(), cid0.tobytes()
-            cached = self._cidx_cache
-            rows_any = valid.any(axis=1)
-            all_rows_valid = bool(rows_any.all())
-            if cached is not None and all_rows_valid \
-                    and cached[0] == rkey and cached[1] == ckey:
-                lut = cached[2]
-            else:
-                # mint only for rows with at least one acked op (an
-                # all-NOOP row must not consume one of the doc's
-                # MAX_CLIENTS slots — and must match what a log rebuild
-                # would intern)
-                lut = np.zeros(R, np.int32)
-                mint = self._client
-                rows_l, cid_l = rows.tolist(), cid0.tolist()
-                for i in map(int, np.flatnonzero(rows_any)):
-                    lut[i] = mint(rows_l[i], cid_l[i])
-                if all_rows_valid:
-                    self._cidx_cache = (rkey, ckey, lut)
-            cidx[:] = lut[:, None]
-            cmax = int(lut.max(initial=0))
-        elif valid.any():
-            rr = np.broadcast_to(rows[:, None], (R, O))[valid]
-            cc = cid.astype(np.int64)[valid]
-            key = (rr.astype(np.int64) << 32) | (cc & 0xFFFFFFFF)
-            uniq, inv = np.unique(key, return_inverse=True)
-            lut = np.array(
-                [self._client(int(k >> 32), int(np.int32(k & 0xFFFFFFFF)))
-                 for k in uniq], np.int32)
-            cidx[valid] = lut[inv]
-            cmax = int(lut.max(initial=0))
+        ``rec``: the window's record (``utils.tracing``) that the spans
+        of this call are stamped into; a fresh one without."""
+        if rec is None:
+            rec = tracing.new_record()
+        with tracing.stage(rec, "store.apply_planes"):
+            # host pack: from here to the first upload, then once more for
+            # each later segment (entered by hand: the span ends inside the
+            # loop below; an exception in between ends the whole apply)
+            pack = tracing.stage(rec, "store.pack")
+            pack.__enter__()
+            rows = np.ascontiguousarray(rows, np.int32)
+            R, O = kind.shape
+            if len(np.unique(rows)) != R:
+                raise ValueError("duplicate rows in columnar batch (the device "
+                                 "scatter would silently drop ops)")
+            kind = np.asarray(kind, np.int32)
+            ins = kind == int(OpKind.STR_INSERT)
+            a0 = np.asarray(a0, np.int32)
+            a1 = np.asarray(a1, np.int32)
+            # payload/props side of the pack: either handed in by the
+            # pipelined executor's pack worker (``prepacked``, built
+            # concurrent with the previous wave's dispatch) or built inline
+            # right here — identical code either way (_pack_payload_tables)
+            pp = prepacked
+            if pp is None:
+                pp = self._pack_payload_tables(rows, kind, a0, a1, text,
+                                               texts, tidx, props)
+            rich = pp.rich
+            rich_mode = pp.rich_mode
+            a2_np = pp.a2_np
+            tab_a2, tab_len, tab_n = pp.tab_a2, pp.tab_len, pp.tab_n
+            tidx_eff = pp.tidx_eff
+            a1 = pp.a1
 
-        # unsigned u16 packing would alias a (malformed) negative position
-        # to ~65535 — minima force such inputs onto the sign-preserving
-        # wide path, where they behave exactly like the per-op path
-        narrow = int(a0.max(initial=0)) < 32767 and \
-            int(a1.max(initial=0)) < 32767 and \
-            int(a0.min(initial=0)) >= 0 and int(a1.min(initial=0)) >= 0
-        seq_base = np.asarray(seq_base, np.int32)
-        seq = seq_base[:, None] + np.cumsum(valid, axis=1, dtype=np.int32)
-        lag = np.subtract(seq, np.asarray(ref_seq, np.int32))
-        np.maximum(lag, 1, out=lag)
-        ref_wide = bool((lag > 65535).any())
-        use_pallas, tile, interpret = self._pallas_choice()
-        scatter_rows = not (R == self.n_docs
-                            and np.array_equal(rows, np.arange(R)))
-        fuse = min_seq is not None and not self._iv_docs
-        ms = np.asarray(min_seq, np.int32) if fuse \
-            else np.zeros((1,), np.int32)
-        # tightest profile first: 5 B/op when spans, lags and client
-        # indexes all fit a byte (the live-collaboration common case —
-        # see _columnar_unpack_jit on why wire bytes are the ceiling).
-        # (kind-set membership via compares, not np.isin — isin costs ~8 ms
-        # at 655k ops for the same answer)
-        span = np.where(ins, a1, a1 - a0) if rich_mode < 2 \
-            else np.where(ins, 0, a1 - a0)
-        kinds_ok = bool(((kind >= 0) & ((kind <= int(OpKind.STR_ANNOTATE))
-                                        | ~valid)).all())
-        compact8 = bool(
-            narrow and not ref_wide and kinds_ok
-            and cmax < 64
-            and int(lag.max(initial=0)) < 256
-            and int(span.max(initial=0)) < 256
-            and int(span.min(initial=0)) >= 0)
-        # observability: which wire profile this batch took (head encoding,
-        # position width, payload form) — tests pin each branch by name;
-        # the rich payload's wire form (plane vs table) rides separately
-        self.last_profile = (
-            "compact8" if compact8 else
-            "ref_wide" if ref_wide else "lag16",
-            "pos16" if narrow else "pos32",
-            "rich" if rich else "broadcast")
-        self.last_rich_wire = (None if not rich else
-                               {1: "plane", 2: "tab8", 3: "tab16"}
-                               [rich_mode])
+            # vectorized client interning. Fast path: one writer per doc row in
+            # this batch (the common live-collaboration window) — R dict hits,
+            # no materialized (R·O) key array — with a one-entry cache: steady
+            # serving re-presents the SAME (rows, client) pairing every batch,
+            # which a memcmp detects without touching the dicts. General path:
+            # one dict hit per UNIQUE (row, client) pair via a packed int64 key
+            # (np.unique on a 1-D int key is ~10× faster than axis=0 row
+            # dedup); nacked/NOOP slots never mint an index there.
+            valid = kind != int(OpKind.NOOP)
+            cidx = np.zeros((R, O), np.int32)
+            cid = np.asarray(client_id, np.int32)
+            cmax = 0
+            if (cid == cid[:, :1]).all():
+                cid0 = np.ascontiguousarray(cid[:, 0])
+                rkey, ckey = rows.tobytes(), cid0.tobytes()
+                cached = self._cidx_cache
+                rows_any = valid.any(axis=1)
+                all_rows_valid = bool(rows_any.all())
+                if cached is not None and all_rows_valid \
+                        and cached[0] == rkey and cached[1] == ckey:
+                    lut = cached[2]
+                else:
+                    # mint only for rows with at least one acked op (an
+                    # all-NOOP row must not consume one of the doc's
+                    # MAX_CLIENTS slots — and must match what a log rebuild
+                    # would intern)
+                    lut = np.zeros(R, np.int32)
+                    mint = self._client
+                    rows_l, cid_l = rows.tolist(), cid0.tolist()
+                    for i in map(int, np.flatnonzero(rows_any)):
+                        lut[i] = mint(rows_l[i], cid_l[i])
+                    if all_rows_valid:
+                        self._cidx_cache = (rkey, ckey, lut)
+                cidx[:] = lut[:, None]
+                cmax = int(lut.max(initial=0))
+            elif valid.any():
+                rr = np.broadcast_to(rows[:, None], (R, O))[valid]
+                cc = cid.astype(np.int64)[valid]
+                key = (rr.astype(np.int64) << 32) | (cc & 0xFFFFFFFF)
+                uniq, inv = np.unique(key, return_inverse=True)
+                lut = np.array(
+                    [self._client(int(k >> 32), int(np.int32(k & 0xFFFFFFFF)))
+                     for k in uniq], np.int32)
+                cidx[valid] = lut[inv]
+                cmax = int(lut.max(initial=0))
 
-        # interval crossing scan: split the batch at every column where a
-        # doc's window floor crosses a pending tombstone (mirrors the
-        # apply_messages per-message bookkeeping; mutates the heaps/floors)
-        segments = [(0, O, ())]
-        if self._iv_docs:
-            if min_ops is not None:
-                min_ops = np.asarray(min_ops)
-            splits = self._interval_scan(rows, kind, seq, min_ops)
-            if splits:
-                segs, prev = [], 0
-                for b in sorted(splits):
-                    segs.append((prev, b, splits[b]))
-                    prev = b
-                if prev < O:
-                    segs.append((prev, O, ()))
-                segments = segs
+            # unsigned u16 packing would alias a (malformed) negative position
+            # to ~65535 — minima force such inputs onto the sign-preserving
+            # wide path, where they behave exactly like the per-op path
+            narrow = int(a0.max(initial=0)) < 32767 and \
+                int(a1.max(initial=0)) < 32767 and \
+                int(a0.min(initial=0)) >= 0 and int(a1.min(initial=0)) >= 0
+            seq_base = np.asarray(seq_base, np.int32)
+            seq = seq_base[:, None] + np.cumsum(valid, axis=1, dtype=np.int32)
+            lag = np.subtract(seq, np.asarray(ref_seq, np.int32))
+            np.maximum(lag, 1, out=lag)
+            ref_wide = bool((lag > 65535).any())
+            use_pallas, tile, interpret = self._pallas_choice()
+            scatter_rows = not (R == self.n_docs
+                                and np.array_equal(rows, np.arange(R)))
+            fuse = min_seq is not None and not self._iv_docs
+            ms = np.asarray(min_seq, np.int32) if fuse \
+                else np.zeros((1,), np.int32)
+            # tightest profile first: 5 B/op when spans, lags and client
+            # indexes all fit a byte (the live-collaboration common case —
+            # see _columnar_unpack_jit on why wire bytes are the ceiling).
+            # (kind-set membership via compares, not np.isin — isin costs ~8 ms
+            # at 655k ops for the same answer)
+            span = np.where(ins, a1, a1 - a0) if rich_mode < 2 \
+                else np.where(ins, 0, a1 - a0)
+            kinds_ok = bool(((kind >= 0) & ((kind <= int(OpKind.STR_ANNOTATE))
+                                            | ~valid)).all())
+            compact8 = bool(
+                narrow and not ref_wide and kinds_ok
+                and cmax < 64
+                and int(lag.max(initial=0)) < 256
+                and int(span.max(initial=0)) < 256
+                and int(span.min(initial=0)) >= 0)
+            # observability: which wire profile this batch took (head encoding,
+            # position width, payload form) — tests pin each branch by name;
+            # the rich payload's wire form (plane vs table) rides separately
+            self.last_profile = (
+                "compact8" if compact8 else
+                "ref_wide" if ref_wide else "lag16",
+                "pos16" if narrow else "pos32",
+                "rich" if rich else "broadcast")
+            self.last_rich_wire = (None if not rich else
+                                   {1: "plane", 2: "tab8", 3: "tab16"}
+                                   [rich_mode])
 
-        # word-pack EVERYTHING into one int32 buffer: each transfer pays
-        # a fixed per-transfer overhead, so the whole batch (planes +
-        # rows + seq bases + fused min_seq) rides ONE host→device copy
-        # at ~8 B/op (see _columnar_unpack_jit)
-        def seg_u8(arr):
-            b = np.ascontiguousarray(arr, np.uint8).reshape(-1)
-            if len(b) % 4:
-                b = np.concatenate([b, np.zeros((-len(b)) % 4, np.uint8)])
-            return b.view("<i4")
+            # interval crossing scan: split the batch at every column where a
+            # doc's window floor crosses a pending tombstone (mirrors the
+            # apply_messages per-message bookkeeping; mutates the heaps/floors)
+            segments = [(0, O, ())]
+            if self._iv_docs:
+                if min_ops is not None:
+                    min_ops = np.asarray(min_ops)
+                splits = self._interval_scan(rows, kind, seq, min_ops)
+                if splits:
+                    segs, prev = [], 0
+                    for b in sorted(splits):
+                        segs.append((prev, b, splits[b]))
+                        prev = b
+                    if prev < O:
+                        segs.append((prev, O, ()))
+                    segments = segs
 
-        def seg_u16(arr):
-            b = np.ascontiguousarray(arr, "<u2").reshape(-1)
-            if len(b) % 2:
-                b = np.concatenate([b, np.zeros(1, "<u2")])
-            return b.view("<i4")
+            # word-pack EVERYTHING into one int32 buffer: each transfer pays
+            # a fixed per-transfer overhead, so the whole batch (planes +
+            # rows + seq bases + fused min_seq) rides ONE host→device copy
+            # at ~8 B/op (see _columnar_unpack_jit)
+            def seg_u8(arr):
+                b = np.ascontiguousarray(arr, np.uint8).reshape(-1)
+                if len(b) % 4:
+                    b = np.concatenate([b, np.zeros((-len(b)) % 4, np.uint8)])
+                return b.view("<i4")
 
-        seg_pos = (lambda a: np.ascontiguousarray(a, "<i4").reshape(-1)) \
-            if not narrow else seg_u16
+            def seg_u16(arr):
+                b = np.ascontiguousarray(arr, "<u2").reshape(-1)
+                if len(b) % 2:
+                    b = np.concatenate([b, np.zeros(1, "<u2")])
+                return b.view("<i4")
 
-        def pad_cols(arr, c0, c1, wp, fill=0):
-            """Column slice padded to the wp bucket (NOOP-filled pads
-            consume no seq and touch no state)."""
-            w = c1 - c0
-            if c0 == 0 and c1 == O and wp == O:
-                return arr
-            out = np.full((R, wp), fill, np.int32)
-            out[:, :w] = arr[:, c0:c1]
-            return out
+            seg_pos = (lambda a: np.ascontiguousarray(a, "<i4").reshape(-1)) \
+                if not narrow else seg_u16
 
-        ref_i32 = None
-        if ref_wide:
-            ref_i32 = np.ascontiguousarray(ref_seq, "<i4")
+            def pad_cols(arr, c0, c1, wp, fill=0):
+                """Column slice padded to the wp bucket (NOOP-filled pads
+                consume no seq and touch no state)."""
+                w = c1 - c0
+                if c0 == 0 and c1 == O and wp == O:
+                    return arr
+                out = np.full((R, wp), fill, np.int32)
+                out[:, :w] = arr[:, c0:c1]
+                return out
 
-        pack_ms = 0.0
-        dispatch_ms = 0.0
-        _t_prep = time.perf_counter()
-        for si, (c0, c1, slides) in enumerate(segments):
-            _t_s0 = time.perf_counter()
-            last_seg = si == len(segments) - 1
-            fuse_seg = fuse and last_seg
-            ms_seg = ms if fuse_seg else np.zeros((1,), np.int32)
-            w = c1 - c0
-            # power-of-two column buckets keep the jit cache warm when a
-            # crossing splits the batch (the no-split common case keeps
-            # the exact original shape)
-            wp = O if w == O else max(8, 1 << (w - 1).bit_length())
-            k_s = pad_cols(kind, c0, c1, wp, fill=int(OpKind.NOOP))
-            a0_s = pad_cols(a0, c0, c1, wp)
-            lag_s = pad_cols(lag, c0, c1, wp, fill=1)
-            cidx_s = pad_cols(cidx, c0, c1, wp)
-            base_s = seq_base if c0 == 0 else \
-                np.ascontiguousarray(seq[:, c0 - 1])
-            if compact8:
-                span_s = pad_cols(span, c0, c1, wp)
-                kc = np.where(k_s == int(OpKind.NOOP), 3, k_s) \
-                    | (cidx_s << 2)
-                head = [seg_u8(kc), seg_u16(a0_s), seg_u8(span_s),
-                        seg_u8(lag_s)]
-            elif ref_wide:
-                head = [seg_u8(k_s), seg_u8(cidx_s), seg_pos(a0_s),
-                        seg_pos(pad_cols(a1, c0, c1, wp)),
-                        pad_cols(ref_i32, c0, c1, wp).reshape(-1)
-                        .astype("<i4", copy=False)]
-            else:  # ship the (u16) lag; device reconstructs ref=seq-lag
-                head = [seg_u8(k_s), seg_u8(cidx_s), seg_pos(a0_s),
-                        seg_pos(pad_cols(a1, c0, c1, wp)),
-                        seg_u16(lag_s)]
-            if rich_mode >= 2:
-                tail = [(seg_u8 if rich_mode == 2 else seg_u16)(
-                            pad_cols(tidx_eff, c0, c1, wp)),
-                        tab_a2.astype("<i4", copy=False),
-                        tab_len.astype("<i4", copy=False)]
-            elif rich_mode == 1:
-                tail = [np.ascontiguousarray(
-                    pad_cols(a2_np, c0, c1, wp), "<i4").reshape(-1)]
-            else:
-                tail = [a2_np.astype("<i4", copy=False)]
-            buf = np.concatenate(head + tail + [
-                base_s.astype("<i4", copy=False),
-                rows.astype("<i4", copy=False),
-                ms_seg.astype("<i4", copy=False),
-            ])
-            _t_pack = time.perf_counter()
-            variant = dict(R=R, O=wp, pos_wide=not narrow,
-                           ref_wide=ref_wide, rich=rich_mode,
-                           n_docs=self.n_docs, fuse_compact=fuse_seg,
-                           scatter_rows=scatter_rows, compact8=compact8,
-                           tab_n=tab_n)
-            self.unpack_variants.add(tuple(variant.values()))
-            planes, ms_dev = _columnar_unpack_jit(jnp.asarray(buf),
-                                                  **variant)
-            if self.mesh is not None:
-                # planes are (n_docs, O) either way: subset batches
-                # scattered by the unpack, full-store batches already in
-                # row order
-                from ..parallel.sharded import sharded_merge
-                fn = sharded_merge(self.mesh, use_pallas, tile, interpret,
-                                   self._has_props, fuse_seg)
-                self.state = fn(self.state, planes, ms_dev) if fuse_seg \
-                    else fn(self.state, planes)
-            else:
-                self.state = _columnar_merge_jit(
-                    self.state, planes, ms_dev, use_pallas=use_pallas,
-                    tile=tile, interpret=interpret,
-                    with_props=self._has_props, fuse_compact=fuse_seg)
-            _t_done = time.perf_counter()
-            pack_ms += (_t_pack - _t_s0) * 1000
-            dispatch_ms += (_t_done - _t_pack) * 1000
-            if slides:
-                # re-anchor the crossing docs off the device state AS OF
-                # this segment's end — one fused gather for all of them
-                # (the gather also drains the dispatch pipeline, so the
-                # planes it returns include this segment's ops)
-                self._slide_docs(slides)
-        self._tab_release(pp)
-        #: host-packing vs device-dispatch wall per columnar apply — the
-        #: breakdown behind the serving throughput number (dispatches are
-        #: async; device time is measured by the caller's end sync).
-        #: ``prepack_ms`` is the payload/table build wall: when the wave
-        #: came through the pipelined executor that work ran OFF the
-        #: critical path (concurrent with the previous wave's dispatch)
-        #: and pack_ms counts only the inline remainder.
-        self.last_apply_stats = {
-            "pack_ms": (_t_prep - _t0) * 1000 + pack_ms,
-            "prepack_ms": pp.prep_ms if prepacked is not None else 0.0,
-            "dispatch_ms": dispatch_ms,
-            "segments": len(segments),
-        }
-        _note_dispatch("columnar", dispatch_ms)
-        if min_seq is not None and not fuse:
-            self.compact(np.asarray(min_seq))
+            ref_i32 = None
+            if ref_wide:
+                ref_i32 = np.ascontiguousarray(ref_seq, "<i4")
+
+            pack_ms = 0.0
+            dispatch_ms = 0.0
+            for si, (c0, c1, slides) in enumerate(segments):
+                if si:
+                    pack.__enter__()
+                last_seg = si == len(segments) - 1
+                fuse_seg = fuse and last_seg
+                ms_seg = ms if fuse_seg else np.zeros((1,), np.int32)
+                w = c1 - c0
+                # power-of-two column buckets keep the jit cache warm when a
+                # crossing splits the batch (the no-split common case keeps
+                # the exact original shape)
+                wp = O if w == O else max(8, 1 << (w - 1).bit_length())
+                k_s = pad_cols(kind, c0, c1, wp, fill=int(OpKind.NOOP))
+                a0_s = pad_cols(a0, c0, c1, wp)
+                lag_s = pad_cols(lag, c0, c1, wp, fill=1)
+                cidx_s = pad_cols(cidx, c0, c1, wp)
+                base_s = seq_base if c0 == 0 else \
+                    np.ascontiguousarray(seq[:, c0 - 1])
+                if compact8:
+                    span_s = pad_cols(span, c0, c1, wp)
+                    kc = np.where(k_s == int(OpKind.NOOP), 3, k_s) \
+                        | (cidx_s << 2)
+                    head = [seg_u8(kc), seg_u16(a0_s), seg_u8(span_s),
+                            seg_u8(lag_s)]
+                elif ref_wide:
+                    head = [seg_u8(k_s), seg_u8(cidx_s), seg_pos(a0_s),
+                            seg_pos(pad_cols(a1, c0, c1, wp)),
+                            pad_cols(ref_i32, c0, c1, wp).reshape(-1)
+                            .astype("<i4", copy=False)]
+                else:  # ship the (u16) lag; device reconstructs ref=seq-lag
+                    head = [seg_u8(k_s), seg_u8(cidx_s), seg_pos(a0_s),
+                            seg_pos(pad_cols(a1, c0, c1, wp)),
+                            seg_u16(lag_s)]
+                if rich_mode >= 2:
+                    tail = [(seg_u8 if rich_mode == 2 else seg_u16)(
+                                pad_cols(tidx_eff, c0, c1, wp)),
+                            tab_a2.astype("<i4", copy=False),
+                            tab_len.astype("<i4", copy=False)]
+                elif rich_mode == 1:
+                    tail = [np.ascontiguousarray(
+                        pad_cols(a2_np, c0, c1, wp), "<i4").reshape(-1)]
+                else:
+                    tail = [a2_np.astype("<i4", copy=False)]
+                buf = np.concatenate(head + tail + [
+                    base_s.astype("<i4", copy=False),
+                    rows.astype("<i4", copy=False),
+                    ms_seg.astype("<i4", copy=False),
+                ])
+                variant = dict(R=R, O=wp, pos_wide=not narrow,
+                               ref_wide=ref_wide, rich=rich_mode,
+                               n_docs=self.n_docs, fuse_compact=fuse_seg,
+                               scatter_rows=scatter_rows, compact8=compact8,
+                               tab_n=tab_n)
+                self.unpack_variants.add(tuple(variant.values()))
+                pack.__exit__()
+                with tracing.stage(rec, "store.upload") as sp_up:
+                    dev = jnp.asarray(buf)
+                with tracing.stage(rec, "store.unpack_dispatch") as sp_unp:
+                    planes, ms_dev = _columnar_unpack_jit(dev, **variant)
+                with tracing.stage(rec, "store.merge_dispatch") as sp_mrg:
+                    if self.mesh is not None:
+                        # planes are (n_docs, O) either way: subset batches
+                        # scattered by the unpack, full-store batches already
+                        # in row order
+                        from ..parallel.sharded import sharded_merge
+                        fn = sharded_merge(self.mesh, use_pallas, tile,
+                                           interpret, self._has_props, fuse_seg)
+                        self.state = fn(self.state, planes, ms_dev) \
+                            if fuse_seg else fn(self.state, planes)
+                    else:
+                        self.state = _columnar_merge_jit(
+                            self.state, planes, ms_dev, use_pallas=use_pallas,
+                            tile=tile, interpret=interpret,
+                            with_props=self._has_props, fuse_compact=fuse_seg)
+                pack_ms += pack.ms
+                dispatch_ms += sp_up.ms + sp_unp.ms + sp_mrg.ms
+                # drop the segment's device buffers here, inside the
+                # span, not at the frame's teardown after it: releasing
+                # the upload and the seven planes is part of the apply
+                del dev, planes, ms_dev
+                if slides:
+                    # re-anchor the crossing docs off the device state AS OF
+                    # this segment's end — one fused gather for all of them
+                    # (the gather also drains the dispatch pipeline, so the
+                    # planes it returns include this segment's ops)
+                    with tracing.stage(rec, "store.slide_docs"):
+                        self._slide_docs(slides)
+            self._tab_release(pp)
+            #: host-packing vs device-dispatch wall per columnar apply — the
+            #: breakdown behind the serving throughput number (dispatches are
+            #: async; device time is measured by the caller's end sync).
+            #: ``prepack_ms`` is the payload/table build wall: when the wave
+            #: came through the pipelined executor that work ran OFF the
+            #: critical path (concurrent with the previous wave's dispatch)
+            #: and pack_ms counts only the inline remainder.
+            self.last_apply_stats = {
+                "pack_ms": pack_ms,
+                "prepack_ms": pp.prep_ms if prepacked is not None else 0.0,
+                "dispatch_ms": dispatch_ms,
+                "segments": len(segments),
+            }
+            _note_dispatch("columnar", dispatch_ms)
+            if min_seq is not None and not fuse:
+                self.compact(np.asarray(min_seq))
 
     def _pallas_choice(self):
         """(use_pallas, tile, interpret) for this store's dispatch policy.

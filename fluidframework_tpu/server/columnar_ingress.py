@@ -294,6 +294,9 @@ class _ColSession:
         #: perf_counter of the first undrained byte — the rx-buffer
         #: crossing of the latency-attribution timeline (ISSUE 17)
         self.rx_t0: Optional[float] = None
+        #: its mirror on the way out: perf_counter of the oldest frame
+        #: pushed and not yet written (``door.tx_wait``)
+        self.tx_t0: Optional[float] = None
         #: cleared while the rx buffer is over budget — reader
         #: backpressure until a drain trims it
         self._resume = asyncio.Event()
@@ -338,6 +341,11 @@ class _ColSession:
     async def _send_loop(self) -> None:
         while True:
             frame = await self.out.get()
+            t = time.perf_counter()
+            if self.tx_t0 is not None:
+                # a frame is tied to no one window: the table alone
+                tracing.wait(None, "door.tx_wait", self.tx_t0, t)
+            self.tx_t0 = None if self.out.empty() else t
             self.writer.write(frame)
             await self.writer.drain()
 
@@ -346,6 +354,8 @@ class _ColSession:
             return
         try:
             self.out.put_nowait(frame)
+            if self.tx_t0 is None:
+                self.tx_t0 = time.perf_counter()
         except asyncio.QueueFull:
             # slow-client policy: evict (Broadcaster's slow-consumer
             # disconnect); reconnect resyncs via the JSON front door
@@ -540,11 +550,14 @@ class ColumnarAlfred:
         self.idle_ages = capacity.IdleAgeTracker()
         capacity.LEDGER.add_idle_tracker(
             "ColumnarAlfred", self.idle_ages, row_doc_id=self._doc_of_row)
-        #: latency-attribution timeline of the current drain pass:
-        #: rx/drain/decode/admit crossings every window of the pass
-        #: inherits (the executor marks + ack fan complete it)
+        #: the current drain pass's record (``utils.tracing``): its
+        #: spans, counts and the rx/drain/admit crossings every window
+        #: of the pass inherits (the window's own record, stamped by the
+        #: executor and the engine and closed at the ack fan, names it as
+        #: parent)
         self._pass_tl: Optional[dict] = None
-        self._pass_admit_ms = 0.0
+        #: when the flusher last ran out of work (``door.tick_wait``)
+        self._t_idle0 = time.perf_counter()
         self._ops: Optional[object] = None   # attached OpsServer
 
     # --------------------------------------------------------- partitions
@@ -618,29 +631,31 @@ class ColumnarAlfred:
         cost scales with bytes drained, not frames seen."""
         if not self._dirty:
             return
-        t0 = time.perf_counter()
+        rec = self._pass_tl = tracing.new_record(
+            pid=self.drain_passes, frames=0, ops=0, admit_ms=0.0)
         sessions = list(self._dirty)
         self._dirty.clear()
         self._rx_backlog = 0
-        self._pass_admit_ms = 0.0
         total = 0
         rx_min: Optional[float] = None
-        for sess in sessions:
-            if sess.dead or not sess.rx:
-                continue
-            if sess.rx_t0 is not None and (rx_min is None
-                                           or sess.rx_t0 < rx_min):
-                rx_min = sess.rx_t0
-            total += self._drain_session(sess)
+        with tracing.stage(rec, "door.drain") as sp:
+            for sess in sessions:
+                if sess.dead or not sess.rx:
+                    continue
+                if sess.rx_t0 is not None and (rx_min is None
+                                               or sess.rx_t0 < rx_min):
+                    rx_min = sess.rx_t0
+                with tracing.stage(rec, "door.decode"):
+                    total += self._drain_session(sess)
         if total:
-            t1 = time.perf_counter()
+            t0, t1 = sp.t0, sp.t1
             # pass-level timeline crossings: every window carved from
             # this pass inherits them (t_rx = oldest undrained byte —
             # the worst op's wait, which is what an SLO cares about)
-            self._pass_tl = {"t_rx": rx_min if rx_min is not None else t0,
-                             "t_drain0": t0,
-                             "admit_ms": self._pass_admit_ms,
-                             "t_ready": t1}
+            rec.update(t_rx=rx_min if rx_min is not None else t0,
+                       t_drain0=t0, t_ready=t1, bytes=total)
+            tracing.wait(rec, "door.tick_wait", self._t_idle0, t0)
+            tracing.wait(rec, "door.rx_wait", rec["t_rx"], t0)
             self._drain_ms.append((t1 - t0) * 1e3)
             self._drain_bytes.append(total)
             self.drain_passes += 1
@@ -695,6 +710,7 @@ class ColumnarAlfred:
                     fatal = "frame too large"
         finally:
             mv.release()
+        self._pass_tl["frames"] += len(frames)
         if runs:
             self._decode_runs(sess, rx, runs)
         # no view of rx survives _decode_runs (planes are copies): the
@@ -781,12 +797,13 @@ class ColumnarAlfred:
             gidx, cseq, ref, client = (x[ok] for x in
                                        (gidx, cseq, ref, client))
         if row.size and self.admission is not None:
-            _t_adm = time.perf_counter()
-            row, kind, a0, a1, gidx, cseq, ref, client = \
-                self._admit_planes(sess, row, kind, a0, a1, gidx,
-                                   cseq, ref, client)
-            self._pass_admit_ms += (time.perf_counter() - _t_adm) * 1e3
+            with tracing.stage(self._pass_tl, "door.admit") as sp:
+                row, kind, a0, a1, gidx, cseq, ref, client = \
+                    self._admit_planes(sess, row, kind, a0, a1, gidx,
+                                       cseq, ref, client)
+            self._pass_tl["admit_ms"] += sp.ms
         if row.size:
+            self._pass_tl["ops"] += int(row.size)
             self._note_hotdocs(row, int(client[0]))
             self._parts.append({"sess": sess, "row": row, "kind": kind,
                                 "a0": a0, "a1": a1, "gidx": gidx,
@@ -914,107 +931,111 @@ class ColumnarAlfred:
         if not parts:
             return []
         self._parts = []
-        tab: List[_ColSession] = []
-        idx_of: Dict[int, int] = {}
-        sessi_parts = []
-        for p in parts:
-            s = p["sess"]
-            i = idx_of.get(id(s))
-            if i is None:
-                i = idx_of[id(s)] = len(tab)
-                tab.append(s)
-            sessi_parts.append(np.full(p["row"].size, i, np.int32))
-        if len(parts) == 1:
-            f = {k: parts[0][k] for k in _PLANES}
-            sessi = sessi_parts[0]
-        else:
-            f = {k: np.concatenate([p[k] for p in parts])
-                 for k in _PLANES}
-            sessi = np.concatenate(sessi_parts)
-        row = f["row"]
-        n = row.size
-        order = np.argsort(row, kind="stable")
-        srow = row[order]
-        # partitioned engine: global row = partition * dpp + local, so
-        # after the row sort partition runs are CONTIGUOUS — carve at
-        # partition boundaries FIRST, then occurrence levels per
-        # partition segment (each window then belongs to exactly one
-        # partition's sequencer/executor)
-        if self.n_partitions > 1:
-            pids = srow // self._dpp
-            pcuts = np.flatnonzero(np.diff(pids)) + 1
-            segs = [(int(pids[seg[0]]), seg)
-                    for seg in np.split(np.arange(n), pcuts)]
-        else:
-            segs = [(0, np.arange(n))]
-        chunks: List[Tuple[int, np.ndarray]] = []
-        for part, seg in segs:
-            so = srow[seg]
-            m = so.size
-            new = np.empty(m, bool)
-            new[0] = True
-            new[1:] = so[1:] != so[:-1]
-            starts = np.flatnonzero(new)
-            occ = np.arange(m) - np.repeat(starts,
-                                           np.diff(np.append(starts, m)))
-            lvl_order = np.argsort(occ, kind="stable")
-            cuts = np.flatnonzero(np.diff(occ[lvl_order])) + 1
-            oseg = order[seg]
-            for lvl in np.split(oseg[lvl_order], cuts):
-                for s in range(0, lvl.size, self.window_min_rows):
-                    chunks.append((part, lvl[s:s + self.window_min_rows]))
-        texts_g, props_g = self._texts, self._props
-        windows = []
-        for part, w in chunks:
-            kind_w = f["kind"][w]
-            gidx_w = f["gidx"][w]
-            tidx_w = np.zeros(w.size, np.int32)
-            ins = kind_w == _K_INS
-            texts_w: List[str] = []
-            if ins.any():
-                u, inv = np.unique(gidx_w[ins], return_inverse=True)
-                tidx_w[ins] = inv.astype(np.int32)
-                texts_w = [texts_g[i] for i in u.tolist()]
-            props_w: List[dict] = []
-            ann = kind_w == _K_ANN
-            if ann.any():
-                u, inv = np.unique(gidx_w[ann], return_inverse=True)
-                tidx_w[ann] = inv.astype(np.int32)
-                props_w = [props_g[i] for i in u.tolist()]
-            windows.append({
-                "rows": row[w], "kind": kind_w.reshape(-1, 1),
-                "a0": f["a0"][w].reshape(-1, 1),
-                "a1": f["a1"][w].reshape(-1, 1),
-                "tidx": tidx_w.reshape(-1, 1),
-                "cseq": f["cseq"][w].reshape(-1, 1),
-                "ref": f["ref"][w].reshape(-1, 1),
-                "client": f["client"][w].reshape(-1, 1),
-                "cseq_flat": f["cseq"][w], "sessi": sessi[w],
-                "texts": texts_w or [""], "props": props_w or None,
-                "tab": tab, "tl": self._pass_tl, "part": part})
-        # the interners only feed this pass's windows, which now carry
-        # their own compacted tables — reset so they stay bounded
-        self._texts, self._text_of = [], {}
-        self._props, self._prop_of = [], {}
-        if self.n_partitions > 1 and len(windows) > 1:
-            # interleave submission round-robin across partitions: the
-            # per-partition depth wait then parks on the SATURATED
-            # partition only after its peers' windows are already in
-            # flight (within a partition, level order — per-doc FIFO —
-            # is preserved: stable grouping keeps relative order)
-            byp: Dict[int, List[dict]] = {}
-            for w in windows:
-                byp.setdefault(w["part"], []).append(w)
-            queues = list(byp.values())
+        with tracing.stage(self._pass_tl, "door.build_windows"):
+            tab: List[_ColSession] = []
+            idx_of: Dict[int, int] = {}
+            sessi_parts = []
+            for p in parts:
+                s = p["sess"]
+                i = idx_of.get(id(s))
+                if i is None:
+                    i = idx_of[id(s)] = len(tab)
+                    tab.append(s)
+                sessi_parts.append(np.full(p["row"].size, i, np.int32))
+            if len(parts) == 1:
+                f = {k: parts[0][k] for k in _PLANES}
+                sessi = sessi_parts[0]
+            else:
+                f = {k: np.concatenate([p[k] for p in parts])
+                     for k in _PLANES}
+                sessi = np.concatenate(sessi_parts)
+            row = f["row"]
+            n = row.size
+            order = np.argsort(row, kind="stable")
+            srow = row[order]
+            # partitioned engine: global row = partition * dpp + local, so
+            # after the row sort partition runs are CONTIGUOUS — carve at
+            # partition boundaries FIRST, then occurrence levels per
+            # partition segment (each window then belongs to exactly one
+            # partition's sequencer/executor)
+            if self.n_partitions > 1:
+                pids = srow // self._dpp
+                pcuts = np.flatnonzero(np.diff(pids)) + 1
+                segs = [(int(pids[seg[0]]), seg)
+                        for seg in np.split(np.arange(n), pcuts)]
+            else:
+                segs = [(0, np.arange(n))]
+            chunks: List[Tuple[int, np.ndarray]] = []
+            for part, seg in segs:
+                so = srow[seg]
+                m = so.size
+                new = np.empty(m, bool)
+                new[0] = True
+                new[1:] = so[1:] != so[:-1]
+                starts = np.flatnonzero(new)
+                occ = np.arange(m) - np.repeat(starts,
+                                               np.diff(np.append(starts, m)))
+                lvl_order = np.argsort(occ, kind="stable")
+                cuts = np.flatnonzero(np.diff(occ[lvl_order])) + 1
+                oseg = order[seg]
+                for lvl in np.split(oseg[lvl_order], cuts):
+                    for s in range(0, lvl.size, self.window_min_rows):
+                        chunks.append((part, lvl[s:s + self.window_min_rows]))
+            texts_g, props_g = self._texts, self._props
             windows = []
-            i = 0
-            while queues:
-                q = queues[i % len(queues)]
-                windows.append(q.pop(0))
-                if q:
-                    i += 1
-                else:
-                    queues.remove(q)
+            for part, w in chunks:
+                kind_w = f["kind"][w]
+                gidx_w = f["gidx"][w]
+                tidx_w = np.zeros(w.size, np.int32)
+                ins = kind_w == _K_INS
+                texts_w: List[str] = []
+                if ins.any():
+                    u, inv = np.unique(gidx_w[ins], return_inverse=True)
+                    tidx_w[ins] = inv.astype(np.int32)
+                    texts_w = [texts_g[i] for i in u.tolist()]
+                props_w: List[dict] = []
+                ann = kind_w == _K_ANN
+                if ann.any():
+                    u, inv = np.unique(gidx_w[ann], return_inverse=True)
+                    tidx_w[ann] = inv.astype(np.int32)
+                    props_w = [props_g[i] for i in u.tolist()]
+                windows.append({
+                    "rows": row[w], "kind": kind_w.reshape(-1, 1),
+                    "a0": f["a0"][w].reshape(-1, 1),
+                    "a1": f["a1"][w].reshape(-1, 1),
+                    "tidx": tidx_w.reshape(-1, 1),
+                    "cseq": f["cseq"][w].reshape(-1, 1),
+                    "ref": f["ref"][w].reshape(-1, 1),
+                    "client": f["client"][w].reshape(-1, 1),
+                    "cseq_flat": f["cseq"][w], "sessi": sessi[w],
+                    "texts": texts_w or [""], "props": props_w or None,
+                    "tab": tab, "tl": self._pass_tl, "part": part,
+                    "rec": tracing.new_record(pid=self._pass_tl["pid"],
+                                              ops=int(w.size))})
+            # the interners only feed this pass's windows, which now carry
+            # their own compacted tables — reset so they stay bounded
+            self._texts, self._text_of = [], {}
+            self._props, self._prop_of = [], {}
+            if self.n_partitions > 1 and len(windows) > 1:
+                # interleave submission round-robin across partitions: the
+                # per-partition depth wait then parks on the SATURATED
+                # partition only after its peers' windows are already in
+                # flight (within a partition, level order — per-doc FIFO —
+                # is preserved: stable grouping keeps relative order)
+                byp: Dict[int, List[dict]] = {}
+                for w in windows:
+                    byp.setdefault(w["part"], []).append(w)
+                queues = list(byp.values())
+                windows = []
+                i = 0
+                while queues:
+                    q = queues[i % len(queues)]
+                    windows.append(q.pop(0))
+                    if q:
+                        i += 1
+                    else:
+                        queues.remove(q)
+        self._pass_tl["windows"] = len(windows)
         return windows
 
     def _submit_window(self, w: dict) -> None:
@@ -1024,34 +1045,31 @@ class ColumnarAlfred:
         # shed fences, hotdocs) keeps the door's global rows
         loc = w["rows"] - part * self._dpp if self.n_partitions > 1 \
             else w["rows"]
+        # the window's identity from here on: the engine's stages stamp
+        # the same record (``marks=``), the ack fan closes it
+        rec = w["rec"]
+        rec["wid"] = self.windows_flushed
         if self._executors:
             # pipelined front door: hand the window to its partition's
             # executor and return — the NEXT window aggregates while
             # this one packs/sequences/dispatches; acks fan back from
             # the done callback only after the durable append commits
             # (ack-after-durable)
-            with tracing.TRACER.maybe_root_span(
-                    "columnar.submit_window", every=256, ops=n):
-                # sampled windows carry their trace context to the ack
-                # fan: the e2e histogram's exemplar names a real trace
-                w["ctx"] = tracing.TRACER.current()
+            with tracing.stage(rec, "door.submit"):
                 ticket = self._executors[part].submit(
                     loc, w["client"], w["cseq"], w["ref"],
                     w["kind"], w["a0"], w["a1"], texts=w["texts"],
-                    tidx=w["tidx"], props=w["props"])
-            self._waves_inflight[part] += 1
-            loop = getattr(self, "_loop", None) or \
-                asyncio.get_running_loop()
-            ticket.add_done_callback(
-                lambda t: self._bounce_ack(loop, t, w))
+                    tidx=w["tidx"], props=w["props"], marks=rec)
+                self._waves_inflight[part] += 1
+                loop = getattr(self, "_loop", None) or \
+                    asyncio.get_running_loop()
+                ticket.add_done_callback(
+                    lambda t: self._bounce_ack(loop, t, w))
         else:
-            with tracing.TRACER.maybe_root_span(
-                    "columnar.flush_window", every=256, ops=n):
-                w["ctx"] = tracing.TRACER.current()
-                res = self._engine_of(part).ingest_planes(
-                    loc, w["client"], w["cseq"], w["ref"],
-                    w["kind"], w["a0"], w["a1"], texts=w["texts"],
-                    tidx=w["tidx"], props=w["props"])
+            res = self._engine_of(part).ingest_planes(
+                loc, w["client"], w["cseq"], w["ref"],
+                w["kind"], w["a0"], w["a1"], texts=w["texts"],
+                tidx=w["tidx"], props=w["props"], marks=rec)
             self._fan_acks(w, np.asarray(res["seq"]).reshape(-1),
                            marks=res.get("marks"))
         self.windows_flushed += 1
@@ -1072,38 +1090,41 @@ class ColumnarAlfred:
         The frame carries a parallel ``rows`` list (acks keep their
         2-tuple shape for wire compatibility) so resilient clients can
         attribute each ack to a doc."""
-        rows, cseq = w["rows"], w["cseq_flat"]
-        sessi, tab = w["sessi"], w["tab"]
-        self.engine.note_acked_planes(rows, w["client"].reshape(-1),
-                                      cseq, seqs)
-        if self.digest_tap is not None:
-            # fold the sequenced window into the replicated shadow and
-            # assert cross-replica digest parity (ISSUE 18): the tap's
-            # on_window runs the shard_map step and records agreement
-            self.digest_tap.on_window(
-                rows, w["kind"], w["a0"], w["a1"], seqs,
-                w["client"], w["ref"])
-        if self.admission is not None:
-            # service-rate feedback for the deadline estimator: these
-            # ops just finished sequencing + durable append
-            self.admission.note_served(int(rows.size))
-        order = np.argsort(sessi, kind="stable")
-        ss = sessi[order]
-        cuts = np.flatnonzero(np.diff(ss)) + 1
-        for g in np.split(order, cuts):
-            pairs = np.empty((g.size, 2), np.int64)
-            pairs[:, 0] = cseq[g]
-            pairs[:, 1] = seqs[g]
-            tab[int(sessi[g[0]])]._push_json(
-                {"t": "acks", "acks": pairs.tolist(),
-                 "rows": rows[g].tolist()})
-        # latency attribution (ISSUE 17): the ack fan completes the
-        # window's timeline — attribute e2e to consecutive stage segments
+        rec = w["rec"]
+        with tracing.stage(rec, "door.fan_acks") as sp:
+            rows, cseq = w["rows"], w["cseq_flat"]
+            sessi, tab = w["sessi"], w["tab"]
+            self.engine.note_acked_planes(rows, w["client"].reshape(-1),
+                                          cseq, seqs)
+            if self.digest_tap is not None:
+                # fold the sequenced window into the replicated shadow and
+                # assert cross-replica digest parity (ISSUE 18): the tap's
+                # on_window runs the shard_map step and records agreement
+                self.digest_tap.on_window(
+                    rows, w["kind"], w["a0"], w["a1"], seqs,
+                    w["client"], w["ref"])
+            if self.admission is not None:
+                # service-rate feedback for the deadline estimator: these
+                # ops just finished sequencing + durable append
+                self.admission.note_served(int(rows.size))
+            order = np.argsort(sessi, kind="stable")
+            ss = sessi[order]
+            cuts = np.flatnonzero(np.diff(ss)) + 1
+            for g in np.split(order, cuts):
+                pairs = np.empty((g.size, 2), np.int64)
+                pairs[:, 0] = cseq[g]
+                pairs[:, 1] = seqs[g]
+                tab[int(sessi[g[0]])]._push_json(
+                    {"t": "acks", "acks": pairs.tolist(),
+                     "rows": rows[g].tolist()})
+        # the ack fan completes the window's record: file it (a slow
+        # window is kept whole, and is then the e2e histogram's exemplar)
+        # and attribute rx → ack to consecutive stage segments
         tl = w.get("tl")
         if tl is not None and marks:
-            t_ack = time.perf_counter()
-            observe_window_timeline(tl, marks, t_ack,
-                                    exemplar=w.get("ctx"))
+            t_ack = sp.t1
+            ctx = tracing.close_window(rec, tl, t_ack)
+            observe_window_timeline(tl, marks, t_ack, exemplar=ctx)
             if self._part_colls:
                 # same stage histograms, partition-labeled (ISSUE 18):
                 # /debug/latency?partition=p splits the storm by
@@ -1112,7 +1133,7 @@ class ColumnarAlfred:
                 observe_window_timeline(
                     tl, marks, t_ack,
                     registry=self._part_colls[w.get("part", 0)],
-                    exemplar=w.get("ctx"))
+                    exemplar=ctx)
 
     def _bounce_ack(self, loop, ticket, w: dict) -> None:
         """Ticket done-callback: runs on the executor's log worker —
@@ -1123,6 +1144,10 @@ class ColumnarAlfred:
             pass   # loop already closed (shutdown race): acks are moot
 
     def _ack_wave(self, ticket, w: dict) -> None:
+        rec = w["rec"]
+        if "log1" in rec:   # log done → here, on the loop
+            tracing.wait(rec, "door.ack_bounce", rec["log1"],
+                         time.perf_counter(), mark="ack0")
         self._waves_inflight[w.get("part", 0)] -= 1
         if self._capacity is not None:
             self._capacity.set()
@@ -1169,11 +1194,15 @@ class ColumnarAlfred:
                                        ) from self._pipeline_error
                 self._drain()
                 for w in self._build_windows():
+                    t = time.perf_counter()
                     await self._wait_capacity(w.get("part", 0))
+                    tracing.wait(w["rec"], "door.capacity_wait", t,
+                                 time.perf_counter())
                     if self._pipeline_error is not None:
                         raise RuntimeError("pipelined ingest failed"
                                            ) from self._pipeline_error
                     self._submit_window(w)
+                    self._t_idle0 = time.perf_counter()
             except Exception as e:   # poisoned engine / device fault:
                 # surface to every connected session, then stop serving
                 for sess in list(self._sessions):
@@ -1202,6 +1231,7 @@ class ColumnarAlfred:
         started = threading.Event()
 
         def _run():
+            tracing.name_os_thread("fluid-door")
             self._loop = asyncio.new_event_loop()
             asyncio.set_event_loop(self._loop)
 
@@ -1216,7 +1246,8 @@ class ColumnarAlfred:
             except asyncio.CancelledError:
                 pass
 
-        self._thread = threading.Thread(target=_run, daemon=True)
+        self._thread = threading.Thread(target=_run, name="fluid-door",
+                                        daemon=True)
         self._thread.start()
         if not started.wait(timeout=10):
             raise TimeoutError("columnar ingress failed to start")

@@ -51,6 +51,7 @@ import threading
 import time
 from typing import Any, Callable, List, Optional
 
+from ..utils import tracing
 from ..utils.telemetry import StageClock
 
 _STOP = object()
@@ -278,7 +279,20 @@ class PipelinedIngestExecutor:
             self._inflight -= 1
             self._cond.notify_all()
 
+    @staticmethod
+    def _queue_wait(wave, name: str, since, t0: float) -> None:
+        """Stamp the wait a wave spent in a stage's queue into its
+        record (``marks``: the string engine's waves carry one, a tree
+        wave none): from ``since``, a time or the name of the crossing
+        the stage before left there, to ``t0``, when this worker took it."""
+        marks = getattr(wave, "marks", None)
+        if marks is not None:
+            since = marks.get(since) if isinstance(since, str) else since
+            if since is not None:
+                tracing.wait(marks, name, since, t0)
+
     def _pack_worker(self) -> None:
+        tracing.name_os_thread("fluid-pack")
         eng = self.engine
         while True:
             item = self._pack_q.get()
@@ -297,6 +311,8 @@ class PipelinedIngestExecutor:
                 continue
             self.clock.add("pack", (time.perf_counter() - t0) * 1000)
             ticket.wave = wave
+            self._queue_wait(wave, "executor.pack_wait", ticket.t_submit,
+                             t0)
             self._seq_q.put(ticket)
             if wave.prepacked is None:
                 # un-prepackable wave (interval batch: anchor handles
@@ -308,6 +324,7 @@ class PipelinedIngestExecutor:
                 ticket._dispatched.wait()
 
     def _seq_worker(self) -> None:
+        tracing.name_os_thread("fluid-seq")
         eng = self.engine
         while True:
             item = self._seq_q.get()
@@ -319,6 +336,7 @@ class PipelinedIngestExecutor:
                 self._finish(ticket, error=self._chain_error(ticket))
                 continue
             t0 = time.perf_counter()
+            self._queue_wait(ticket.wave, "executor.seq_wait", "pack1", t0)
             try:
                 eng._ingest_sequence(ticket.wave)
                 eng._ingest_dispatch(ticket.wave)
@@ -331,6 +349,7 @@ class PipelinedIngestExecutor:
             self._log_q.put(ticket)
 
     def _log_worker(self) -> None:
+        tracing.name_os_thread("fluid-log")
         eng = self.engine
         while True:
             item = self._log_q.get()
@@ -341,6 +360,7 @@ class PipelinedIngestExecutor:
             # queue sequenced+dispatched BEFORE the failure — its ops
             # must become durable or the poison sentinel never clears
             t0 = time.perf_counter()
+            self._queue_wait(ticket.wave, "executor.log_wait", "disp1", t0)
             try:
                 result = eng._ingest_log(ticket.wave)
             except BaseException as e:  # noqa: BLE001 — fail-stop

@@ -58,6 +58,11 @@ __all__ = ["OpsServer", "SpaceSaving", "STAGES",
 #: histograms are consecutive segments of one monotonic timeline
 STAGES = ("rx", "decode", "admit", "pack",
           "sequence", "dispatch", "log", "ack")
+#: stage → the mark at which its work starts; what precedes it inside
+#: the stage's segment is a wait (``stage_{name}_wait_ms``). ``rx`` is a
+#: wait whole, ``decode`` and ``admit`` have none.
+WORK_STARTS = {"pack": "pack0", "sequence": "seq0", "dispatch": "disp0",
+               "log": "log0", "ack": "ack0"}
 
 
 def observe_window_timeline(tl: dict, marks: dict, t_ack: float,
@@ -73,7 +78,11 @@ def observe_window_timeline(tl: dict, marks: dict, t_ack: float,
     ``crossing[k+1] - crossing[k]`` with crossings clamped monotonic, so
     ``sum(stage_*_ms) == stage_e2e_ack_ms`` exactly — queue waits land
     in the stage that absorbed them (pack's segment includes the
-    executor hand-off wait; ack's the done-callback bounce)."""
+    executor hand-off wait; ack's the done-callback bounce). Where
+    ``marks`` also holds a stage's start crossing (``pack0``/``seq0``/
+    ``disp0``/``log0``, and ``ack0`` where the bounce ended), the part
+    of the segment before it is observed as ``stage_{name}_wait_ms``:
+    each segment is then a wait plus the stage's work."""
     t_rx = float(tl["t_rx"])
     t_ready = float(tl["t_ready"])
     admit_s = max(0.0, float(tl.get("admit_ms", 0.0))) * 1e-3
@@ -94,6 +103,10 @@ def observe_window_timeline(tl: dict, marks: dict, t_ack: float,
     reg = registry if registry is not None else REGISTRY
     for name, a, b in zip(STAGES, crossings, crossings[1:]):
         reg.observe(f"stage_{name}_ms", (b - a) * 1e3)
+        start = marks.get(WORK_STARTS.get(name))
+        if start is not None:
+            reg.observe(f"stage_{name}_wait_ms",
+                        min(max(float(start) - a, 0.0), b - a) * 1e3)
     reg.observe("stage_e2e_ack_ms", (crossings[-1] - crossings[0]) * 1e3,
                 exemplar=exemplar)
 
@@ -113,8 +126,13 @@ def latency_breakdown(registry: Optional[MetricsRegistry] = None) -> dict:
         h = reg.histograms.get(f"stage_{name}_ms")
         if h is None or h.n == 0:
             continue
+        hw = reg.histograms.get(f"stage_{name}_wait_ms")
         stages[name] = {"mean_ms": h.mean, "p50_ms": h.percentile(50),
-                        "p99_ms": h.percentile(99), "count": h.n}
+                        "p99_ms": h.percentile(99), "count": h.n,
+                        # per window, like mean_ms (windows whose marks
+                        # lacked the start crossing count as no wait)
+                        "wait_ms": h.mean if name == "rx" else
+                        hw.sum_ms / h.n if hw is not None else 0.0}
         stage_sum += h.mean
     e2e = reg.histograms.get("stage_e2e_ack_ms")
     e2e_mean = e2e.mean if e2e is not None and e2e.n else 0.0

@@ -1177,7 +1177,7 @@ class _IngestWave:
     ``_ingest_log``); the pipelined executor hands one of these from
     worker to worker, the serial ``ingest_planes`` walks it in place."""
     __slots__ = (
-        "t_start", "rows", "R", "O", "kind", "a0", "a1", "client",
+        "rows", "R", "O", "kind", "a0", "a1", "client",
         "ref_seq", "text", "texts", "tidx", "props", "flat_client",
         "flat_client_seq", "flat_ref_seq", "handles", "prepacked",
         "pipelined", "prep_ms", "seq_ms", "out_seq", "out_min", "nacked",
@@ -1192,9 +1192,10 @@ class _IngestWave:
         self.seq_ms = 0.0
         self.apply_stats = {}
         self.ov_prev = None
-        # latency-attribution crossings (ISSUE 17): each stage method
-        # stamps its completion time here; the front door joins them
-        # with its own rx/decode timeline at ack-fan time
+        # the window's record (utils.tracing): each stage method stamps
+        # its spans and its two crossings here (``pack0``/``pack1`` ...);
+        # the front door hands its own record in (``marks=``) and closes
+        # it at the ack fan
         self.marks: dict = {}
 
 
@@ -1384,7 +1385,7 @@ class StringServingEngine(ServingEngineBase):
 
     def ingest_planes(self, rows, client, client_seq, ref_seq, kind, a0, a1,
                       text: str = "", texts=None, tidx=None,
-                      props=None) -> dict:
+                      props=None, marks: Optional[dict] = None) -> dict:
         """The high-throughput ingest path: a dense (R, O) columnar batch of
         RAW client string ops — sequenced in ONE native C call, bulk-appended
         to the durable log as per-partition ``ColumnarOps`` records, and
@@ -1418,7 +1419,8 @@ class StringServingEngine(ServingEngineBase):
         per-op submit() fallback."""
         self._check_poisoned()
         w = self._ingest_prepare(rows, client, client_seq, ref_seq, kind,
-                                 a0, a1, text, texts, tidx, props)
+                                 a0, a1, text, texts, tidx, props,
+                                 marks=marks)
         self._ingest_sequence(w)
         self._ingest_dispatch(w)
         return self._ingest_log(w)
@@ -1435,313 +1437,315 @@ class StringServingEngine(ServingEngineBase):
 
     def _ingest_prepare(self, rows, client, client_seq, ref_seq, kind,
                         a0, a1, text="", texts=None, tidx=None,
-                        props=None, prepack=False) -> "_IngestWave":
+                        props=None, prepack=False,
+                        marks: Optional[dict] = None) -> "_IngestWave":
         """Stage 1 — validation, row-handle fill, plane flattening, and
         (``prepack=True``, pipelined mode) the payload/table pack, all
-        independent of sequencing results."""
+        independent of sequencing results. ``marks``: the door's record
+        of this window (``utils.tracing``); a fresh one without."""
         raw = getattr(self.deli, "raw", None)
         if raw is None:
             raise RuntimeError("columnar ingest requires sequencer='native'")
         w = _IngestWave()
-        w.t_start = time.perf_counter()
-        rows = np.ascontiguousarray(rows, np.int32)
-        R, O = kind.shape
-        if len(rows) != R or len(np.unique(rows)) != R:
-            raise ValueError("rows must be exactly one UNIQUE row per "
-                             "plane row (duplicates would silently drop "
-                             "ops in the device scatter)")
-        if self._graduated and any(self._row_doc_id[r] in self._graduated
-                                   for r in rows):
-            raise ValueError("a targeted doc has graduated off the flat "
-                             "tier; route its ops through submit()")
-        kind = np.asarray(kind, np.int32)
-        top = int(OpKind.STR_REMOVE)
-        if props is not None:
-            top = int(OpKind.STR_ANNOTATE)
-            if any(len(p) != 1 for p in props):
-                raise ValueError("columnar annotates are single-key; "
-                                 "multi-key props go through submit()")
-            # reserve prop planes/values BEFORE sequencing: an op the
-            # flush path cannot apply must never be acked+logged
-            self.store.reserve_prop_tables(
-                {k for p in props for k in p},
-                [v for p in props for v in p.values()])
-        # range compares, not np.isin: set membership over a 655k-op plane
-        # costs ~8 ms for the same answer (the kind codes are contiguous
-        # from STR_INSERT)
-        if not bool(((kind >= int(OpKind.STR_INSERT))
-                     & (kind <= top)).all()):
-            raise ValueError("columnar planes must be dense "
-                             "insert/remove" +
-                             ("/annotate" if props is not None else ""))
-        # tidx must be validated BEFORE sequencing: a negative index would
-        # silently wrap (numpy fancy indexing) and apply/ack/log the WRONG
-        # payload; an out-of-range one would raise only after the native
-        # sequencer consumed seqs, leaving doc.seq ahead of the durable log
-        if tidx is not None:
-            tidx_arr = np.asarray(tidx, np.int32)
-            if tidx_arr.shape != kind.shape:
-                raise ValueError("tidx shape must match the op planes")
-            if (tidx_arr < 0).any():
-                raise ValueError("negative tidx in columnar batch")
-            # masked maxima (initial=-1) instead of boolean extraction:
-            # tidx_arr[mask] materializes a copy per check on the hot path
-            if texts is not None and int(np.max(
-                    tidx_arr, initial=-1,
-                    where=kind == int(OpKind.STR_INSERT))) >= len(texts):
-                raise ValueError("insert tidx beyond the payload table")
-            if props is not None and int(np.max(
-                    tidx_arr, initial=-1,
-                    where=kind == int(OpKind.STR_ANNOTATE))) >= len(props):
-                raise ValueError("annotate tidx beyond the props table")
-        elif texts is not None or props is not None:
-            raise ValueError("payload/props tables require the tidx plane")
+        w.marks = marks if marks is not None else tracing.new_record()
+        with tracing.stage(w.marks, "engine.prepare", mark="pack") as sp:
+            rows = np.ascontiguousarray(rows, np.int32)
+            R, O = kind.shape
+            if len(rows) != R or len(np.unique(rows)) != R:
+                raise ValueError("rows must be exactly one UNIQUE row per "
+                                 "plane row (duplicates would silently drop "
+                                 "ops in the device scatter)")
+            if self._graduated and any(self._row_doc_id[r] in self._graduated
+                                       for r in rows):
+                raise ValueError("a targeted doc has graduated off the flat "
+                                 "tier; route its ops through submit()")
+            kind = np.asarray(kind, np.int32)
+            top = int(OpKind.STR_REMOVE)
+            if props is not None:
+                top = int(OpKind.STR_ANNOTATE)
+                if any(len(p) != 1 for p in props):
+                    raise ValueError("columnar annotates are single-key; "
+                                     "multi-key props go through submit()")
+                # reserve prop planes/values BEFORE sequencing: an op the
+                # flush path cannot apply must never be acked+logged
+                self.store.reserve_prop_tables(
+                    {k for p in props for k in p},
+                    [v for p in props for v in p.values()])
+            # range compares, not np.isin: set membership over a 655k-op plane
+            # costs ~8 ms for the same answer (the kind codes are contiguous
+            # from STR_INSERT)
+            if not bool(((kind >= int(OpKind.STR_INSERT))
+                         & (kind <= top)).all()):
+                raise ValueError("columnar planes must be dense "
+                                 "insert/remove" +
+                                 ("/annotate" if props is not None else ""))
+            # tidx must be validated BEFORE sequencing: a negative index would
+            # silently wrap (numpy fancy indexing) and apply/ack/log the WRONG
+            # payload; an out-of-range one would raise only after the native
+            # sequencer consumed seqs, leaving doc.seq ahead of the durable log
+            if tidx is not None:
+                tidx_arr = np.asarray(tidx, np.int32)
+                if tidx_arr.shape != kind.shape:
+                    raise ValueError("tidx shape must match the op planes")
+                if (tidx_arr < 0).any():
+                    raise ValueError("negative tidx in columnar batch")
+                # masked maxima (initial=-1) instead of boolean extraction:
+                # tidx_arr[mask] materializes a copy per check on the hot path
+                if texts is not None and int(np.max(
+                        tidx_arr, initial=-1,
+                        where=kind == int(OpKind.STR_INSERT))) >= len(texts):
+                    raise ValueError("insert tidx beyond the payload table")
+                if props is not None and int(np.max(
+                        tidx_arr, initial=-1,
+                        where=kind == int(OpKind.STR_ANNOTATE))) >= len(props):
+                    raise ValueError("annotate tidx beyond the props table")
+            elif texts is not None or props is not None:
+                raise ValueError("payload/props tables require the tidx plane")
 
-        self._fill_row_handles(rows, raw)
-        w.rows, w.R, w.O = rows, R, O
-        w.kind = kind
-        w.a0 = np.ascontiguousarray(np.asarray(a0, np.int32))
-        w.a1 = np.ascontiguousarray(np.asarray(a1, np.int32))
-        w.client = np.ascontiguousarray(np.asarray(client, np.int32))
-        w.ref_seq = np.ascontiguousarray(np.asarray(ref_seq, np.int32))
-        w.text, w.texts, w.tidx, w.props = text, texts, tidx, props
-        w.flat_client = w.client.reshape(-1)
-        w.flat_client_seq = np.ascontiguousarray(
-            np.asarray(client_seq, np.int32).reshape(-1))
-        w.flat_ref_seq = w.ref_seq.reshape(-1)
-        w.handles = np.repeat(self._row_handle[rows], O)
-        _t_val = time.perf_counter()
-        w.prep_ms = (_t_val - w.t_start) * 1000
-        if prepack:
-            w.pipelined = True
-            # payload/table pack AHEAD of sequencing (overlaps the
-            # previous wave's device dispatch). None = interval batch:
-            # the executor barriers and the dispatch stage packs inline.
-            w.prepacked = self.store.prepack_planes(
-                rows, kind, w.a0, w.a1, text, texts, tidx, props)
-        w.marks["pack1"] = time.perf_counter()
+            self._fill_row_handles(rows, raw)
+            w.rows, w.R, w.O = rows, R, O
+            w.kind = kind
+            w.a0 = np.ascontiguousarray(np.asarray(a0, np.int32))
+            w.a1 = np.ascontiguousarray(np.asarray(a1, np.int32))
+            w.client = np.ascontiguousarray(np.asarray(client, np.int32))
+            w.ref_seq = np.ascontiguousarray(np.asarray(ref_seq, np.int32))
+            w.text, w.texts, w.tidx, w.props = text, texts, tidx, props
+            w.flat_client = w.client.reshape(-1)
+            w.flat_client_seq = np.ascontiguousarray(
+                np.asarray(client_seq, np.int32).reshape(-1))
+            w.flat_ref_seq = w.ref_seq.reshape(-1)
+            w.handles = np.repeat(self._row_handle[rows], O)
+            if prepack:
+                w.pipelined = True
+                # payload/table pack AHEAD of sequencing (overlaps the
+                # previous wave's device dispatch). None = interval batch:
+                # the executor barriers and the dispatch stage packs inline.
+                w.prepacked = self.store.prepack_planes(
+                    rows, kind, w.a0, w.a1, text, texts, tidx, props)
+        # validation and flattening; the table pack timed itself
+        w.prep_ms = sp.ms - (w.prepacked.prep_ms
+                             if w.prepacked is not None else 0.0)
         return w
 
     def _ingest_sequence(self, w: "_IngestWave") -> None:
         """Stage 2 — ONE native sequencing call + the post-seq plane math
         (nack masking, per-row seq bases, window-floor fold)."""
         raw = self.deli.raw
-        _t0 = time.perf_counter()
-        self.flush()  # per-op queue first: per-doc seq order must hold
-        rdi_rows = w.rows
-        out_seq, out_min, nacked, n_ok = self._sequence_columnar(
-            raw, w.handles, w.flat_client, w.flat_client_seq,
-            w.flat_ref_seq, "columnar batch",
-            doc_of=lambda i: self._row_doc_id[rdi_rows[i // w.O]])
-        _t_seq = time.perf_counter()
-        w.out_seq, w.out_min, w.nacked, w.n_ok = out_seq, out_min, \
-            nacked, n_ok
-        # dup-acked resubmits: nacked (not re-applied/re-logged) but carry
-        # their original positive seq in out_seq so the ack fan re-acks
-        w.dup_acked = self._dup_acked_last
-        R, O = w.R, w.O
-        # nacked slots become NOOP (they consumed no seq); the store
-        # rebuilds per-op seqs on device from each doc's base — only
-        # narrow planes cross the host→device link (ref clamps on device)
-        valid_rs = (~nacked).reshape(R, O)
-        w.kind_eff = np.where(valid_rs, w.kind, int(OpKind.NOOP))
-        w.seq_rs = out_seq.reshape(R, O)
-        w.n_valid = valid_rs.sum(axis=1)
-        w.seq_base = (np.max(np.where(valid_rs, w.seq_rs, 0), axis=1)
-                      - w.n_valid).astype(np.int32)
-        # window-floor tracking for zamboni: fold this batch's MSN advance
-        # in BEFORE building the fused compaction floor, so a compaction-due
-        # batch zambonis at the post-batch floor (not one batch stale)
-        w.min_rs = out_min.reshape(R, O)
-        last_min = w.min_rs[:, -1]
-        # C-level dict bulk update (zip over plain-int lists), not a
-        # 10k-iteration Python loop with an int() per row
-        rdi = self._row_doc_id
-        self._min_seq.update(zip((rdi[r] for r in w.rows.tolist()),
-                                 last_min.tolist()))
-        w.compact_due = \
-            self._flushes_since_compact + 1 >= self.compact_every
-        w.ms_arr = None
-        if w.compact_due:
-            ms_arr = np.zeros((self.n_docs,), np.int32)
-            dr = self._doc_rows
-            if dr:
-                g = self._min_seq.get
-                ms_arr[np.fromiter(dr.values(), np.int32, count=len(dr))] \
-                    = np.fromiter((g(d, 0) for d in dr), np.int64,
-                                  count=len(dr))
-            w.ms_arr = ms_arr
-        w.seq_ms = (_t_seq - _t0) * 1000
-        w.prep_ms += (time.perf_counter() - _t_seq) * 1000
-        w.marks["seq1"] = time.perf_counter()
+        with tracing.stage(w.marks, "engine.sequence", mark="seq") as sp:
+            self.flush()  # per-op queue first: per-doc seq order must hold
+            rdi_rows = w.rows
+            with tracing.stage(w.marks, "deli.sequence") as sp_deli:
+                out_seq, out_min, nacked, n_ok = self._sequence_columnar(
+                    raw, w.handles, w.flat_client, w.flat_client_seq,
+                    w.flat_ref_seq, "columnar batch",
+                    doc_of=lambda i: self._row_doc_id[rdi_rows[i // w.O]])
+            w.out_seq, w.out_min, w.nacked, w.n_ok = out_seq, out_min, \
+                nacked, n_ok
+            # dup-acked resubmits: nacked (not re-applied/re-logged) but carry
+            # their original positive seq in out_seq so the ack fan re-acks
+            w.dup_acked = self._dup_acked_last
+            R, O = w.R, w.O
+            # nacked slots become NOOP (they consumed no seq); the store
+            # rebuilds per-op seqs on device from each doc's base — only
+            # narrow planes cross the host→device link (ref clamps on device)
+            valid_rs = (~nacked).reshape(R, O)
+            w.kind_eff = np.where(valid_rs, w.kind, int(OpKind.NOOP))
+            w.seq_rs = out_seq.reshape(R, O)
+            w.n_valid = valid_rs.sum(axis=1)
+            w.seq_base = (np.max(np.where(valid_rs, w.seq_rs, 0), axis=1)
+                          - w.n_valid).astype(np.int32)
+            # window-floor tracking for zamboni: fold this batch's MSN advance
+            # in BEFORE building the fused compaction floor, so a compaction-due
+            # batch zambonis at the post-batch floor (not one batch stale)
+            w.min_rs = out_min.reshape(R, O)
+            last_min = w.min_rs[:, -1]
+            # C-level dict bulk update (zip over plain-int lists), not a
+            # 10k-iteration Python loop with an int() per row
+            rdi = self._row_doc_id
+            self._min_seq.update(zip((rdi[r] for r in w.rows.tolist()),
+                                     last_min.tolist()))
+            w.compact_due = \
+                self._flushes_since_compact + 1 >= self.compact_every
+            w.ms_arr = None
+            if w.compact_due:
+                ms_arr = np.zeros((self.n_docs,), np.int32)
+                dr = self._doc_rows
+                if dr:
+                    g = self._min_seq.get
+                    ms_arr[np.fromiter(dr.values(), np.int32, count=len(dr))] \
+                        = np.fromiter((g(d, 0) for d in dr), np.int64,
+                                      count=len(dr))
+                w.ms_arr = ms_arr
+        # the native call; the plane math around it counts as prep
+        w.seq_ms = sp_deli.ms
+        w.prep_ms += sp.ms - sp_deli.ms
 
     def _ingest_dispatch(self, w: "_IngestWave") -> None:
         """Stage 3 — the async device merge (zamboni fuses into the same
         dispatch on a compaction-due wave) + compaction cadence."""
-        # degradation injection: an armed plan may stall the device apply
-        # here; the watchdog must surface it
-        fault_point(SITE_APPLY_STALL, what="ingest_planes")
-        pp = w.prepacked
-        if pp is not None and getattr(self.store, "_iv_docs", None) \
-                and not self.store._iv_docs.isdisjoint(w.rows.tolist()):
-            # intervals appeared on a targeted row between prepack and
-            # apply (interval mutation racing the pipeline): fall back to
-            # the inline pack, which mints the per-op anchor handles
-            self.store._tab_release(pp)
-            pp = w.prepacked = None
-        self.store.apply_planes(
-            w.rows, w.kind_eff, w.a0, w.a1, w.seq_base, w.client,
-            w.ref_seq, w.text, min_seq=w.ms_arr, texts=w.texts,
-            tidx=w.tidx, props=w.props, min_ops=w.min_rs, prepacked=pp)
-        self._ensure_shard_collectors()
-        self._note_shard_ops(w.rows, counts=w.n_valid)
-        w.apply_stats = dict(getattr(self.store, "last_apply_stats",
-                                     None) or {})
-        if w.compact_due:
-            self._flushes_since_compact = 0
-            self.metrics.inc("compactions")
-            if self.mega_store is not None and self._mega_rows:
-                mms = np.zeros((self.mega_store.n_docs,), np.int32)
-                for doc_id, row in self._mega_rows.items():
-                    mms[row] = self._min_seq.get(doc_id, 0)
-                self.mega_store.compact(mms)
-            for doc_id, store in self._graduated.items():
-                store.compact(self._min_seq.get(doc_id, 0))
-            if self.auto_recover:
-                # DEFERRED overflow harvest: a synchronous flag read here
-                # would drain the dispatch pipeline (a device→host sync)
-                # at every compaction. Instead start an async device→host
-                # copy of the flags now and inspect the PREVIOUS
-                # compaction's copy (already landed) — detection is one
-                # compaction late, which only delays recovery (the log has
-                # every acked op).
-                w.ov_prev = self._ov_pending
-                # jnp.copy: the live overflow buffer is donated away by
-                # the next merge; the stash must own its storage
-                import jax.numpy as jnp
-                self._ov_pending = jnp.copy(self.store.state.overflow)
-                try:
-                    self._ov_pending.copy_to_host_async()
-                except (AttributeError, RuntimeError):
-                    pass
-        else:
-            self._flushes_since_compact += 1
-        w.marks["disp1"] = time.perf_counter()
+        with tracing.stage(w.marks, "engine.dispatch", mark="disp"):
+            # degradation injection: an armed plan may stall the device apply
+            # here; the watchdog must surface it
+            fault_point(SITE_APPLY_STALL, what="ingest_planes")
+            pp = w.prepacked
+            if pp is not None and getattr(self.store, "_iv_docs", None) \
+                    and not self.store._iv_docs.isdisjoint(w.rows.tolist()):
+                # intervals appeared on a targeted row between prepack and
+                # apply (interval mutation racing the pipeline): fall back to
+                # the inline pack, which mints the per-op anchor handles
+                self.store._tab_release(pp)
+                pp = w.prepacked = None
+            self.store.apply_planes(
+                w.rows, w.kind_eff, w.a0, w.a1, w.seq_base, w.client,
+                w.ref_seq, w.text, min_seq=w.ms_arr, texts=w.texts,
+                tidx=w.tidx, props=w.props, min_ops=w.min_rs, prepacked=pp,
+                rec=w.marks)
+            self._ensure_shard_collectors()
+            self._note_shard_ops(w.rows, counts=w.n_valid)
+            w.apply_stats = dict(getattr(self.store, "last_apply_stats",
+                                         None) or {})
+            if w.compact_due:
+                self._flushes_since_compact = 0
+                self.metrics.inc("compactions")
+                if self.mega_store is not None and self._mega_rows:
+                    mms = np.zeros((self.mega_store.n_docs,), np.int32)
+                    for doc_id, row in self._mega_rows.items():
+                        mms[row] = self._min_seq.get(doc_id, 0)
+                    self.mega_store.compact(mms)
+                for doc_id, store in self._graduated.items():
+                    store.compact(self._min_seq.get(doc_id, 0))
+                if self.auto_recover:
+                    # DEFERRED overflow harvest: a synchronous flag read here
+                    # would drain the dispatch pipeline (a device→host sync)
+                    # at every compaction. Instead start an async device→host
+                    # copy of the flags now and inspect the PREVIOUS
+                    # compaction's copy (already landed) — detection is one
+                    # compaction late, which only delays recovery (the log has
+                    # every acked op).
+                    w.ov_prev = self._ov_pending
+                    # jnp.copy: the live overflow buffer is donated away by
+                    # the next merge; the stash must own its storage
+                    import jax.numpy as jnp
+                    self._ov_pending = jnp.copy(self.store.state.overflow)
+                    try:
+                        self._ov_pending.copy_to_host_async()
+                    except (AttributeError, RuntimeError):
+                        pass
+            else:
+                self._flushes_since_compact += 1
 
     def _ingest_log(self, w: "_IngestWave") -> dict:
         """Stage 4 — the durable whole-batch append (ack barrier: poison
         clears and callers may ack only after this commits), metrics,
         attribution, watchdog."""
-        _t_apply = time.perf_counter()
-        ts = self.deli.clock()
-        R, O = w.R, w.O
-        rows, kind, nacked = w.rows, w.kind, w.nacked
-        out_seq, out_min = w.out_seq, w.out_min
-        text, texts, tidx, props = w.text, w.texts, w.tidx, w.props
-        rowidx = np.repeat(np.arange(R, dtype=np.int32), O)
-        ids = [self._row_doc_id[r] for r in rows]
-        flat_client = w.flat_client
-        ref_clamped = self._clamped_ref(w.flat_ref_seq, out_seq)
-        flat_tidx = None if tidx is None else np.ascontiguousarray(
-            np.asarray(tidx, np.int32).reshape(-1))
-        if not nacked.any():
-            # hot path: the whole batch is ONE ColumnarOps record (the
-            # Kafka-batch analog) — no partition sort, no per-field
-            # gathers; a doc's columnar history is reassembled seq-ordered
-            # at read (_doc_log_messages scans all partitions — recovery
-            # only). Copies detach the log from caller-owned planes.
-            self._append_columnar(ColumnarOps(
-                ids, rowidx, flat_client.copy(),
-                w.flat_client_seq.copy(), ref_clamped, out_seq, out_min,
-                kind.reshape(-1).copy(), w.a0.reshape(-1).copy(),
-                w.a1.reshape(-1).copy(), text=text, timestamp=ts,
-                texts=texts, props=props,
-                tidx=None if flat_tidx is None else flat_tidx.copy()))
-        else:
-            # nacked slots present (rare): group the survivors by doc
-            # partition with ONE stable sort, one record per partition
-            parts = np.repeat(self._row_part[rows], O)
-            ok_idx = np.flatnonzero(~nacked)
-            order = ok_idx[np.argsort(parts[ok_idx], kind="stable")]
-            p_sorted = parts[order]
-            bounds = np.searchsorted(
-                p_sorted, np.arange(self.log.n_partitions + 1))
-            fields = (flat_client, w.flat_client_seq, ref_clamped,
-                      out_seq, out_min, kind.reshape(-1),
-                      w.a0.reshape(-1), w.a1.reshape(-1))
-            gathered = tuple(f[order] for f in fields)
-            row_sorted = rowidx[order]
-            tidx_flat = None if flat_tidx is None else flat_tidx[order]
-            for p in range(self.log.n_partitions):
-                lo, hi = bounds[p], bounds[p + 1]
-                if lo == hi:
-                    continue
-                sl = slice(lo, hi)
-                self._fenced_append(int(p), ColumnarOps(
-                    ids, row_sorted[sl], *(g[sl] for g in gathered),
-                    text=text, timestamp=ts, texts=texts, props=props,
-                    tidx=None if tidx_flat is None else tidx_flat[sl]))
-            self._ingest_mark_logged()  # sequence → merge → log completed
-        # per-stage host wall (the throughput breakdown): C++ sequencing,
-        # plane prep + wire packing, async device dispatch, log append —
-        # device time itself is covered by the caller's end sync. In
-        # pipelined mode ``ingest_prepack_ms`` is the pack work that ran
-        # OFF the critical path (pack worker, overlapped with the
-        # previous wave's dispatch).
-        _t_log = time.perf_counter()
-        log_ms = (_t_log - _t_apply) * 1000
-        st = w.apply_stats
-        self.metrics.observe("ingest_seq_ms", w.seq_ms)
-        self.metrics.observe("ingest_pack_ms", st.get("pack_ms", 0.0))
-        self.metrics.observe("ingest_dispatch_ms",
-                             st.get("dispatch_ms", 0.0))
-        self.metrics.observe("ingest_prep_ms", w.prep_ms)
-        self.metrics.observe("ingest_log_ms", log_ms)
-        prepack_ms = st.get("prepack_ms", 0.0)
-        if prepack_ms:
-            self.metrics.observe("ingest_prepack_ms", prepack_ms)
-
-        if self._attributors is not None:
-            ok = ~nacked
-            for doc_local, s, c in zip(rowidx[ok], out_seq[ok],
-                                       flat_client[ok]):
-                self._attributor_of(ids[int(doc_local)]).record_raw(
-                    int(s), int(c), ts)
-        self.metrics.inc("flushes")
-        self.metrics.inc("ops_flushed", w.n_ok)
-        busy_ms = (w.seq_ms + w.prep_ms + st.get("pack_ms", 0.0)
-                   + prepack_ms + st.get("dispatch_ms", 0.0) + log_ms)
-        # pipelined waves sit in stage queues between workers; wall time
-        # since submission would count that waiting as a stall, so the
-        # watchdog judges the wave's BUSY time instead
-        elapsed_ms = busy_ms if w.pipelined \
-            else (time.perf_counter() - w.t_start) * 1000
-        self.metrics.observe("flush_ms", elapsed_ms)
-        tracing.TRACER.record_complete(
-            "serving.ingest_planes", elapsed_ms, ops=int(w.n_ok),
-            nacked=int(nacked.sum()), seq_ms=w.seq_ms,
-            pack_ms=st.get("pack_ms", 0.0),
-            dispatch_ms=st.get("dispatch_ms", 0.0), log_ms=log_ms)
-        self._watch_apply(elapsed_ms, "ingest_planes", w.n_ok)
-        # overflow harvest decision rides AFTER the durable append —
-        # recovery replays the LOG, so it must see this wave's record.
-        # Pipelined: defer to the executor's drain (other waves may still
-        # be sequencing on another thread).
-        if w.ov_prev is not None and np.asarray(w.ov_prev).any():
-            if w.pipelined:
-                self._ov_recover_due = True
+        with tracing.stage(w.marks, "engine.log", mark="log") as sp:
+            ts = self.deli.clock()
+            R, O = w.R, w.O
+            rows, kind, nacked = w.rows, w.kind, w.nacked
+            out_seq, out_min = w.out_seq, w.out_min
+            text, texts, tidx, props = w.text, w.texts, w.tidx, w.props
+            rowidx = np.repeat(np.arange(R, dtype=np.int32), O)
+            ids = [self._row_doc_id[r] for r in rows]
+            flat_client = w.flat_client
+            ref_clamped = self._clamped_ref(w.flat_ref_seq, out_seq)
+            flat_tidx = None if tidx is None else np.ascontiguousarray(
+                np.asarray(tidx, np.int32).reshape(-1))
+            if not nacked.any():
+                # hot path: the whole batch is ONE ColumnarOps record (the
+                # Kafka-batch analog) — no partition sort, no per-field
+                # gathers; a doc's columnar history is reassembled seq-ordered
+                # at read (_doc_log_messages scans all partitions — recovery
+                # only). Copies detach the log from caller-owned planes.
+                record = ColumnarOps(
+                    ids, rowidx, flat_client.copy(),
+                    w.flat_client_seq.copy(), ref_clamped, out_seq, out_min,
+                    kind.reshape(-1).copy(), w.a0.reshape(-1).copy(),
+                    w.a1.reshape(-1).copy(), text=text, timestamp=ts,
+                    texts=texts, props=props,
+                    tidx=None if flat_tidx is None else flat_tidx.copy())
+                with tracing.stage(w.marks, "log.append") as sp_app:
+                    self._append_columnar(record)
             else:
-                self.recover_overflowed()
-        n_dup = int(getattr(w, "dup_acked", 0) or 0)
-        # read plane (ISSUE 20): the columnar window is durable — pump
-        # one encoded observer window at ingest pace (the fast path
-        # never passes through flush()/_after_flush)
-        plane = self._read_plane
-        if plane is not None and w.n_ok:
-            plane.pump()
-        w.marks["log1"] = time.perf_counter()
-        return {"seq": w.seq_rs, "nacked": int(nacked.sum()) - n_dup,
-                "dup_acked": n_dup, "marks": w.marks}
+                # nacked slots present (rare): group the survivors by doc
+                # partition with ONE stable sort, one record per partition
+                parts = np.repeat(self._row_part[rows], O)
+                ok_idx = np.flatnonzero(~nacked)
+                order = ok_idx[np.argsort(parts[ok_idx], kind="stable")]
+                p_sorted = parts[order]
+                bounds = np.searchsorted(
+                    p_sorted, np.arange(self.log.n_partitions + 1))
+                fields = (flat_client, w.flat_client_seq, ref_clamped,
+                          out_seq, out_min, kind.reshape(-1),
+                          w.a0.reshape(-1), w.a1.reshape(-1))
+                gathered = tuple(f[order] for f in fields)
+                row_sorted = rowidx[order]
+                tidx_flat = None if flat_tidx is None else flat_tidx[order]
+                with tracing.stage(w.marks, "log.append") as sp_app:
+                    for p in range(self.log.n_partitions):
+                        lo, hi = bounds[p], bounds[p + 1]
+                        if lo == hi:
+                            continue
+                        sl = slice(lo, hi)
+                        self._fenced_append(int(p), ColumnarOps(
+                            ids, row_sorted[sl], *(g[sl] for g in gathered),
+                            text=text, timestamp=ts, texts=texts, props=props,
+                            tidx=None if tidx_flat is None else tidx_flat[sl]))
+                    # sequence → merge → log completed
+                    self._ingest_mark_logged()
+            # per-stage host wall (the throughput breakdown): C++ sequencing,
+            # plane prep + wire packing, async device dispatch, log append —
+            # device time itself is covered by the caller's end sync. In
+            # pipelined mode ``ingest_prepack_ms`` is the pack work that ran
+            # OFF the critical path (pack worker, overlapped with the
+            # previous wave's dispatch).
+            log_ms = (sp_app.t1 - sp.t0) * 1000
+            st = w.apply_stats
+            self.metrics.observe("ingest_seq_ms", w.seq_ms)
+            self.metrics.observe("ingest_pack_ms", st.get("pack_ms", 0.0))
+            self.metrics.observe("ingest_dispatch_ms",
+                                 st.get("dispatch_ms", 0.0))
+            self.metrics.observe("ingest_prep_ms", w.prep_ms)
+            self.metrics.observe("ingest_log_ms", log_ms)
+            prepack_ms = st.get("prepack_ms", 0.0)
+            if prepack_ms:
+                self.metrics.observe("ingest_prepack_ms", prepack_ms)
+
+            if self._attributors is not None:
+                ok = ~nacked
+                for doc_local, s, c in zip(rowidx[ok], out_seq[ok],
+                                           flat_client[ok]):
+                    self._attributor_of(ids[int(doc_local)]).record_raw(
+                        int(s), int(c), ts)
+            self.metrics.inc("flushes")
+            self.metrics.inc("ops_flushed", w.n_ok)
+            busy_ms = (w.seq_ms + w.prep_ms + st.get("pack_ms", 0.0)
+                       + prepack_ms + st.get("dispatch_ms", 0.0) + log_ms)
+            # pipelined waves sit in stage queues between workers; wall time
+            # since submission would count that waiting as a stall, so the
+            # watchdog judges the wave's BUSY time instead
+            elapsed_ms = busy_ms if w.pipelined \
+                else (time.perf_counter() - w.marks["pack0"]) * 1000
+            self.metrics.observe("flush_ms", elapsed_ms)
+            self._watch_apply(elapsed_ms, "ingest_planes", w.n_ok)
+            # overflow harvest decision rides AFTER the durable append —
+            # recovery replays the LOG, so it must see this wave's record.
+            # Pipelined: defer to the executor's drain (other waves may still
+            # be sequencing on another thread).
+            if w.ov_prev is not None and np.asarray(w.ov_prev).any():
+                if w.pipelined:
+                    self._ov_recover_due = True
+                else:
+                    self.recover_overflowed()
+            n_dup = int(getattr(w, "dup_acked", 0) or 0)
+            # read plane (ISSUE 20): the columnar window is durable — pump
+            # one encoded observer window at ingest pace (the fast path
+            # never passes through flush()/_after_flush)
+            plane = self._read_plane
+            if plane is not None and w.n_ok:
+                plane.pump()
+            result = {"seq": w.seq_rs, "nacked": int(nacked.sum()) - n_dup,
+                      "dup_acked": n_dup, "marks": w.marks}
+        return result
 
     # ----------------------------------------------------------- device side
 

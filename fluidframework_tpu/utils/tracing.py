@@ -34,7 +34,14 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional
 
-from .telemetry import PerformanceEvent, TelemetryLogger
+from .telemetry import REGISTRY, MetricsRegistry, PerformanceEvent, \
+    TelemetryLogger
+
+#: every stamp in this module is ``time.perf_counter()``: the clock the
+#: window timeline, the executor and the engine's stages already use.
+#: The wall-clock anchor is taken once, here, and only ``chrome_event``
+#: adds it (Chrome/Perfetto want epoch microseconds).
+_EPOCH_US = (time.time() - time.perf_counter()) * 1e6
 
 
 class TraceContext:
@@ -85,7 +92,7 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
-        self._ts_us = time.time() * 1e6
+        self._ts_us = time.perf_counter() * 1e6
         self._pe.__enter__()
         self.tracer._push(self.ctx)
         return self
@@ -229,7 +236,7 @@ class Tracer:
         else:
             ctx = TraceContext(parent.trace_id, next(self._span_ids))
             parent_id = parent.span_id
-        now_us = time.time() * 1e6
+        now_us = time.perf_counter() * 1e6
         self._record({
             "name": name, "trace_id": ctx.trace_id,
             "span_id": ctx.span_id, "parent_id": parent_id,
@@ -237,6 +244,33 @@ class Tracer:
             "tid": threading.get_ident(), "args": args,
         })
         return ctx
+
+    def record_window(self, wid: int, t0: float, t1: float,
+                      spans: Iterable[tuple], **args: Any
+                      ) -> Optional[TraceContext]:
+        """Append one door window's whole record as one trace (id
+        ``w<wid>``): a root ``window`` span from ``t0`` to ``t1`` and one
+        child per ``(name, start, end)`` stamp (``perf_counter`` seconds),
+        parented by :data:`PARENTS`. Returns the root's context — the
+        window's exemplar."""
+        if not self.enabled:
+            return None
+        tid, trace_id = threading.get_ident(), f"w{wid}"
+        root = TraceContext(trace_id, next(self._span_ids))
+        self._record({"name": "window", "trace_id": trace_id,
+                      "span_id": root.span_id, "parent_id": None,
+                      "ts": t0 * 1e6, "dur": (t1 - t0) * 1e6, "tid": tid,
+                      "args": dict(args, wid=wid)})
+        latest: Dict[str, int] = {}
+        # parents open before their children but close after them
+        for name, a, b in sorted(spans, key=lambda sp: (sp[1], -sp[2])):
+            sid = latest[name] = next(self._span_ids)
+            self._record({"name": name, "trace_id": trace_id,
+                          "span_id": sid, "tid": tid, "args": {},
+                          "parent_id": latest.get(PARENTS.get(name),
+                                                  root.span_id),
+                          "ts": a * 1e6, "dur": (b - a) * 1e6})
+        return root
 
     def events(self, trace_id: Optional[str] = None) -> List[dict]:
         evs = list(self._events)
@@ -272,7 +306,7 @@ class Tracer:
 def chrome_event(e: dict) -> dict:
     return {
         "ph": "X", "name": e["name"], "cat": "op",
-        "ts": e["ts"], "dur": e["dur"],
+        "ts": e["ts"] + _EPOCH_US, "dur": e["dur"],
         "pid": os.getpid(), "tid": e["tid"],
         "args": {"trace_id": e["trace_id"], "span_id": e["span_id"],
                  "parent_id": e["parent_id"],
@@ -284,6 +318,190 @@ def chrome_event(e: dict) -> dict:
 def _arg(v: Any) -> Any:
     return v if isinstance(v, (int, float, str, bool, type(None))) \
         else repr(v)
+
+
+# ---------------------------------------------------------------------
+# the window record: one per door window, stamped where the work happens
+# ---------------------------------------------------------------------
+#
+# A record is a plain dict: ids (``wid``, ``pid``) and counts beside
+# ``spans``, a list of ``(name, start, end)`` stamps. The door makes one
+# per drain pass and one per window; the window's rides the wave through
+# the executor as its ``marks`` (the crossings ``observe_window_timeline``
+# reads are keys of the same dict). ``stage`` stamps work, ``wait`` stamps
+# a wait once it has ended, ``close_window`` files the finished record.
+
+#: a span whose own time (less its children's) reaches this is "long":
+#: by the ledger (PR 24) a window's stages take 2-6 ms each in both cells
+LONG_S = 0.050
+#: 1 window in this many is kept in the ring whatever it held
+KEEP_EVERY = 256
+
+#: static nesting, child → parent: a span's self time is its duration
+#: less that of the children stamped into the same record meanwhile
+PARENTS = {
+    "door.decode": "door.drain", "door.admit": "door.decode",
+    "deli.sequence": "engine.sequence",
+    "store.apply_planes": "engine.dispatch",
+    "store.pack": "store.apply_planes",
+    "store.upload": "store.apply_planes",
+    "store.unpack_dispatch": "store.apply_planes",
+    "store.merge_dispatch": "store.apply_planes",
+    "store.slide_docs": "store.apply_planes",
+    "log.append": "engine.log",
+}
+#: work, entered as ``TraceAnnotation("fluid.<name>")``
+SPANS = ("door.drain", "door.decode", "door.admit", "door.build_windows",
+         "door.submit", "engine.prepare", "engine.sequence",
+         "deli.sequence", "engine.dispatch", "store.apply_planes",
+         "store.pack", "store.upload", "store.unpack_dispatch",
+         "store.merge_dispatch", "store.slide_docs", "engine.log",
+         "log.append", "door.fan_acks")
+#: waits, known only when they end: stamped, never annotated
+WAITS = ("door.tick_wait", "door.rx_wait", "door.capacity_wait",
+         "executor.pack_wait", "executor.seq_wait", "executor.log_wait",
+         "door.ack_bounce", "door.tx_wait")
+#: waits that are backpressure by design: however long, they do not make
+#: a window a slow one
+BACKPRESSURE = frozenset(WAITS[:3])
+#: the table's rows; ``window`` is the whole rx → ack-fanned time
+TABLE_NAMES = SPANS + WAITS + ("window",)
+
+#: the process table, one collector of the registry (``/metrics`` and
+#: ``full_snapshot()`` carry it): per name seconds ``.s``, instances
+#: ``.n``, and ``.long_s``/``.long_n`` of the instances whose own time
+#: reached ``LONG_S``. Every key exists from import on.
+SPAN_TABLE = MetricsRegistry()
+_KEYS = {n: (f"{n}.s", f"{n}.n", f"{n}.long_s", f"{n}.long_n")
+         for n in TABLE_NAMES}
+SPAN_TABLE.counters.update({k: 0.0 for ks in _KEYS.values() for k in ks})
+REGISTRY.attach("spans", SPAN_TABLE)
+_table_lock = threading.Lock()
+_ANN_NAMES = {n: "fluid." + n for n in SPANS}
+#: the newest closed window records, whole (what a traced run dumps)
+RECENT: deque = deque(maxlen=4096)
+_annotation = None      # jax.profiler.TraceAnnotation, on first use
+
+
+def _tabulate(rec: Optional[dict], name: str, dur: float,
+              own: float) -> None:
+    ks, c = _KEYS[name], SPAN_TABLE.counters
+    with _table_lock:
+        c[ks[0]] += dur
+        c[ks[1]] += 1
+        if own >= LONG_S:
+            c[ks[2]] += own
+            c[ks[3]] += 1
+    if own >= LONG_S and rec is not None and name not in BACKPRESSURE:
+        rec.setdefault("long", []).append(name)
+
+
+def new_record(**ids: Any) -> dict:
+    return dict(ids, spans=[])
+
+
+class stage:
+    """``with stage(rec, "store.pack"): ...`` — one span of work: start
+    and end stamped into ``rec`` (and, with ``mark="pack"``, as the
+    crossings ``rec["pack0"]``/``rec["pack1"]``), the same interval
+    entered as a profiler annotation carrying the record's ``wid``, its
+    duration added to the table. A few microseconds while no profile is
+    being taken (2.4 on the sandbox's CPU); there is no other switch."""
+
+    __slots__ = ("rec", "name", "mark", "t0", "t1", "_first", "_ann")
+
+    def __init__(self, rec: dict, name: str, mark: Optional[str] = None):
+        self.rec, self.name, self.mark = rec, name, mark
+
+    def __enter__(self) -> "stage":
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        rec = self.rec
+        self._first = len(rec["spans"])
+        ann = self._ann = _annotation(_ANN_NAMES[self.name],
+                                      wid=rec.get("wid", -1))
+        ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        t1 = self.t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        rec, name, t0 = self.rec, self.name, self.t0
+        spans = rec["spans"]
+        own = t1 - t0
+        if len(spans) > self._first:
+            for child, a, b in spans[self._first:]:
+                if PARENTS.get(child) == name:
+                    own -= b - a
+        spans.append((name, t0, t1))
+        if self.mark is not None:
+            rec[self.mark + "0"], rec[self.mark + "1"] = t0, t1
+        _tabulate(rec, name, t1 - t0, own)
+
+    @property
+    def ms(self) -> float:
+        return (self.t1 - self.t0) * 1e3
+
+
+def wait(rec: Optional[dict], name: str, t0: float, t1: float,
+         mark: Optional[str] = None) -> None:
+    """Stamp a wait that has just ended (``rec`` None: the table alone,
+    for waits tied to no window)."""
+    if rec is not None:
+        rec["spans"].append((name, t0, t1))
+        if mark is not None:
+            rec[mark] = t1
+    _tabulate(rec, name, t1 - t0, t1 - t0)
+
+
+def close_window(rec: dict, tl: dict, t_ack: float
+                 ) -> Optional[TraceContext]:
+    """File a window whose acks have been fanned: the table's ``window``
+    row, the ring of recent records, and, where the window or its drain
+    pass held a long span (or for 1 window in ``KEEP_EVERY``), the whole
+    record as one trace in the tracer's ring. Returns that trace's
+    context, the exemplar of ``stage_e2e_ack_ms``."""
+    t_rx = tl["t_rx"]
+    _tabulate(None, "window", t_ack - t_rx, t_ack - t_rx)
+    rec["t_rx"], rec["t_ack"], rec["pass"] = t_rx, t_ack, tl
+    RECENT.append(rec)
+    wid = rec.get("wid", 0)
+    long = rec.get("long", []) + tl.get("long", [])
+    if not long and wid % KEEP_EVERY:
+        return None
+    return TRACER.record_window(
+        wid, t_rx, t_ack, tl.get("spans", []) + rec["spans"],
+        ops=rec.get("ops"), pid=tl.get("pid"), long=",".join(long))
+
+
+def clock_mark() -> None:
+    """Emit ``TraceAnnotation("fluid.clock", perf_counter_ns=...)``: a
+    reader of the profiler's trace maps this module's stamps onto the
+    trace's clock through two of them, one at each end of the trace."""
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation("fluid.clock",
+                         perf_counter_ns=time.perf_counter_ns()):
+        pass
+
+
+def name_os_thread(name: str) -> None:
+    """Name the calling thread for the OS (Linux ``prctl(PR_SET_NAME)``,
+    15 characters; silent elsewhere), so that a profiler's host lines
+    read ``fluid-seq`` and not ``python3``: ``threading.Thread(name=)``
+    does not reach the OS on Python 3.12."""
+    try:
+        import ctypes
+        libc = ctypes.CDLL(None, use_errno=True)
+        libc.prctl.argtypes = [ctypes.c_int, ctypes.c_char_p,
+                               ctypes.c_ulong, ctypes.c_ulong,
+                               ctypes.c_ulong]
+        libc.prctl.restype = ctypes.c_int
+        libc.prctl(15, name.encode()[:15], 0, 0, 0)     # PR_SET_NAME
+    except (OSError, AttributeError):
+        pass
 
 
 #: the process tracer — all layers record here
