@@ -35,7 +35,8 @@ from __future__ import annotations
 import math
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, Optional,
+                    Tuple)
 
 from ..utils import capacity as _capacity
 from ..utils import flight_recorder as _flight
@@ -152,6 +153,18 @@ def latency_breakdown(registry: Optional[MetricsRegistry] = None) -> dict:
 # heavy-hitter sketch
 # --------------------------------------------------------------------------
 
+class _Bucket:
+    """The tracked keys that share one estimated count: a node of the
+    stream-summary's ascending list."""
+    __slots__ = ("count", "keys", "prev", "next")
+
+    def __init__(self, count):
+        self.count = count
+        #: key -> err, in the order the keys reached this count
+        self.keys: Dict[Any, int] = {}
+        self.prev = self.next = self
+
+
 class SpaceSaving:
     """Bounded Space-Saving heavy-hitter sketch (Metwally et al. 2005).
 
@@ -161,38 +174,85 @@ class SpaceSaving:
     exceeds ``total / capacity`` is guaranteed to be tracked — exactly
     the guarantee a hot-doc router or eviction policy needs. Thread-safe:
     the drain pass offers from the ingress loop, the ops endpoint reads
-    from scrape threads."""
+    from scrape threads.
+
+    The entries live in the paper's stream-summary: keys grouped in
+    buckets by count, the buckets in a ring in ascending order. A miss
+    against a full sketch (the ordinary case of a door that serves more
+    documents than ``capacity``; ``evictions`` counts them) takes any
+    key of the first bucket as its victim, and every offer moves its key
+    forward past at most the buckets between ``count`` and
+    ``count + n``: O(1) for a unit offer, never a scan of the entries."""
 
     def __init__(self, capacity: int = 256):
         self.capacity = max(1, int(capacity))
-        #: key -> [count, err]
-        self._entries: Dict[Any, List[int]] = {}
+        #: key -> the bucket that holds it
+        self._entries: Dict[Any, _Bucket] = {}
+        #: the ring's sentinel: ``next`` is the minimum bucket, ``prev``
+        #: the maximum; its infinite count ends every forward walk
+        self._root = _Bucket(math.inf)
         self.total = 0
+        #: misses against a full sketch, each of which replaced a
+        #: minimum-count key; beside ``total``, how saturated it is
+        self.evictions = 0
         self._lock = threading.Lock()
+
+    def _offer(self, key: Any, n: int) -> None:
+        """One offer, the lock held."""
+        entries, root = self._entries, self._root
+        self.total += n
+        src = entries.get(key)
+        if src is not None:
+            count, err = src.count + n, src.keys.pop(key)
+        elif len(entries) < self.capacity:
+            src, count, err = root, n, 0
+        else:
+            # evict a key of the minimum bucket; the newcomer inherits
+            # its count as the overestimation bound
+            src = root.next
+            del entries[src.keys.popitem()[0]]
+            count, err = src.count + n, src.count
+            self.evictions += 1
+        dst = src.next
+        while dst.count < count:
+            dst = dst.next
+        if not src.keys and src is not root:
+            if dst is src.next and dst.count != count:
+                src.count = count       # alone, the gap free: in place
+                dst = src
+            else:
+                src.prev.next, src.next.prev = src.next, src.prev
+        if dst.count != count:
+            nxt, dst = dst, _Bucket(count)
+            dst.prev, dst.next = nxt.prev, nxt
+            nxt.prev.next = nxt.prev = dst
+        dst.keys[key] = err
+        entries[key] = dst
 
     def offer(self, key: Any, n: int = 1) -> None:
         with self._lock:
-            self.total += n
-            e = self._entries.get(key)
-            if e is not None:
-                e[0] += n
-                return
-            if len(self._entries) < self.capacity:
-                self._entries[key] = [n, 0]
-                return
-            # evict the current minimum; the newcomer inherits its count
-            # as the overestimation bound
-            victim = min(self._entries, key=lambda k: self._entries[k][0])
-            floor = self._entries.pop(victim)[0]
-            self._entries[key] = [floor + n, floor]
+            self._offer(key, n)
+
+    def offer_many(self, keys: Iterable[Any], counts: Iterable[int]
+                   ) -> None:
+        """``offer(key, n)`` for each pair in order, under one hold of
+        the lock: a drain pass's part in one call."""
+        offer = self._offer
+        with self._lock:
+            for key, n in zip(keys, counts):
+                offer(key, n)
 
     def top(self, k: int = 10) -> List[Tuple[Any, int, int]]:
         """``(key, estimated_count, err)`` rows, largest first.
         ``estimated_count - err`` is a guaranteed lower bound."""
+        rows: List[Tuple[Any, int, int]] = []
         with self._lock:
-            rows = sorted(self._entries.items(),
-                          key=lambda kv: kv[1][0], reverse=True)
-        return [(key, e[0], e[1]) for key, e in rows[:k]]
+            b = self._root.prev
+            while b is not self._root and len(rows) < k:
+                rows.extend((key, b.count, err)
+                            for key, err in b.keys.items())
+                b = b.prev
+        return rows[:k]
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -200,15 +260,18 @@ class SpaceSaving:
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
+            self._root.prev = self._root.next = self._root
             self.total = 0
+            self.evictions = 0
 
 
 def publish_hotdoc_gauges(sketches: List[SpaceSaving],
                           registry: Optional[MetricsRegistry] = None
                           ) -> None:
     """Roll the attached sketches up into the ``hotdoc_*`` gauges: how
-    many keys are tracked, the hottest key's estimated ops, and its
-    share of all sketched traffic — the skew signal at a glance."""
+    many keys are tracked, how many offers evicted one, the hottest
+    key's estimated ops, and its share of all sketched traffic — the
+    skew signal at a glance."""
     reg = registry if registry is not None else REGISTRY
     tracked = sum(len(s) for s in sketches)
     total = sum(s.total for s in sketches)
@@ -218,6 +281,8 @@ def publish_hotdoc_gauges(sketches: List[SpaceSaving],
         if rows:
             top = max(top, rows[0][1])
     reg.set_gauge("hotdoc_tracked", float(tracked))
+    reg.set_gauge("hotdoc_evictions",
+                  float(sum(s.evictions for s in sketches)))
     reg.set_gauge("hotdoc_top_count", float(top))
     reg.set_gauge("hotdoc_top_share", top / total if total else 0.0)
 
@@ -371,6 +436,7 @@ class OpsServer:
             "capacity": sum(s.capacity for s in self._sketches),
             "tracked": sum(len(s) for s in self._sketches),
             "total_ops": sum(s.total for s in self._sketches),
+            "evictions": sum(s.evictions for s in self._sketches),
             "top": [{"doc": key[0], "tenant": key[1],
                      "count": count, "err": err}
                     if isinstance(key, tuple) and len(key) == 2 else

@@ -17,6 +17,7 @@ import json
 import os
 import random
 import re
+import sys
 import threading
 import time
 import urllib.request
@@ -198,49 +199,152 @@ class TestStageAttribution:
 
 # ------------------------------------------------------- space-saving
 
+def _check_contract(sk, exact):
+    """The Space-Saving contract against exact counts, and the sketch's
+    own invariants; returns ``{key: (est, err)}``."""
+    total = sum(exact.values())
+    assert sk.total == total
+    rows = {key: (est, err) for key, est, err in sk.top(sk.capacity)}
+    assert len(rows) == len(sk) <= sk.capacity
+    for key, (est, err) in rows.items():
+        true = exact.get(key, 0)
+        # est overestimates by <= err
+        assert true <= est <= true + err, (key, true, est, err)
+    # every key above the total/capacity threshold IS tracked
+    for key, true in exact.items():
+        if true > total / sk.capacity:
+            assert key in rows, (key, true, total / sk.capacity)
+    # every offered op sits in exactly one counter, and a full sketch's
+    # minimum (the next victim's floor) cannot exceed the mean
+    assert sum(est for est, _ in rows.values()) == total
+    if len(rows) == sk.capacity:
+        assert min(est for est, _ in rows.values()) <= total / sk.capacity
+    ests = [est for _, est, _ in sk.top(sk.capacity)]
+    assert ests == sorted(ests, reverse=True)
+    return rows
+
+
+class _CountedKey:
+    """A key that counts its ``__hash__`` calls: what an offer costs in
+    the sketch's own terms, no clock involved."""
+    hashes = 0
+
+    def __init__(self, i):
+        self.i = i
+
+    def __hash__(self):
+        _CountedKey.hashes += 1
+        return hash(self.i)
+
+    def __eq__(self, other):
+        return self.i == other.i
+
+
 class TestSpaceSaving:
-    def test_zipf_accuracy_vs_exact_counts(self):
+    @pytest.mark.parametrize("capacity", [8, 64, 256])
+    @pytest.mark.parametrize("weight", [1, 4, "mixed"])
+    def test_zipf_accuracy_vs_exact_counts(self, weight, capacity):
         rng = random.Random(7)
-        n_keys, capacity, draws = 400, 64, 30_000
+        n_keys, draws = 400, 30_000
         weights = [1.0 / (k + 1) ** 1.2 for k in range(n_keys)]
         sk = SpaceSaving(capacity=capacity)
         exact = {}
         for _ in range(draws):
             key = rng.choices(range(n_keys), weights=weights)[0]
-            exact[key] = exact.get(key, 0) + 1
+            n = rng.choice((1, 2, 4, 7)) if weight == "mixed" else weight
+            exact[key] = exact.get(key, 0) + n
+            sk.offer(key, n)
+        assert len(sk) == capacity
+        assert sk.evictions > 0
+        _check_contract(sk, exact)
+        # the sketch's top-10 contains the true top-5 heavy hitters (a
+        # sketch of 8 owes only the keys above total/8, held above: the
+        # fifth key's share of this stream is below that)
+        if capacity >= 64:
+            true_top5 = sorted(exact, key=exact.get, reverse=True)[:5]
+            sketch_top10 = [key for key, _, _ in sk.top(10)]
+            assert set(true_top5) <= set(sketch_top10)
+
+    def test_all_miss_stream_evicts_on_every_offer(self):
+        # the door's ordinary case: far more documents than entries
+        sk = SpaceSaving(capacity=256)
+        keys = [(f"doc-{i}", f"client-{i % 8}") for i in range(10_240)]
+        exact = {}
+        for _ in range(3):
+            for key in keys:
+                exact[key] = exact.get(key, 0) + 4
+                sk.offer(key, 4)
+        assert len(sk) == 256
+        assert sk.evictions == 3 * len(keys) - 256
+        _check_contract(sk, exact)
+
+    def test_a_miss_costs_a_few_hashes_not_a_scan(self):
+        sk = SpaceSaving(capacity=256)
+        for i in range(256):
+            sk.offer(_CountedKey(i), 1 + i % 3)
+        assert len(sk) == 256
+        missing = [_CountedKey(1000 + i) for i in range(1000)]
+        _CountedKey.hashes = 0
+        for key in missing[:500]:
             sk.offer(key)
-        assert sk.total == draws and len(sk) == capacity
-        rows = {key: (est, err) for key, est, err in sk.top(capacity)}
-        for key, (est, err) in rows.items():
-            true = exact.get(key, 0)
-            # the Space-Saving contract: est overestimates by <= err
-            assert true <= est <= true + err, (key, true, est, err)
-        # every key above the total/capacity threshold IS tracked
-        threshold = draws / capacity
-        for key, true in exact.items():
-            if true > threshold:
-                assert key in rows, (key, true, threshold)
-        # the sketch's top-10 contains the true top-5 heavy hitters
-        true_top5 = sorted(exact, key=exact.get, reverse=True)[:5]
-        sketch_top10 = [key for key, _, _ in sk.top(10)]
-        assert set(true_top5) <= set(sketch_top10)
+        sk.offer_many(missing[500:], [1 + i % 4 for i in range(500)])
+        assert sk.evictions == 1000 and len(sk) == 256
+        # a scan of the entries for their minimum is 256 hashes an offer
+        assert _CountedKey.hashes <= 8 * 1000, _CountedKey.hashes
+
+    @pytest.mark.parametrize("capacity", [8, 256])
+    def test_offer_many_equals_the_offers_in_sequence(self, capacity):
+        rng = random.Random(11)
+        keys = [("doc-%d" % int(rng.paretovariate(0.5)), "t%d" % (i % 2))
+                for i in range(6000)]
+        counts = [rng.choice((1, 1, 3, 8)) for _ in keys]
+        one, many = SpaceSaving(capacity), SpaceSaving(capacity)
+        exact = {}
+        for key, n in zip(keys, counts):
+            one.offer(key, n)
+            exact[key] = exact.get(key, 0) + n
+        for i in range(0, len(keys), 750):       # a part at a time
+            many.offer_many(keys[i:i + 750], iter(counts[i:i + 750]))
+        assert many.total == one.total == sum(counts)
+        assert many.evictions == one.evictions > 0
+        assert many.top(capacity) == one.top(capacity)
+        _check_contract(many, exact)
 
     def test_bounded_memory_and_concurrent_offers(self):
         sk = SpaceSaving(capacity=16)
+        seen = []
+
         def pound(seed):
             r = random.Random(seed)
-            for _ in range(5000):
+            for i in range(1000):
                 sk.offer(("doc-%d" % r.randrange(200), "t"))
+                part = [("doc-%d" % r.randrange(200), "t")
+                        for _ in range(4)]
+                sk.offer_many(part, [1, 2, 3, 4])
+                if i % 50 == 0:
+                    rows = sk.top(16)
+                    seen.append(len(rows) <= 16 and
+                                [c for _, c, _ in rows] == sorted(
+                                    (c for _, c, _ in rows), reverse=True))
         threads = [threading.Thread(target=pound, args=(s,))
                    for s in range(4)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert sk.total == 4 * 5000
-        assert len(sk) <= 16
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        assert sk.total == 4 * 1000 * 11
+        assert len(sk) == 16 and all(seen) and len(seen) == 4 * 20
+        # a lost update would leave an op outside every counter
+        assert sum(c for _, c, _ in sk.top(16)) == sk.total
         sk.clear()
-        assert len(sk) == 0 and sk.total == 0
+        assert len(sk) == 0 and sk.total == 0 and sk.evictions == 0
+        assert sk.top(16) == []
 
 
 # ------------------------------------------------------- the endpoint
@@ -294,7 +398,8 @@ needs_native = pytest.mark.skipif(not native_deli.available(),
 
 @needs_native
 class TestScrapeUnderIngestStorm:
-    def test_live_scrape_during_columnar_storm(self):
+    @pytest.mark.parametrize("sketch_capacity", [256, 8])
+    def test_live_scrape_during_columnar_storm(self, sketch_capacity):
         from fluidframework_tpu.server.columnar_ingress import (
             ColumnarAlfred, ColumnarClient, _OP_DTYPE,
         )
@@ -303,7 +408,10 @@ class TestScrapeUnderIngestStorm:
                                   batch_window=10 ** 9,
                                   sequencer="native")
         srv = ColumnarAlfred(eng, window_min_rows=4,
-                             window_ms=2.0).start_in_thread()
+                             window_ms=2.0)
+        # 8: more (doc, tenant) keys than entries, a door's ordinary case
+        srv.hotdocs.capacity = sketch_capacity
+        srv.start_in_thread()
         ops = srv.start_ops(tick_interval_s=0.1)
         routes = ("/metrics", "/healthz", "/debug/hotdocs",
                   "/debug/latency", "/debug/flights", "/debug/trace")
@@ -366,13 +474,35 @@ class TestScrapeUnderIngestStorm:
             assert abs(bd["stage_sum_ms"] - bd["e2e_mean_ms"]) \
                 <= 0.10 * bd["e2e_mean_ms"]
             assert set(bd["stages"]) == set(STAGES)
-            # the drain-pass sketch saw exactly the ingested ops (all
-            # (doc, tenant) keys fit: no evictions, err == 0)
+            # the drain-pass sketch saw exactly the ingested ops, and
+            # every one of them sits in some entry's count
             hot = json.loads(_get(ops.url + "/debug/hotdocs?k=64")[2])
-            assert hot["total_ops"] == srv.ops_ingested
+            assert hot["total_ops"] == srv.ops_ingested \
+                == n_clients * docs_per * waves
             assert sum(r["count"] for r in hot["top"]) \
                 == srv.ops_ingested
-            assert all(r["err"] == 0 for r in hot["top"])
+            assert hot["capacity"] == sketch_capacity
+            n_keys = n_clients * docs_per
+            if n_keys <= sketch_capacity:
+                # all (doc, tenant) keys fit: no evictions, err == 0
+                assert hot["tracked"] == n_keys
+                assert hot["evictions"] == 0
+                assert all(r["err"] == 0 for r in hot["top"])
+            else:
+                assert hot["tracked"] == sketch_capacity
+                assert hot["evictions"] >= n_keys - sketch_capacity
+                assert all(r["count"] - r["err"] <= waves
+                           for r in hot["top"])
+            # the ticker publishes the counter beside hotdoc_tracked
+            want = f"hotdoc_evictions {float(hot['evictions'])}"
+            deadline = time.time() + 5.0
+            while time.time() < deadline:
+                metrics = _get(ops.url + "/metrics")[2].decode()
+                if want in metrics.splitlines():
+                    break
+                time.sleep(0.05)
+            assert want in metrics.splitlines(), [
+                ln for ln in metrics.splitlines() if "hotdoc" in ln]
         finally:
             stop.set()
             srv.stop()
