@@ -68,6 +68,19 @@ def dropped_annotates(door, engine, log, armed):
     _nth_call(armed, engine, "_ingest_dispatch", before=blank, onward=True)
 
 
+def unsharded_state(door, engine, log, armed):
+    """As the window opens the store's planes are gathered onto one chip
+    and its mesh is dropped: every later window merges there, every
+    answer stays right, and the placement the configuration states is
+    gone. A fault only a cell on several chips can have."""
+    def gather(w):
+        import jax
+        store = engine.store
+        store.state = jax.device_put(store.state, jax.devices()[0])
+        store.mesh = None
+    _nth_call(armed, engine, "_ingest_dispatch", before=gather, n=1)
+
+
 PLANTS = {f.__name__: f for f in (unapplied_window, half_window,
                                   skipped_append, altered_ack,
-                                  dropped_annotates)}
+                                  dropped_annotates, unsharded_state)}

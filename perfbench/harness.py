@@ -137,6 +137,17 @@ def run_cell(cell: dict, config: dict, traffic: dict, files: dict, *,
         os.sched_setaffinity(0, all_cores)
 
 
+def sharded_over(state, devs, n_docs: int) -> bool:
+    """Whether every plane of a store's state lies on exactly these
+    devices, ``n_docs / len(devs)`` document rows on each."""
+    import jax
+    want, rows = sorted(d.id for d in devs), n_docs // len(devs)
+    return all(
+        sorted(sh.device.id for sh in x.addressable_shards) == want
+        and all(sh.data.shape[0] == rows for sh in x.addressable_shards)
+        for x in jax.tree.leaves(state))
+
+
 def _run(cell, config, traffic, gen, srv_cores, gen_cores, *, seed, seconds,
          trace_on, t_start, end_to_end, per_layer, require_tpu, plant):
     import jax
@@ -149,8 +160,8 @@ def _run(cell, config, traffic, gen, srv_cores, gen_cores, *, seed, seconds,
               "count": len(jax.devices())}
     note(f"device {device}; cores: server {srv_cores}, generator "
          f"{gen_cores or 'shared (fewer than 4 cores)'}")
-    if require_tpu and (dev.platform != "tpu"
-                        or device["count"] < cell["chips"]):
+    if (require_tpu and dev.platform != "tpu") \
+            or device["count"] < cell["chips"]:
         raise SystemExit(f"perfbench: needs {cell['chips']} TPU chip(s), "
                          f"found {device}")
     ready = gen.wait("ready", 60)
@@ -163,6 +174,15 @@ def _run(cell, config, traffic, gen, srv_cores, gen_cores, *, seed, seconds,
     from fluidframework_tpu.server.serving import StringServingEngine
 
     dep = config["deployment"]
+    # the cell's chips: one, or a doc mesh over the first ``chips`` of them
+    mesh = None
+    if cell["chips"] > 1:
+        if dep["n_docs"] % cell["chips"]:
+            raise ValueError(f"{dep['n_docs']} documents do not divide "
+                             f"over {cell['chips']} chips")
+        from fluidframework_tpu.parallel.sharded import make_doc_mesh
+        mesh = make_doc_mesh(cell["chips"])
+    devs = [dev] if mesh is None else list(mesh.devices.flat)
     lay = Layout(dep["n_docs"], traffic["connections"],
                  traffic["multi_writer_docs"])
     for target in TARGETS:
@@ -174,7 +194,7 @@ def _run(cell, config, traffic, gen, srv_cores, gen_cores, *, seed, seconds,
         n_docs=dep["n_docs"], capacity=dep["capacity"],
         n_props=dep["n_props"], log=log,
         compact_every=dep["engine"]["compact_every"],
-        sequencer=dep["engine"]["sequencer"])
+        sequencer=dep["engine"]["sequencer"], mesh=mesh)
     interpret = dep["pallas"] == "interpret"
     engine.store.pallas = "interpret" if interpret else "auto"
     door = ColumnarAlfred(engine, decode=dep["decode"], **dep["door"]
@@ -182,8 +202,8 @@ def _run(cell, config, traffic, gen, srv_cores, gen_cores, *, seed, seconds,
     note(f"server up {now() - t_start:.1f}s since start")
 
     try:
-        return _serve(cell, config, traffic, gen, door, engine, log, dev,
-                      device, lay, seed=seed, seconds=seconds,
+        return _serve(cell, config, traffic, gen, door, engine, log, devs,
+                      mesh, device, lay, seed=seed, seconds=seconds,
                       trace_on=trace_on, t_start=t_start,
                       end_to_end=end_to_end, per_layer=per_layer,
                       require_tpu=require_tpu, plant=plant)
@@ -192,8 +212,8 @@ def _run(cell, config, traffic, gen, srv_cores, gen_cores, *, seed, seconds,
         log.close()
 
 
-def _serve(cell, config, traffic, gen, door, engine, log, dev, device, lay,
-           *, seed, seconds, trace_on, t_start, end_to_end, per_layer,
+def _serve(cell, config, traffic, gen, door, engine, log, devs, mesh, device,
+           lay, *, seed, seconds, trace_on, t_start, end_to_end, per_layer,
            require_tpu, plant):
     """Set-up traffic, the window and the checks, on a server that is up."""
     import jax
@@ -202,7 +222,7 @@ def _serve(cell, config, traffic, gen, door, engine, log, dev, device, lay,
     interpret = dep["pallas"] == "interpret"
     spans, windows_seen = None, []
     if trace_on:
-        spans = _instrument(door, engine, windows_seen)
+        spans = _instrument(door, engine, windows_seen, len(devs))
     armed = threading.Event()
     if plant:
         faults.PLANTS[plant](door, engine, log, armed)
@@ -271,15 +291,22 @@ def _serve(cell, config, traffic, gen, door, engine, log, dev, device, lay,
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
-        a = now()
         jax.profiler.start_trace(tr_dir, profiler_options=opts)
+        # the windows dispatched while the profiler records, and not in
+        # the seconds it takes to start and to write its trace out
+        # (several, and more on several chips): those the trace's
+        # module times are of
+        a = now()
         time.sleep(tr_len)
-        jax.profiler.stop_trace()
         tr_span = (a, now())
+        jax.profiler.stop_trace()
     time.sleep(max(t1 - now(), 0))
     c1 = counters()
     found = gen.wait("drained", seconds + 180)
-    mem = dev.memory_stats() or {}
+    # the fullest of the cell's chips is the cell's peak
+    mems = [dict(d.memory_stats() or {}, id=d.id) for d in devs]
+    peak = max((m["peak_bytes_in_use"] for m in mems
+                if m.get("peak_bytes_in_use") is not None), default=None)
     new_variants = sorted(set(engine.store.unpack_variants) - variants0)
     note(f"window {seconds}s: {found['acked_in_window']} ops acked, "
          f"compiles in window {c1['compiles'] - c0['compiles']}"
@@ -296,7 +323,7 @@ def _serve(cell, config, traffic, gen, door, engine, log, dev, device, lay,
     compared = {}
     try:
         _check(compared, cell, config, traffic, lay, gen, door, engine, log,
-               found, joined, seed, interpret)
+               found, joined, seed, interpret, devs, mesh)
     except Exception:
         # a server that falls over under the checks (a failed pipeline, a
         # log that no longer reloads) is not correct; what was compared
@@ -312,16 +339,19 @@ def _serve(cell, config, traffic, gen, door, engine, log, dev, device, lay,
               + sum(found["failures"].values()),
               "failed": sum(found["failures"].values()),
               "metrics": {}, "device": dict(
-                  device, memory_peak_bytes=mem.get("peak_bytes_in_use"))}
+                  device, memory_peak_bytes=peak, memory=[
+                      {k: m.get(k) for k in ("id", "peak_bytes_in_use",
+                                             "bytes_in_use")} for m in mems])}
     raw = {f"d.{k}": c1[k] - c0[k] for k in c1}
     raw.update({"setup_s": setup_s, "window_s": found["window_s"],
                 "window_ms": found["window_s"] * 1e3,
                 "acked": found["acked_in_window"],
-                "peak_hbm_bytes": mem.get("peak_bytes_in_use")})
+                "peak_hbm_bytes": peak})
     raw.update({f"gen.{k}": v for k, v in found.items()
                 if isinstance(v, (int, float))})
     if trace_on:
-        red = trace.reduce_dir(tr_dir, rehearsal=not require_tpu)
+        red = trace.reduce_dir(tr_dir, rehearsal=not require_tpu,
+                               devices=[d.id for d in devs])
         raw.update(red["raw"])
         inside = [w for w in windows_seen if tr_span[0] <= w[0] < tr_span[1]]
         if require_tpu:     # a rehearsal's CPU has no peaks, and no share
@@ -342,10 +372,11 @@ def _serve(cell, config, traffic, gen, door, engine, log, dev, device, lay,
     return result
 
 
-def _instrument(door, engine, windows_seen):
+def _instrument(door, engine, windows_seen, chips):
     """Spans from the benchmark's files around the calls into each layer
     (traced runs only), and a record of each window as it is dispatched:
-    (when, rows, ops, fused zamboni, distinct rows since the last one)."""
+    (when, rows, ops, fused zamboni, distinct rows since the last one),
+    the three counts by shard (``roofline.by_shard``)."""
     spans = Spans()
     touched = np.zeros(engine.n_docs, bool)
     rx = {"passes": door.drain_passes}
@@ -361,8 +392,12 @@ def _instrument(door, engine, windows_seen):
         w = a[0]
         touched[w.rows] = True
         fused = bool(w.compact_due)
-        windows_seen.append((now(), int(w.R), int(w.n_ok), fused,
-                             int(touched.sum()) if fused else 0))
+        rows, ops = roofline.by_shard(w.rows, engine.n_docs, chips,
+                                      w.n_valid)
+        windows_seen.append((
+            now(), rows, ops, fused,
+            touched.reshape(chips, -1).sum(axis=1).tolist() if fused
+            else [0] * chips))
         if fused:
             touched[:] = False
 
@@ -378,7 +413,7 @@ def _instrument(door, engine, windows_seen):
 
 
 def _check(cmp, cell, config, traffic, lay, gen, door, engine, log, found,
-           joined, seed, interpret):
+           joined, seed, interpret, devs, mesh):
     """Everything compared, into ``cmp``, each number beside its limit
     (all exact: 0).
 
@@ -409,6 +444,8 @@ def _check(cmp, cell, config, traffic, lay, gen, door, engine, log, found,
          == bool(config["wire"]["props"])),
         ("pipelined executor", door.pipeline_depth
          == dep["door"]["pipeline_depth"]),
+        ("state sharded over the cell's chips", sharded_over(
+            engine.store.state, devs, dep["n_docs"])),
     ) if not ok]
     if weak:
         note(f"guarantees weakened: {weak}")
@@ -508,8 +545,11 @@ def _check(cmp, cell, config, traffic, lay, gen, door, engine, log, found,
 
     # what a reload from the summary and the log's tail reproduces
     revived = StringServingEngine.load(
-        summary, log, sequencer=dep["engine"]["sequencer"])
+        summary, log, mesh=mesh, sequencer=dep["engine"]["sequencer"])
     revived.store.pallas = engine.store.pallas
+    if not sharded_over(revived.store.state, devs, dep["n_docs"]):
+        note("guarantees weakened: the reload is not on the cell's chips")
+        cmp["guarantees_weakened"] = (cmp["guarantees_weakened"][0] + 1, 0)
     # documents the tail did not touch come back from the summary bit for
     # bit; those it touched were merged again from the log, by another
     # path than the door's, and are held to the reference like the rest

@@ -422,8 +422,8 @@ def attached(harness, seen: dict):
         clock_mark()
         stop(*a, **k)
 
-    def reduce_both(tr_dir, rehearsal=False):
-        red = reduce_trace(tr_dir, rehearsal=rehearsal)
+    def reduce_both(tr_dir, rehearsal=False, devices=None):
+        red = reduce_trace(tr_dir, rehearsal=rehearsal, devices=devices)
         seen["timer"].join(5.0)
         (p0, c0, s0, n0), (p1, c1, s1, n1) = ends
         raw = {f"d.{k}": c1[k] - c0[k] for k in c1}

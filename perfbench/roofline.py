@@ -7,10 +7,17 @@ op buffer; a zamboni needs the rows touched since the last one, on the
 same footing. The program's kernel today passes over every row of the
 store for each window; that is its cost, not the window's need, and is
 why the share reads low.
+
+On several chips the store's rows lie in contiguous blocks, one a chip
+(``parallel/sharded.py:shard_of_rows``), the chips work side by side, and
+a window is done when its fullest shard is: its least time is the largest
+over the shards of what each needs. One shard is the one-chip arithmetic.
 """
 
 import json
 import os
+
+import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 #: bytes of one op on the host→device buffer (kind/client, a0, span, lag,
@@ -46,9 +53,19 @@ def window_need(rows: int, ops: int, zamboni_rows: int, row_b: float,
             float(ops * slots * OPS_PER_SLOT + zamboni_rows * slots))
 
 
+def by_shard(rows, n_docs: int, chips: int, ops_per_row):
+    """A window's rows and ops counted by the shard that holds each row:
+    two lists of ``chips`` whole numbers."""
+    shard = np.asarray(rows, np.int64) // (n_docs // chips)
+    return (np.bincount(shard, minlength=chips).tolist(),
+            np.bincount(shard, np.asarray(ops_per_row, np.int64),
+                        minlength=chips).astype(np.int64).tolist())
+
+
 def least_seconds(windows, state, n_docs: int, device_kind: str) -> dict:
     """``windows``: (when, rows, ops, fused zamboni, rows since the last
-    zamboni) for each window dispatched in the traced span."""
+    zamboni) for each window dispatched in the traced span, the three
+    counts as lists by shard (:func:`by_shard`)."""
     if not windows:
         return {}
     pk = peaks(device_kind)
@@ -56,12 +73,15 @@ def least_seconds(windows, state, n_docs: int, device_kind: str) -> dict:
     slots = state.seq.shape[1]
     least = by_bytes = 0.0
     for _when, rows, ops, _fused, zrows in windows:
-        b, o = window_need(rows, ops, zrows, rb, slots)
-        tb, to = b / pk["hbm_bytes_per_s"], o / pk["bf16_flops_per_s"]
+        need = (window_need(r, o, z, rb, slots)
+                for r, o, z in zip(rows, ops, zrows, strict=True))
+        # the window's fullest shard
+        tb, to = max(((b / pk["hbm_bytes_per_s"], o / pk["bf16_flops_per_s"])
+                      for b, o in need), key=max)
         least += max(tb, to)
         by_bytes += tb >= to
     return {"roofline.least_s": least,
             "roofline.windows": len(windows),
             "roofline.bound_by_bytes_share": by_bytes / len(windows),
-            "roofline.rows_touched": sum(w[1] for w in windows),
+            "roofline.rows_touched": sum(sum(w[1]) for w in windows),
             "roofline.row_bytes": rb}

@@ -53,18 +53,32 @@ def union(intervals):
     return out
 
 
-def reduce_events(events, rehearsal: bool = False) -> dict:
-    """``rehearsal``: a CPU run has no device plane; its XLA client
-    threads then stand in, so that the whole reduction is exercised (the
-    numbers are never reported as a device's)."""
-    planes = sorted({p for p, *_ in events if DEVICE.match(p)})
-    if not planes and rehearsal:
-        events = [("/device:TPU:0", OPS_LINE, n, s, d) if
-                  ln.startswith("tf_XLAPjRtCpuClient") else (p, ln, n, s, d)
-                  for p, ln, n, s, d in events]
-        planes = ["/device:TPU:0"]
-    if not planes:
-        raise ValueError("the trace has no device plane")
+def reduce_events(events, rehearsal: bool = False, devices=None) -> dict:
+    """``devices``: the ids of the chips the cell runs on; only their
+    planes are read (a host's other chips are not the cell's), and one of
+    them with no plane in the trace did nothing in it. Without it, every
+    device plane the trace has. Times by module are means over those
+    chips, ``trace.busy_s`` too, beside each chip's own (``.<i>``, the
+    i-th of ``devices``) and the fullest and emptiest.
+
+    ``rehearsal``: a CPU run has no device plane; its XLA client
+    threads then stand in, dealt out over the cell's devices, so that the
+    whole reduction is exercised (the numbers are never reported as a
+    device's)."""
+    found = sorted({p for p, *_ in events if DEVICE.match(p)})
+    planes = found if devices is None else \
+        [f"/device:TPU:{i}" for i in devices]
+    if not found and rehearsal:
+        planes = planes or ["/device:TPU:0"]
+        lines = sorted({ln for _p, ln, *_ in events
+                        if ln.startswith("tf_XLAPjRtCpuClient")})
+        deal = {ln: planes[k % len(planes)] for k, ln in enumerate(lines)}
+        events = [(deal[ln], OPS_LINE, n, s, d) if ln in deal
+                  else (p, ln, n, s, d) for p, ln, n, s, d in events]
+        found = planes
+    if not set(planes) & set(found):
+        raise ValueError(f"the trace has no device plane of {planes}: "
+                         f"{found}")
     lo = min(e[3] for e in events)
     hi = max(e[3] + e[4] for e in events)
     window_ns = hi - lo
@@ -98,12 +112,17 @@ def reduce_events(events, rehearsal: bool = False) -> dict:
         raw[f"trace.module_n.{k}"] = mod_n[k] / n
     raw["trace.window_s"] = window_ns / 1e9
     raw["trace.busy_s"] = sum(busy_ns) / n / 1e9
+    for i, b in enumerate(busy_ns):
+        raw[f"trace.busy_s.{i}"] = b / 1e9
+    raw["trace.busy_s.max"] = max(busy_ns) / 1e9
+    raw["trace.busy_s.min"] = min(busy_ns) / 1e9
     return {"raw": raw, "busy_s": raw["trace.busy_s"],
             "window_s": raw["trace.window_s"],
             "breakdown": {
                 "device_ops": [[k, v] for k, v in sorted(
                     op_s.items(), key=lambda kv: -kv[1])[:10]],
-                "idle_gaps": idle_by_span(events, gaps)[:10]}}
+                "idle_gaps": [[k, v / n] for k, v in
+                              idle_by_span(events, gaps)[:10]]}}
 
 
 def idle_by_span(events, gaps):
@@ -143,10 +162,11 @@ def idle_by_span(events, gaps):
             if v > 0]
 
 
-def reduce_dir(trace_dir: str, rehearsal: bool = False) -> dict:
+def reduce_dir(trace_dir: str, rehearsal: bool = False,
+               devices=None) -> dict:
     paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     if len(paths) != 1:
         raise FileNotFoundError(f"one .xplane.pb wanted under {trace_dir},"
                                 f" found {paths}")
-    return reduce_events(load(paths[0]), rehearsal)
+    return reduce_events(load(paths[0]), rehearsal, devices)

@@ -34,16 +34,19 @@ def _data(name):
         return json.load(f)
 
 
-def _rehearse(config, traffic, trace_on, plant="", seed=2_147_483_659):
-    """The rest of a run, without the harness's look for a chip."""
+def _rehearse(config, traffic, trace_on, plant="", seed=2_147_483_659,
+              chips=1):
+    """The rest of a run, without the harness's look for a chip (on
+    several chips: the CPU's virtual devices, which conftest.py makes)."""
     from perfbench import harness
-    # the tiny cell reports what the committed cell of its mix reports,
-    # picked by the rule the command itself uses
+    # the tiny cell reports what the committed cell of its mix and its
+    # chips reports, picked by the rule the command itself uses
     like = next(w["name"] for w in BENCH["workloads"]
-                if "tiny-" + w["traffic"] == traffic)
+                if "tiny-" + w["traffic"] == traffic
+                and (w["chips"] > 1) == (chips > 1))
     end_to_end, per_layer = select_metrics(BENCH, like)
     return harness.run_cell(
-        {"name": f"{config}.{traffic}", "chips": 1}, _data(config),
+        {"name": f"{config}.{traffic}", "chips": chips}, _data(config),
         _data(traffic),
         {"config": os.path.join(DATA, config + ".json"),
          "traffic": os.path.join(DATA, traffic + ".json")},
@@ -54,27 +57,52 @@ def _rehearse(config, traffic, trace_on, plant="", seed=2_147_483_659):
 
 # ------------------------------------------------------ the whole command
 
-@pytest.mark.parametrize("config,traffic,trace_on", [
-    ("tiny-string", "tiny-replay", False),
-    ("tiny-rich", "tiny-typing", True)])
-def test_rehearsal_of_a_cell(config, traffic, trace_on):
-    r = _rehearse(config, traffic, trace_on)
+# what a CPU's trace cannot give: it has no line of modules, the roofline
+# needs the chip's peaks, and the CPU reports no memory
+CHIP_ONLY = {"kernel.merge_ms_per_window.replay", "merge_roofline.replay",
+             "device.peak_hbm_bytes.replay"}
+
+
+@pytest.mark.parametrize("config,traffic,trace_on,chips", [
+    ("tiny-string", "tiny-replay", False, 1),
+    ("tiny-rich", "tiny-typing", True, 1),
+    ("tiny-string", "tiny-replay", False, 4),
+    ("tiny-string", "tiny-replay", True, 4)])
+def test_rehearsal_of_a_cell(config, traffic, trace_on, chips):
+    r = _rehearse(config, traffic, trace_on, chips=chips)
     assert list(r)[:5] == RESULT_KEYS and list(r)[-1] == "compared"
     assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
     assert all(v["value"] <= v["limit"] for v in r["compared"].values())
-    assert {"platform", "kind", "count", "memory_peak_bytes"} \
+    assert {"platform", "kind", "count", "memory_peak_bytes", "memory"} \
         <= set(r["device"])
+    assert [m["id"] for m in r["device"]["memory"]] == list(range(chips))
+    family = traffic.split("-")[1]
     names = set(r["metrics"])
     if trace_on:
         assert r["device"]["busy_s"] > 0 and r["device"]["window_s"] > 0
-        assert "store.compiles_in_window.typing" in names
-        assert r["metrics"]["store.compiles_in_window.typing"]["value"] == 0
+        assert r["metrics"][f"store.compiles_in_window.{family}"][
+            "value"] == 0
         assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
         assert not any("roofline" in n for n in names)   # no CPU roofline
+        if chips == 4:
+            want = {m["name"] for m in BENCH["per_layer"]
+                    if m["name"].endswith(".replay")} - CHIP_ONLY
+            assert len(want) == 11 and names == want
+            assert 0 < r["metrics"]["device.busy_min_over_max.replay"][
+                "value"] <= 1
+            assert r["metrics"]["device.chip0_busy_over_mean.replay"][
+                "value"] > 0
     else:
         assert names == {"setup_s", "acked_ops_per_s"}
         assert all(v["value"] > 0 for v in r["metrics"].values())
     json.dumps(r)
+
+
+def test_population_divides_over_the_cells_chips():
+    """A population that does not divide over the cell's chips is refused
+    as the server is built, before any traffic."""
+    with pytest.raises(ValueError, match="do not divide"):
+        _rehearse("tiny-string", "tiny-replay", False, chips=3)
 
 
 # each fault, the cell it is planted in, and the numbers of which one has to
@@ -86,16 +114,27 @@ FAULTS = {"unapplied_window": ("tiny-string", "tiny-replay", MERGED),
           "skipped_append": ("tiny-string", "tiny-replay", ("log_differs",)),
           "altered_ack": ("tiny-string", "tiny-replay", ("acks_failed",)),
           "dropped_annotates": ("tiny-rich", "tiny-typing",
-                                ("props_differ",))}
+                                ("props_differ",)),
+          "unsharded_state": ("tiny-string", "tiny-replay",
+                              ("guarantees_weakened",))}
+# a fault only a cell on a mesh can have, and two of the one-chip cell's
+# that it can have as well
+MESH_FAULTS = ("unsharded_state", "unapplied_window", "skipped_append")
 
 
-@pytest.mark.parametrize("fault", sorted(faults.PLANTS))
-def test_planted_fault_is_not_correct(fault):
+@pytest.mark.parametrize("fault,chips", [
+    (f, 1) for f in sorted(set(faults.PLANTS) - {"unsharded_state"})] + [
+    (f, 4) for f in MESH_FAULTS])
+def test_planted_fault_is_not_correct(fault, chips):
     config, traffic, numbers = FAULTS[fault]
-    r = _rehearse(config, traffic, False, plant=fault)
+    r = _rehearse(config, traffic, False, plant=fault, chips=chips)
     assert r["correct"] is False
-    assert any(r["compared"][n]["value"] > r["compared"][n]["limit"]
-               for n in numbers), r["compared"]
+    over = {n for n, v in r["compared"].items() if v["value"] > v["limit"]}
+    assert over & set(numbers), r["compared"]
+    if fault == "unsharded_state":
+        # every answer stays right: the placement alone is gone
+        assert over == {"guarantees_weakened"}, r["compared"]
+        assert r["compared"]["guarantees_weakened"]["value"] == 1
 
 
 def test_command_refuses_without_a_chip():
@@ -140,20 +179,39 @@ def test_manifest_finds_every_file_by_name():
                          ids=lambda m: m["name"])
 def test_metric_has_a_reader_of_its_own(metric):
     spec = load_json("metrics", metric["name"])
-    assert set(spec) <= {"reduce", "key", "num", "den", "scale"}
+    assert set(spec) <= {"reduce", "key", "num", "den", "scale", "module",
+                         "num_n", "den_n"}
     assert NAME.match(metric["name"])
     if "moves" in metric:
         moved = next(m for m in BENCH["end_to_end"]
                      if m["name"] == metric["moves"])
-        cells = moved.get("workloads", [w["name"] for w in
-                                        BENCH["workloads"]])
+        every = [w["name"] for w in BENCH["workloads"]]
+        cells = metric.get("workloads", moved.get("workloads", every))
+        # each cell it lists reports the metric it moves, and reports it
+        assert set(cells) <= set(moved.get("workloads", every))
+        for cell in cells:
+            assert metric in select_metrics(BENCH, cell)[1]
         # the suffix names the traffic family of the cells that report it
         assert {metric["name"].rsplit(".", 1)[1]} == {
             load_json("traffic", w["traffic"])["family"]
             for w in BENCH["workloads"] if w["name"] in cells}
-        for cell in cells:
-            assert metric in select_metrics(BENCH, cell)[1]
     assert reduce.read_metric(metric["name"], {}) is None   # nothing read
+
+
+def test_every_traced_cell_line_has_its_metrics():
+    """A cell reports the per-layer entries that list it, or that list
+    nothing and move a metric it reports: the mesh's replay cell reports
+    every metric of the one-chip replay cell, under the same names, and
+    the two that exist only across chips."""
+    one = {m["name"] for m in select_metrics(
+        BENCH, "string-deli-10k.replay")[1]}
+    four = {m["name"] for m in select_metrics(
+        BENCH, "string-deli-10k-mesh4.replay")[1]}
+    assert len(one) == 12 and four - one == {
+        "device.chip0_busy_over_mean.replay",
+        "device.busy_min_over_max.replay"} and one <= four
+    e2e = select_metrics(BENCH, "string-deli-10k-mesh4.replay")[0]
+    assert [m["name"] for m in e2e] == ["acked_ops_per_s", "setup_s"]
 
 
 def test_end_to_end_metrics_are_read_from_files_too():
@@ -176,6 +234,39 @@ def test_reducers():
         {"trace.busy_s": 0.5, "trace.window_s": 4.0}) == 87.5
     assert reduce.read_metric("device.idle_share.replay",
                               {"trace.busy_s": 0.5}) is None
+    # one kernel's module under the name it has on one chip, on a mesh
+    # today, or as a later PR may name it there: the first that the trace
+    # has is read, and never two summed
+    for mod in ("jit__columnar_merge_jit", "jit__sharded_columnar_merge",
+                "jit_fn"):
+        raw = {f"trace.module_s.{mod}": 0.9, f"trace.module_n.{mod}": 2000.0,
+               "trace.module_s.jit_other": 5.0,
+               "trace.module_n.jit_other": 10.0,
+               "roofline.least_s": 0.0225, "roofline.windows": 1000.0}
+        for family in ("replay", "typing"):
+            assert reduce.read_metric(
+                f"kernel.merge_ms_per_window.{family}",
+                raw) == pytest.approx(0.45)
+            # a window's mean need over the module's mean run: the host
+            # counted 1,000 windows where the device ran 2,000
+            assert reduce.read_metric(f"merge_roofline.{family}",
+                                      raw) == pytest.approx(5.0)
+        raw["trace.module_s.jit_fn"], raw["trace.module_n.jit_fn"] = 9.0, 1.0
+        if mod != "jit_fn":
+            assert reduce.read_metric("kernel.merge_ms_per_window.replay",
+                                      raw) == pytest.approx(0.45)
+        del raw["roofline.windows"]
+        assert reduce.read_metric("merge_roofline.replay", raw) is None
+    assert reduce.read_metric(
+        "kernel.merge_ms_per_window.replay",
+        {"trace.module_s.jit_other": 1.0,
+         "trace.module_n.jit_other": 9.0}) is None
+    busy = {"trace.busy_s": 0.5, "trace.busy_s.0": 0.8, "trace.busy_s.1": 0.4,
+            "trace.busy_s.2": 0.4, "trace.busy_s.3": 0.4,
+            "trace.busy_s.max": 0.8, "trace.busy_s.min": 0.4}
+    assert reduce.read_metric("device.chip0_busy_over_mean.replay",
+                              busy) == 1.6
+    assert reduce.read_metric("device.busy_min_over_max.replay", busy) == 0.5
 
 
 # ------------------------------------------------------------- generator
@@ -466,6 +557,46 @@ def test_trace_reduction_on_the_recorded_trace():
         == pytest.approx(100 * 0.8 / 1.3)
     with pytest.raises(ValueError):
         trace.reduce_events([e for e in ev if e[0] == "/host:CPU"])
+    assert raw["trace.busy_s.0"] == raw["trace.busy_s.max"] \
+        == raw["trace.busy_s.min"] == raw["trace.busy_s"]
+
+
+def test_trace_reduction_reads_the_cells_chips_alone():
+    """A one-chip cell on a four-chip host reads the chip it uses, not
+    the idle planes beside it; a cell on several reads each of them."""
+    ev = [tuple(e) for e in _data("trace_small")["events"]]
+    alone = trace.reduce_events(ev, devices=[0])
+    assert alone == trace.reduce_events(ev)
+    # a second chip that ran one op of 0.1 ms in the same 1.3 ms
+    lo = min(e[3] for e in ev)
+    both = ev + [("/device:TPU:1", trace.OPS_LINE, "%fusion.9 = s32[] x",
+                  lo + 200_000, 100_000),
+                 ("/device:TPU:1", trace.MODULES_LINE, "jit_other(7)",
+                  lo + 200_000, 100_000)]
+    assert trace.reduce_events(both, devices=[0]) == alone
+    two = trace.reduce_events(both, devices=[0, 1])
+    assert two == trace.reduce_events(both)
+    raw = two["raw"]
+    assert raw["trace.busy_s.0"] == pytest.approx(0.5e-3)
+    assert raw["trace.busy_s.1"] == raw["trace.busy_s.min"] \
+        == pytest.approx(0.1e-3)
+    assert raw["trace.busy_s.max"] == raw["trace.busy_s.0"]
+    assert two["busy_s"] == raw["trace.busy_s"] == pytest.approx(0.3e-3)
+    # a module's time is per chip; the ops stay summed over the chips
+    assert raw["trace.module_s.jit__columnar_merge_jit"] \
+        == pytest.approx(0.2e-3)
+    assert raw["trace.module_n.jit_other"] == 0.5
+    assert dict(two["breakdown"]["device_ops"])["fusion.9"] \
+        == pytest.approx(0.1e-3)
+    # per chip, idle and busy add up to the window
+    assert sum(v for _k, v in two["breakdown"]["idle_gaps"]) \
+        + two["busy_s"] == pytest.approx(two["window_s"])
+    # a chip of the cell that the trace has no plane for did nothing
+    three = trace.reduce_events(both, devices=[0, 1, 2])["raw"]
+    assert three["trace.busy_s.2"] == three["trace.busy_s.min"] == 0.0
+    assert three["trace.busy_s"] == pytest.approx(0.2e-3)
+    with pytest.raises(ValueError, match="no device plane"):
+        trace.reduce_events(both, devices=[2, 3])
 
 
 def test_roofline_counts_the_bytes_a_window_needs():
@@ -488,6 +619,49 @@ def test_roofline_counts_the_bytes_a_window_needs():
     import jax.numpy as jnp
     leaf = jnp.zeros((1024, 8), jnp.int32)
     assert roofline.row_bytes({"a": leaf, "b": leaf}, 1024) == 64.0
+
+
+def test_roofline_of_a_window_is_its_fullest_shards():
+    import collections
+    import jax.numpy as jnp
+    leaf = jnp.zeros((1024, 8), jnp.int32)
+    state = collections.namedtuple("State", "seq length")(leaf, leaf)  # 64 B
+    pk = roofline.peaks("TPU v5 lite")
+    kind = "TPU v5 lite"
+    # one shard: the one-chip arithmetic, to the last bit
+    wins = [(0.0, [512], [500], False, [0]), (0.1, [256], [256], True, [700])]
+    want = sum(max(b / pk["hbm_bytes_per_s"], o / pk["bf16_flops_per_s"])
+               for b, o in (roofline.window_need(512, 500, 0, 64.0, 8),
+                            roofline.window_need(256, 256, 700, 64.0, 8)))
+    got = roofline.least_seconds(wins, state, 1024, kind)
+    assert got["roofline.least_s"] == want
+    assert got["roofline.rows_touched"] == 768 and got[
+        "roofline.windows"] == 2
+    # four shards: rows are counted by the block that holds them
+    rows = np.concatenate([np.arange(0, 128), np.arange(256, 384),
+                           np.arange(512, 640), np.arange(768, 896)])
+    assert roofline.by_shard(rows, 1024, 4, np.ones(512, np.int64)) \
+        == ([128] * 4, [128] * 4)
+    assert roofline.by_shard(np.arange(256), 1024, 4, np.r_[
+        np.zeros(6, np.int64), np.ones(250, np.int64)]) \
+        == ([256, 0, 0, 0], [250, 0, 0, 0])
+    assert roofline.by_shard(rows, 1024, 1, np.ones(512, np.int64)) \
+        == ([512], [512])
+    # all rows in one shard: the one-chip time; spread evenly: a quarter
+    one = roofline.least_seconds([(0.0, [512], [512], True, [700])],
+                                 state, 1024, kind)["roofline.least_s"]
+    skew = roofline.least_seconds(
+        [(0.0, [0, 512, 0, 0], [0, 512, 0, 0], True, [0, 700, 0, 0])],
+        state, 1024, kind)
+    even = roofline.least_seconds(
+        [(0.0, [128] * 4, [128] * 4, True, [175] * 4)], state, 1024, kind)
+    assert skew["roofline.least_s"] == one
+    assert even["roofline.least_s"] == pytest.approx(one / 4, rel=1e-12)
+    assert skew["roofline.rows_touched"] == even[
+        "roofline.rows_touched"] == 512
+    with pytest.raises(ValueError):         # counts of different shards
+        roofline.least_seconds([(0.0, [512], [512], False, [0] * 4)], state,
+                               1024, kind)
 
 
 def test_unknown_device_has_no_peaks():

@@ -26,8 +26,8 @@ DEV, HOST = "/device:TPU:0", "/host:CPU"
 #: the metrics this module's program readings feed: entries in
 #: BENCHMARK.json's form, which names none of them (``harness.py`` would
 #: have to call the readers)
-NEW = [m for w in BENCH["workloads"]
-       for m in progtrace.per_layer(BENCH, w["name"])]
+NEW = list({m["name"]: m for w in BENCH["workloads"]
+            for m in progtrace.per_layer(BENCH, w["name"])}.values())
 
 
 #: a cell name of its own: the run's files (trace, log, records) go under
