@@ -16,6 +16,7 @@ import functools
 import heapq
 import json
 import logging
+import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -62,6 +63,11 @@ def _note_dispatch(kind: str, dispatch_ms: Optional[float] = None) -> None:
     if dispatch_ms is not None:
         REGISTRY.observe("device_dispatch_ms", dispatch_ms)
     size = sum(globals()[name]._cache_size() for name in _JIT_FN_NAMES)
+    # a store on a mesh dispatches parallel/sharded.py's programs instead
+    # of the columnar pair above (the module is loaded only by such a store)
+    sharded = sys.modules.get("fluidframework_tpu.parallel.sharded")
+    if sharded is not None:
+        size += sharded.jit_cache_size()
     if size > _jit_cache_total:
         REGISTRY.inc("jax_compiles", size - _jit_cache_total)
     else:
@@ -178,42 +184,15 @@ def _apply_pallas_jit(state, kind, a0, a1, a2, seq, client, ref_seq,
                                      with_props=with_props)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("R", "O", "pos_wide", "ref_wide",
-                                    "rich", "n_docs", "fuse_compact",
-                                    "scatter_rows", "compact8", "tab_n"))
-def _columnar_unpack_jit(buf, R, O, pos_wide, ref_wide, rich, n_docs,
-                         fuse_compact, scatter_rows, compact8=False,
-                         tab_n=0):
-    """Device-side unpack of ONE byte-packed columnar batch. The host
-    concatenates every op plane into a single uint8 buffer — kind u8,
-    client-idx u8, a0/a1 (i16, or i32 when ``pos_wide``), ref (u16 LAG
-    behind the op's own seq, or full i32 when ``ref_wide``), a2 (one
-    broadcast i32 handle, or an (N,) i32 plane when ``rich``), the
-    per-row seq bases, the row indices, and the fused min_seq — because
-    EACH host→device transfer pays a fixed per-transfer overhead and its
-    own enqueue: one fused buffer at ~8 B/op is one transfer and one sync
-    point per batch instead of seven.
-
-    seq = base + running count of non-NOOP slots (nacked ops were
-    NOOP-masked host-side and consumed no sequence number); ref clamps to
-    seq-1 (mirroring Deli).
-
-    ``rich`` payload modes: 0 = broadcast (one i32 handle), 1 = a full
-    (N,) i32 a2 plane, 2/3 = TABLE form — the wire carries a u8 (mode 2)
-    or u16 (mode 3) table index per op plus two small i32 tables
-    (``tab_n`` entries each, padded to a power of two): the a2 value
-    (payload handle / packed property) and the insert length. The device
-    gathers a2 and insert a1 from the tables, so rich batches cost ~1-2
-    extra wire bytes per op instead of 4 and the host never materializes
-    an (R, O) handle plane (the former rich-pack hot spot).
-
-    This is deliberately its OWN jit (not fused into the merge program),
-    and the buffer is INT32 WORDS unpacked by shift/mask — not u8 +
-    bitcast: both the u8-bitcast form and fusing the unpack into the
-    scan/compact body pathologically explode XLA's TPU compile time
-    (seconds → many minutes at 10k-doc shapes, measured); this form
-    compiles in seconds and the unpacked planes stay on device."""
+def decode_columnar(buf, R, O, pos_wide, ref_wide, rich, compact8, tab_n,
+                    n_min_seq):
+    """The wire's unpack, whatever places its output: ONE byte-packed
+    columnar batch (int32 words, the layout ``_columnar_unpack_jit``
+    documents) decoded by shift and mask into the seven (R, O) op planes
+    ``(kind, a0, a1, a2, seq, client, ref)``, the batch's (R,) row indices
+    and the trailing ``n_min_seq`` words of fused min_seq. Traced inside
+    ``_columnar_unpack_jit`` (one chip) and inside
+    ``parallel.sharded.sharded_unpack`` (each shard of a mesh)."""
     N = R * O
 
     def take_u8(off, n):
@@ -265,7 +244,7 @@ def _columnar_unpack_jit(buf, R, O, pos_wide, ref_wide, rich, n_docs,
         a2, off = take_i32(off, N if rich else 1)
     base, off = take_i32(off, R)
     rows, off = take_i32(off, R)
-    min_seq, off = take_i32(off, n_docs if fuse_compact else 1)
+    min_seq, off = take_i32(off, n_min_seq)
 
     kind = kind.reshape(R, O)
     valid = kind != int(OpKind.NOOP)
@@ -285,7 +264,52 @@ def _columnar_unpack_jit(buf, R, O, pos_wide, ref_wide, rich, n_docs,
         a2 = jnp.broadcast_to(a2, (R, O))
     a2 = jnp.where((kind == int(OpKind.STR_INSERT))
                    | (kind == int(OpKind.STR_ANNOTATE)), a2, 0)
-    planes = (kind, a0, a1, a2, seq, client, ref)
+    return (kind, a0, a1, a2, seq, client, ref), rows, min_seq
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("R", "O", "pos_wide", "ref_wide",
+                                    "rich", "n_docs", "fuse_compact",
+                                    "scatter_rows", "compact8", "tab_n"))
+def _columnar_unpack_jit(buf, R, O, pos_wide, ref_wide, rich, n_docs,
+                         fuse_compact, scatter_rows, compact8=False,
+                         tab_n=0):
+    """Device-side unpack of ONE byte-packed columnar batch. The host
+    concatenates every op plane into a single uint8 buffer — kind u8,
+    client-idx u8, a0/a1 (i16, or i32 when ``pos_wide``), ref (u16 LAG
+    behind the op's own seq, or full i32 when ``ref_wide``), a2 (one
+    broadcast i32 handle, or an (N,) i32 plane when ``rich``), the
+    per-row seq bases, the row indices, and the fused min_seq — because
+    EACH host→device transfer pays a fixed per-transfer overhead and its
+    own enqueue: one fused buffer at ~8 B/op is one transfer and one sync
+    point per batch instead of seven.
+
+    seq = base + running count of non-NOOP slots (nacked ops were
+    NOOP-masked host-side and consumed no sequence number); ref clamps to
+    seq-1 (mirroring Deli).
+
+    ``rich`` payload modes: 0 = broadcast (one i32 handle), 1 = a full
+    (N,) i32 a2 plane, 2/3 = TABLE form — the wire carries a u8 (mode 2)
+    or u16 (mode 3) table index per op plus two small i32 tables
+    (``tab_n`` entries each, padded to a power of two): the a2 value
+    (payload handle / packed property) and the insert length. The device
+    gathers a2 and insert a1 from the tables, so rich batches cost ~1-2
+    extra wire bytes per op instead of 4 and the host never materializes
+    an (R, O) handle plane (the former rich-pack hot spot).
+
+    This is deliberately its OWN jit (not fused into the merge program),
+    and the buffer is INT32 WORDS unpacked by shift/mask — not u8 +
+    bitcast: both the u8-bitcast form and fusing the unpack into the
+    scan/compact body pathologically explode XLA's TPU compile time
+    (seconds → many minutes at 10k-doc shapes, measured); this form
+    compiles in seconds and the unpacked planes stay on device.
+
+    This is the one-chip placement; a store on a mesh runs the same
+    ``decode_columnar`` where its state lives
+    (``parallel.sharded.sharded_unpack``)."""
+    planes, rows, min_seq = decode_columnar(
+        buf, R, O, pos_wide, ref_wide, rich, compact8, tab_n,
+        n_docs if fuse_compact else 1)
     if scatter_rows:
         def full(p, fill):
             return jnp.full((n_docs, O), fill, jnp.int32).at[rows].set(p)
@@ -1112,6 +1136,8 @@ class TensorStringStore(StringOpInterner):
             if ref_wide:
                 ref_i32 = np.ascontiguousarray(ref_seq, "<i4")
 
+            if self.mesh is not None:
+                from ..parallel import sharded
             pack_ms = 0.0
             dispatch_ms = 0.0
             for si, (c0, c1, slides) in enumerate(segments):
@@ -1168,18 +1194,35 @@ class TensorStringStore(StringOpInterner):
                                tab_n=tab_n)
                 self.unpack_variants.add(tuple(variant.values()))
                 pack.__exit__()
+                # two placements of the same three steps, chosen by where
+                # the state lives: one chip takes one upload and plain jits;
+                # a mesh takes the buffer on every chip and unpacks in each
+                # shard, so the planes are born with the state's sharding
+                # and the merge launch moves nothing between chips
                 with tracing.stage(rec, "store.upload") as sp_up:
-                    dev = jnp.asarray(buf)
+                    if self.mesh is None:
+                        dev = jnp.asarray(buf)
+                    else:
+                        dev = sharded.replicated(buf, self.mesh)
                 with tracing.stage(rec, "store.unpack_dispatch") as sp_unp:
-                    planes, ms_dev = _columnar_unpack_jit(dev, **variant)
+                    if self.mesh is None:
+                        planes, ms_dev = _columnar_unpack_jit(dev, **variant)
+                    else:
+                        planes, ms_dev = sharded.sharded_unpack(
+                            self.mesh, **variant)(dev)
                 with tracing.stage(rec, "store.merge_dispatch") as sp_mrg:
                     if self.mesh is not None:
                         # planes are (n_docs, O) either way: subset batches
                         # scattered by the unpack, full-store batches already
                         # in row order
-                        from ..parallel.sharded import sharded_merge
-                        fn = sharded_merge(self.mesh, use_pallas, tile,
-                                           interpret, self._has_props, fuse_seg)
+                        REGISTRY.inc(
+                            "mesh_windows_resident"
+                            if planes[0].sharding.is_equivalent_to(
+                                self.state.seq.sharding, 2)
+                            else "mesh_windows_resharded")
+                        fn = sharded.sharded_merge(
+                            self.mesh, use_pallas, tile, interpret,
+                            self._has_props, fuse_seg)
                         self.state = fn(self.state, planes, ms_dev) \
                             if fuse_seg else fn(self.state, planes)
                     else:

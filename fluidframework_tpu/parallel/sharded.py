@@ -25,6 +25,7 @@ import functools
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -86,6 +87,75 @@ def shard_store_state(state: StringState, mesh: Mesh) -> StringState:
 _CACHE: dict = {}
 
 
+def jit_cache_size() -> int:
+    """Programs compiled so far by every sharded entry point built: what
+    the store's dispatch accounting adds to its own jits' (a growth
+    between two dispatches is an XLA compile paid on the hot path)."""
+    return sum(fn._cache_size() for fn in _CACHE.values())
+
+
+def replicated(buf, mesh: Mesh):
+    """A host buffer put whole on every chip of the mesh (one
+    host→device copy a chip): the placement ``sharded_unpack`` reads."""
+    return jax.device_put(buf, NamedSharding(mesh, P()))
+
+
+def sharded_unpack(mesh: Mesh, R: int, O: int, pos_wide: bool,
+                   ref_wide: bool, rich: int, n_docs: int,
+                   fuse_compact: bool, scatter_rows: bool,
+                   compact8: bool = False, tab_n: int = 0):
+    """The mesh placement of the columnar unpack: replicated packed buffer
+    → the seven op planes as ``(n_docs, O)`` arrays sharded like the state
+    (``P(docs, None)``), and the fused min_seq ``P(docs)`` (``None``
+    without ``fuse_compact``), so the merge launch moves nothing. The
+    arguments are ``string_store._columnar_unpack_jit``'s statics, the
+    decode is the same ``decode_columnar``; every shard decodes the whole
+    (small) window and keeps the rows of its own block, so no plane of the
+    store's height exists on one chip and no collective appears."""
+    key = ("unpack", mesh, R, O, pos_wide, ref_wide, rich, n_docs,
+           fuse_compact, scatter_rows, compact8, tab_n)
+    if key not in _CACHE:
+        from ..ops.schema import OpKind
+        from ..ops.string_store import decode_columnar
+        local = n_docs // mesh.devices.size
+        planes_spec = (P(DOC_AXIS, None),) * 7
+
+        def body(buf):
+            planes, rows, min_seq = decode_columnar(
+                buf, R, O, pos_wide, ref_wide, rich, compact8, tab_n,
+                n_docs if fuse_compact else 0)
+            first = jax.lax.axis_index(DOC_AXIS) * local
+            if scatter_rows:
+                # another shard's rows go past this block's end, where
+                # mode="drop" leaves them out (a negative index would wrap)
+                at = rows - first
+                at = jnp.where((at >= 0) & (at < local), at, local)
+
+                def block(p, fill):
+                    return jnp.full((local, O), fill, jnp.int32) \
+                        .at[at].set(p, mode="drop")
+
+                planes = (block(planes[0], int(OpKind.NOOP)),) + \
+                    tuple(block(p, 0) for p in planes[1:])
+            else:  # a full-store batch in row order: this shard's slice
+                planes = tuple(
+                    jax.lax.dynamic_slice_in_dim(p, first, local, axis=0)
+                    for p in planes)
+            if not fuse_compact:
+                return planes
+            return planes, jax.lax.dynamic_slice_in_dim(min_seq, first, local)
+
+        @jax.jit
+        def _sharded_columnar_unpack(buf):
+            out = jax.shard_map(
+                body, mesh=mesh, in_specs=P(),
+                out_specs=(planes_spec, P(DOC_AXIS)) if fuse_compact
+                else planes_spec)(buf)
+            return out if fuse_compact else (out, None)
+        _CACHE[key] = _sharded_columnar_unpack
+    return _CACHE[key]
+
+
 def sharded_merge(mesh: Mesh, use_pallas: bool, tile: int, interpret: bool,
                   with_props: bool, fuse_compact: bool):
     """The sharded columnar/message merge: (state, 7×(D,O) planes[, min_seq])
@@ -98,7 +168,7 @@ def sharded_merge(mesh: Mesh, use_pallas: bool, tile: int, interpret: bool,
 
         if fuse_compact:
             @functools.partial(jax.jit, donate_argnums=0)
-            def fn(state, planes, ms):
+            def _sharded_columnar_merge(state, planes, ms):
                 def body(state, planes, ms):
                     if use_pallas:
                         return apply_string_batch_pallas(
@@ -115,7 +185,7 @@ def sharded_merge(mesh: Mesh, use_pallas: bool, tile: int, interpret: bool,
                     out_specs=specs, check_vma=False)(state, planes, ms)
         else:
             @functools.partial(jax.jit, donate_argnums=0)
-            def fn(state, planes):
+            def _sharded_columnar_merge(state, planes):
                 def body(state, planes):
                     if use_pallas:
                         return apply_string_batch_pallas(
@@ -126,7 +196,7 @@ def sharded_merge(mesh: Mesh, use_pallas: bool, tile: int, interpret: bool,
                 return jax.shard_map(
                     body, mesh=mesh, in_specs=(specs, planes_spec),
                     out_specs=specs, check_vma=False)(state, planes)
-        _CACHE[key] = fn
+        _CACHE[key] = _sharded_columnar_merge
     return _CACHE[key]
 
 
@@ -137,12 +207,12 @@ def sharded_compact(mesh: Mesh, with_props: bool):
         specs = doc_state_specs()
 
         @functools.partial(jax.jit, donate_argnums=0)
-        def fn(state, ms):
+        def _sharded_compact(state, ms):
             return jax.shard_map(
                 lambda s, m: compact_string_state(s, m, with_props),
                 mesh=mesh, in_specs=(specs, P(DOC_AXIS)),
                 out_specs=specs, check_vma=False)(state, ms)
-        _CACHE[key] = fn
+        _CACHE[key] = _sharded_compact
     return _CACHE[key]
 
 
@@ -285,21 +355,36 @@ def sharded_cells_apply(mesh: Mesh, fww: bool):
     return _CACHE[key]
 
 
+_COLLECTIVES = ("all-reduce", "all-gather", "all-to-all",
+                "collective-permute", "reduce-scatter",
+                "collective-broadcast")
+
+
 def assert_collective_free(mesh: Mesh, n_docs: int, capacity: int,
                            n_ops: int) -> str:
-    """Compile the sharded merge at the given shape and prove the apply
-    path needs NO cross-chip communication: the optimized HLO must contain
-    zero collective ops. Returns the (empty) list rendered as evidence."""
-    import jax.numpy as jnp
+    """Compile the sharded apply at the given shape and prove it needs NO
+    cross-chip communication: the optimized HLO of the merge and of both
+    forms of the unpack (rows scattered, and a full-store batch) must
+    contain zero collective ops. Returns the (empty) list rendered as
+    evidence."""
     state = shard_store_state(StringState.create(n_docs, capacity), mesh)
     planes = tuple(jnp.zeros((n_docs, n_ops), jnp.int32) for _ in range(7))
     ms = jnp.zeros((n_docs,), jnp.int32)
     fn = sharded_merge(mesh, use_pallas=False, tile=8, interpret=False,
                        with_props=False, fuse_compact=True)
-    hlo = fn.lower(state, planes, ms).compile().as_text()
-    bad = [op for op in ("all-reduce", "all-gather", "all-to-all",
-                         "collective-permute", "reduce-scatter",
-                         "collective-broadcast")
-           if op in hlo]
-    assert not bad, f"sharded merge HLO contains collectives: {bad}"
+    hlos = {"merge": fn.lower(state, planes, ms).compile().as_text()}
+    # any buffer at least as long as the wire's layout lowers: 8 B an op
+    # is the widest head, then seq bases, rows and the fused min_seq
+    for scatter, R in ((True, n_docs // 2), (False, n_docs)):
+        buf = jax.ShapeDtypeStruct((2 * R * n_ops + 2 * R + n_docs + 8,),
+                                   jnp.int32)
+        fn = sharded_unpack(mesh, R, n_ops, pos_wide=False, ref_wide=False,
+                            rich=0, n_docs=n_docs, fuse_compact=True,
+                            scatter_rows=scatter)
+        hlos[f"unpack(scatter_rows={scatter})"] = \
+            fn.lower(buf).compile().as_text()
+    bad = {name: [op for op in _COLLECTIVES if op in hlo]
+           for name, hlo in hlos.items()}
+    assert not any(bad.values()), \
+        f"sharded apply HLO contains collectives: {bad}"
     return "collective-free"

@@ -3,6 +3,8 @@ multi-chip path on the virtual 8-device CPU mesh — parity with the
 unsharded engine, recovery onto the mesh, and the collective-free proof.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -178,3 +180,181 @@ def test_sharded_map_engine_matches_unsharded():
     assert {d: revived.read_doc(d) for d in docs} == \
         {d: a.read_doc(d) for d in docs}
     assert "docs" in str(revived.store.state.present.sharding.spec)
+
+
+# ------------------------------------------------ the columnar apply on a mesh
+# (ISSUE 29) A window's op planes are unpacked where the state lives: the
+# mesh store against a one-chip store on the same windows, plane for plane.
+
+N_DOCS, CAP, CHIPS = 2048, 128, 4   # a shard is 512 rows: the door's tallest
+HEIGHTS = (8, 16, 256, 264, 272, 512)   # window fits inside one
+FORMS = {   # form → (payload-table size, annotates, the wire it must take)
+    "B": (20, False, "tab8"),
+    "R.tab8": (20, True, "tab8"),
+    "R.tab16": (300, True, "tab16"),
+}
+PROPS = [{"bold": True}, {"color": "red"}, {"size": 12}]
+
+
+def _window_rows(height, where):
+    if where == "full":
+        return np.arange(N_DOCS, dtype=np.int32)
+    shard = N_DOCS // CHIPS
+    first = shard if where == "inside" else shard - height // 2
+    # the door's windows are row-sorted; the scatter may not lean on it
+    return np.random.default_rng(height).permutation(
+        np.arange(first, first + height, dtype=np.int32))
+
+
+def _windows(rows, O, form, compact8, n=4):
+    """``n`` windows on the same rows, fused zamboni off and on by turns:
+    inserts first, then removes and (props forms) annotates among them.
+    ``compact8`` off: refs lag their op by 300, past the profile's byte."""
+    from fluidframework_tpu.ops.schema import OpKind
+    n_texts, annotates, _ = FORMS[form]
+    texts = [f"t{k}" for k in range(n_texts)]
+    rng = np.random.default_rng(len(rows) * 31 + O)
+    R = len(rows)
+    base0 = 0 if compact8 else 1000
+    for w in range(n):
+        kind = np.full((R, O), int(OpKind.STR_INSERT), np.int32)
+        tidx = rng.integers(0, n_texts, (R, O)).astype(np.int32)
+        if w >= 2:      # every document holds two runs or more by now
+            edit = rng.random(R) < 0.5
+            kind[edit, 0] = int(OpKind.STR_REMOVE)
+            if annotates:
+                ann = edit & (rng.random(R) < 0.5)
+                kind[ann, 0] = int(OpKind.STR_ANNOTATE)
+                tidx[ann, 0] = rng.integers(0, len(PROPS), int(ann.sum()))
+        a0 = np.zeros((R, O), np.int32)
+        a1 = np.where(kind == int(OpKind.STR_INSERT), 0, 1).astype(np.int32)
+        seq_base = np.full(R, base0 + w * O, np.int32)
+        floor = np.zeros(N_DOCS, np.int32)
+        floor[rows] = seq_base if compact8 else seq_base - 300
+        yield dict(
+            rows=rows, kind=kind, a0=a0, a1=a1, seq_base=seq_base,
+            client_id=np.ones((R, O), np.int32),
+            ref_seq=np.broadcast_to(floor[rows][:, None], (R, O)),
+            texts=texts, tidx=tidx, props=PROPS if annotates else None,
+            min_seq=floor if w % 2 else None)
+
+
+def _parity_cases():
+    for form in FORMS:
+        for height in HEIGHTS:
+            for where in ("inside", "border"):
+                yield height, where, form, True
+    for form in ("B", "R.tab8"):
+        for height in (8, 264, 512):
+            for where in ("inside", "border"):
+                yield height, where, form, False
+    for form in FORMS:
+        for compact8 in (True, False):
+            yield N_DOCS, "full", form, compact8
+
+
+@pytest.mark.parametrize(
+    "height,where,form,compact8", list(_parity_cases()),
+    ids=lambda v: str(v))
+def test_mesh_apply_planes_matches_one_chip(height, where, form, compact8):
+    """Heights of the door's closed set with their rows inside one shard
+    and across two shards' border, and a full-store batch in row order
+    (``scatter_rows=False``); the B form and the props form on both table
+    wires; compact8 where the store chooses it and where it cannot; the
+    fused zamboni off and on: every plane equal after every window."""
+    import jax
+    from fluidframework_tpu.ops.string_store import TensorStringStore
+    on_mesh = TensorStringStore(N_DOCS, CAP, mesh=make_doc_mesh(CHIPS))
+    one_chip = TensorStringStore(N_DOCS, CAP)
+    rows = _window_rows(height, where)
+    O = 2 if where == "full" else 1     # the door's windows are one op deep
+    for w, win in enumerate(_windows(rows, O, form, compact8)):
+        for store in (on_mesh, one_chip):
+            store.apply_planes(**win)
+            assert store.last_profile[0] == \
+                ("compact8" if compact8 else "lag16")
+            assert store.last_rich_wire == FORMS[form][2]
+        for f in dataclasses.fields(on_mesh.state):
+            assert np.array_equal(
+                np.asarray(getattr(on_mesh.state, f.name)),
+                np.asarray(getattr(one_chip.state, f.name))), (w, f.name)
+    assert on_mesh._has_props == FORMS[form][1]
+    assert on_mesh.unpack_variants == one_chip.unpack_variants
+    assert all((v[7] is False) == (where == "full")      # scatter_rows
+               for v in on_mesh.unpack_variants)
+    for x in jax.tree.leaves(on_mesh.state):
+        assert len(x.sharding.device_set) == CHIPS
+
+
+def test_mesh_windows_are_born_on_the_mesh(monkeypatch):
+    """The mechanism: the planes handed to the merge carry the state's
+    sharding, so the launch moves nothing between chips — counted by the
+    store, and refused by JAX's own transfer guard when it is not so."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from fluidframework_tpu.ops import string_store
+    from fluidframework_tpu.parallel import sharded
+    from fluidframework_tpu.utils.telemetry import REGISTRY
+    store = string_store.TensorStringStore(N_DOCS, CAP,
+                                           mesh=make_doc_mesh(CHIPS))
+    wins = list(_windows(_window_rows(264, "border"), 1, "B", True, n=8))
+
+    def counted():
+        return tuple(REGISTRY.counters.get(f"mesh_windows_{k}", 0)
+                     for k in ("resident", "resharded"))
+
+    for win in wins[:2]:        # both programs compile outside the guard
+        store.apply_planes(**win)
+    before = counted()
+    with jax.transfer_guard_device_to_device("disallow"):
+        for win in wins[2:6]:
+            store.apply_planes(**win)
+    resident, resharded = counted()
+    assert (resident - before[0], resharded - before[1]) == (4, 0)
+
+    # the parent's placement (upload to one chip, unpack there) is what
+    # the guard and the second counter exist to catch
+    monkeypatch.setattr(sharded, "replicated",
+                        lambda buf, mesh: jnp.asarray(buf))
+    monkeypatch.setattr(
+        sharded, "sharded_unpack", lambda mesh, **variant: functools.partial(
+            string_store._columnar_unpack_jit, **variant))
+    store.apply_planes(**wins[6])
+    assert counted() == (resident, resharded + 1)
+    with jax.transfer_guard_device_to_device("disallow"), \
+            pytest.raises(Exception, match="device-to-device"):
+        store.apply_planes(**wins[7])
+
+
+@pytest.mark.parametrize("program", [
+    "_sharded_columnar_merge.fused", "_sharded_columnar_merge.plain",
+    "_sharded_columnar_unpack", "_sharded_compact"])
+def test_mesh_programs_carry_their_names(program):
+    """The kernel readers (``perfbench/metrics/kernel.merge_ms_per_window.*``,
+    ``merge_roofline.*``) pick the merge's module out of a trace by name;
+    an anonymous ``jit_fn`` would be summed with every other one."""
+    import jax
+    import jax.numpy as jnp
+    from fluidframework_tpu.ops.merge_tree_kernel import StringState
+    from fluidframework_tpu.parallel import sharded
+    mesh = make_doc_mesh(CHIPS)
+    n_docs, O = 64, 1
+    state = sharded.shard_store_state(StringState.create(n_docs, CAP), mesh)
+    planes = tuple(jnp.zeros((n_docs, O), jnp.int32) for _ in range(7))
+    ms = jnp.zeros((n_docs,), jnp.int32)
+    name, _, variant = program.partition(".")
+    if name == "_sharded_columnar_merge":
+        fused = variant == "fused"
+        fn = sharded.sharded_merge(mesh, False, 8, False, False, fused)
+        args = (state, planes, ms) if fused else (state, planes)
+    elif name == "_sharded_columnar_unpack":
+        fn = sharded.sharded_unpack(
+            mesh, 8, O, pos_wide=False, ref_wide=False, rich=0,
+            n_docs=n_docs, fuse_compact=True, scatter_rows=True)
+        args = (jax.ShapeDtypeStruct((n_docs + 64,), jnp.int32),)
+    else:
+        fn = sharded.sharded_compact(mesh, False)
+        args = (state, ms)
+    assert fn.__name__ == name
+    assert f"@jit_{name} " in fn.lower(*args).as_text()
