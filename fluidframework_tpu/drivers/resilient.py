@@ -28,7 +28,7 @@ that were already sequenced. Two wrappers here:
 
 Both are deterministic under injected ``random.Random`` (reconnect
 schedules replay exactly in a seeded chaos soak) and track reconnect
-latencies / resubmit counts for the bench's reconnect-storm phase.
+latencies / resubmit counts (``tools/chaos_soak.py`` reports them).
 
 Both also honor the admission plane's ``throttled`` frames
 (``server.admission``): a shed op's clientSeq is NOT burned (it was
@@ -834,8 +834,11 @@ class ResilientObserver:
     monotonically with no holes, so ``wid <= last_wid`` is a dup
     (skipped whole) and ``wid > last_wid + 1`` is a gap; per-doc
     sequenced seqs back that up at op granularity (``dups`` /
-    ``op_gaps``). The reconnect-storm test pins all four counters at
-    zero.
+    ``op_gaps``). The window is the unit of both: its ops are held
+    until the last of the header's ``n_frames`` has arrived and applied
+    together with the cursor's advance, so a run the socket tore leaves
+    nothing behind and is replayed whole. The reconnect-storm test pins
+    all four counters at zero.
     """
 
     def __init__(self, host: str, port: int, name: str = "",
@@ -858,6 +861,10 @@ class ResilientObserver:
         self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)
         self._closed = False
+        #: set while the client holds a cursor into the window stream
+        #: (the door has answered a subscribe): from then on every
+        #: window the hub publishes reaches it, across redials
+        self._subscribed = threading.Event()
         self._sock: Optional[socket.socket] = None
         #: doc → last applied sequenced seq (the resume cursor)
         self.doc_seqs: Dict[str, int] = {}
@@ -872,8 +879,12 @@ class ResilientObserver:
         self.sheds = 0           # server-side shed notices received
         self.catchup_needed = 0  # ring could not reach our cursor
         self.gave_up = False
-        #: state of the in-flight window run
+        #: state of the in-flight window run: its id, the frames still
+        #: to come, and the ops decoded from it so far
         self._skip = False
+        self._open_wid = 0
+        self._frames_left = 0
+        self._held: List[tuple] = []
         self._cops_docs: List[str] = []
         self._thread = threading.Thread(
             target=self._run, name=f"observer:{self.name}", daemon=True)
@@ -892,8 +903,10 @@ class ResilientObserver:
                 self._sock = sock
                 sub: Dict[str, Any] = {"t": "subscribe",
                                        "name": self.name}
-                if self.last_wid:
+                if self._subscribed.is_set():
                     # resume, not rehydrate: only the missed windows
+                    # (a cursor of 0 is one too: joined before the
+                    # first window, killed before it arrived)
                     sub["from_wid"] = self.last_wid + 1
                 if self.byte_rate is not None:
                     sub["byte_rate"] = self.byte_rate
@@ -929,32 +942,54 @@ class ResilientObserver:
 
     def _on_frame(self, ftype: int, payload: bytes,
                   sock: socket.socket) -> None:
-        if ftype == ord("J"):
-            msg = json.loads(bytes(payload))
+        msg = json.loads(bytes(payload)) if ftype == ord("J") else None
+        if msg is not None and msg.get("t") != "rec":
             self._on_control(msg, sock)
             return
         if self._skip:
             return
-        if ftype in (ord("B"), ord("R")):
+        if msg is not None:
+            if msg.get("fmt") == "cops":
+                self._cops_docs = list(msg["docs"])
+            elif msg.get("fmt") == "json":
+                self._held.extend(
+                    (doc, int(seq), int(client), contents)
+                    for doc, seq, client, contents in msg["ops"])
+        elif ftype in (ord("B"), ord("R")):
             self._on_op_frame(payload, rich=ftype == ord("R"))
         elif ftype == ord("T"):
             self._on_tree_frame(payload)
+        self._frames_left -= 1
+        if self._frames_left == 0:
+            self._close_window()
+
+    def _close_window(self) -> None:
+        """The open run is whole: advance the cursor and apply its
+        ops."""
+        wid = self._open_wid
+        with self._lock:
+            if wid > self.last_wid + 1:
+                self.gaps += 1
+            self.last_wid = wid
+            self.windows_applied += 1
+        for op in self._held:
+            self._apply(*op)
 
     def _on_control(self, msg: dict, sock: socket.socket) -> None:
         t = msg.get("t")
         if t == "window":
             wid = int(msg["wid"])
             with self._lock:
-                if wid <= self.last_wid:
-                    # replay overlap: skip the whole run, count the dup
-                    self._skip = True
+                # replay overlap: skip the whole run, count the dup
+                self._skip = wid <= self.last_wid
+                if self._skip:
                     self.window_dups += 1
                     return
-                if self.last_wid and wid > self.last_wid + 1:
-                    self.gaps += 1
-                self._skip = False
-                self.last_wid = wid
-                self.windows_applied += 1
+            self._open_wid = wid
+            self._frames_left = int(msg["n_frames"])
+            self._held = []
+            if self._frames_left == 0:
+                self._close_window()
         elif t == "subscribed":
             with self._lock:
                 if msg.get("catchup_needed"):
@@ -962,8 +997,9 @@ class ResilientObserver:
                     # generation-diff ladder owns the gap from here;
                     # the stream itself resumes at the live head
                     self.catchup_needed += 1
-                if not self.last_wid:
+                if not self._subscribed.is_set():
                     self.last_wid = int(msg["next_wid"]) - 1
+            self._subscribed.set()
         elif t == "gap":
             # server shed us a window (byte budget): we are parked;
             # ask for a ring replay from our cursor on this socket
@@ -976,13 +1012,8 @@ class ResilientObserver:
             # resume refused: ring too short — ladder territory
             with self._lock:
                 self.catchup_needed += 1
-                self.last_wid = 0   # rejoin at the live head
+            self._subscribed.clear()   # rejoin at the live head
             raise ConnectionError("ring behind cursor")
-        elif t == "rec" and msg.get("fmt") == "cops":
-            self._cops_docs = list(msg["docs"])
-        elif t == "rec" and msg.get("fmt") == "json":
-            for doc, seq, client, contents in msg["ops"]:
-                self._apply(doc, int(seq), int(client), contents)
 
     def _on_op_frame(self, payload: bytes, rich: bool) -> None:
         texts, props, off = colwire.parse_op_tables(payload, rich)
@@ -996,17 +1027,17 @@ class ResilientObserver:
                 op["text"] = texts[int(r["tidx"])]
             elif kind == 2 and props:            # ANNOTATE
                 op["props"] = props[int(r["tidx"])]
-            self._apply(docs[int(r["row"])], int(r["cseq"]),
-                        int(r["ref"]), op)
+            self._held.append((docs[int(r["row"])], int(r["cseq"]),
+                               int(r["ref"]), op))
 
     def _on_tree_frame(self, payload: bytes) -> None:
         from ..server.read_plane import decode_tree_frame
         header, rec_op, recs = decode_tree_frame(payload)
         docs = header["docs"]
         for i, seq in enumerate(header["seq"]):
-            self._apply(docs[int(header["doc"][i])], int(seq),
-                        int(header["client"][i]),
-                        {"tree_rec": int(rec_op[i])})
+            self._held.append((docs[int(header["doc"][i])], int(seq),
+                               int(header["client"][i]),
+                               {"tree_rec": int(rec_op[i])}))
 
     def _apply(self, doc: str, seq: int, client: int, op: Any) -> None:
         with self._cv:
@@ -1023,6 +1054,13 @@ class ResilientObserver:
             self.on_op(doc, seq, client, op)
 
     # ------------------------------------------------------------- waits
+
+    def wait_subscribed(self, timeout: float = 30.0) -> bool:
+        """Block until the door has acknowledged a subscription. A
+        first-time observer joins at the live head, so what the hub
+        published before this returns true is not delivered; what it
+        publishes afterwards is, whatever happens to the socket."""
+        return self._subscribed.wait(timeout)
 
     def wait_ops(self, n: int, timeout: float = 30.0) -> bool:
         """Block until ``n`` distinct ops have been applied."""

@@ -296,27 +296,22 @@ def _pow2_at_least(n: int, floor: int = 1) -> int:
 
 def pack_wire_records(recs_k: np.ndarray, rec_op_k: np.ndarray,
                       rows_r: np.ndarray, r_floor: int = 256,
-                      bufs=None, id_t=np.uint16, val_t=np.uint16):
+                      id_t=np.uint16, val_t=np.uint16):
     """Width-coded wire buffers for kept records — THE upload layout of
     ``tree_kernel.apply_tree_wire`` (cols: kind|meta<<4 + first-of-op
     bit, field, type; u16/u32 local ids/values; u16 row + u8/u16 pos
     with the ``pos == o`` drop sentinel; records pow2-padded to
-    ``r_floor`` buckets). One implementation shared by the serving
-    dispatch and the bench's kernel-only phase. Returns (cols, ids,
-    vals, row, pos, o), or None when the widest doc exceeds the u16
-    pos budget.
+    ``r_floor`` buckets). Every call allocates its own buffers: once
+    one has been handed to ``jnp.asarray`` nothing may write to it (the
+    CPU backend takes an aligned numpy array without a copy and runs
+    asynchronously; on a TPU the transfer may still be in flight).
+    Returns (cols, ids, vals, row, pos, o), or None when the widest doc
+    exceeds the u16 pos budget.
 
     ``id_t``/``val_t``: dtype of the id/value index lanes — u16 by
     default, widened to u32 by the caller when a batch's id or value
     table outgrows 65534 entries (big general waves; still a fraction
-    of the dense planes' bytes).
-
-    ``bufs``: optional ``(rb, pos_dtype, id_dtype, val_dtype) ->
-    (cols, ids, vals, row, pos)`` allocator (the store's pow2 wire
-    pool). Pooled buffers are NOT zeroed: only the pos padding is
-    filled (``pos == o`` drops the record — both kernel scatters key
-    on (row, pos) with mode="drop", so stale garbage in the other
-    planes' tails is never applied)."""
+    of the dense planes' bytes)."""
     r = len(recs_k)
     pos, widest = positions_in_doc(rows_r)
     o = _pow2_at_least(max(widest, 1))
@@ -324,15 +319,11 @@ def pack_wire_records(recs_k: np.ndarray, rec_op_k: np.ndarray,
         return None
     rb = _pow2_at_least(max(r, 1), floor=r_floor)
     pos_t = np.uint8 if o <= 128 else np.uint16
-    if bufs is not None:
-        cols, idsb, valsb, rowb, posb = bufs(rb, pos_t, id_t, val_t)
-        posb[r:] = o   # the drop sentinel is the only padding that matters
-    else:
-        cols = np.zeros((rb, 3), np.uint8)
-        idsb = np.zeros((rb, 3), id_t)
-        valsb = np.zeros(rb, val_t)
-        rowb = np.zeros(rb, np.uint16)
-        posb = np.full(rb, o, pos_t)   # padding records drop
+    cols = np.zeros((rb, 3), np.uint8)
+    idsb = np.zeros((rb, 3), id_t)
+    valsb = np.zeros(rb, val_t)
+    rowb = np.zeros(rb, np.uint16)
+    posb = np.full(rb, o, pos_t)   # padding records drop
     if r:
         first = np.empty(r, np.uint8)
         first[0] = 1
@@ -361,20 +352,13 @@ def positions_in_doc(rows: np.ndarray):
     return pos, (int(sizes.max()) if len(sizes) else 0)
 
 
-#: wire/map pool depth cap — bounds retained host memory at pipeline
-#: depths beyond the steady state (the string store's _tab_pool cap)
-_WIRE_POOL_DEPTH = 4
-
-
 class PrepackedWire:
     """One tree record wave's wire buffers + interner table maps, packed
     AHEAD of sequencing on the pipeline's pack worker. Every record is
     packed (nacks resolve at dispatch, which discards the prepack on
-    the rare nacked wave and repacks inline). Buffers come from the
-    store's pow2 pools and return via ``release_wire`` — safe right
-    after the dispatch call, because ``jnp.asarray`` copies host
-    buffers at the jit boundary (the pool never aliases a live
-    upload)."""
+    the rare nacked wave and repacks inline). The buffers belong to
+    this wave alone and are dropped with it: nothing may write to a
+    host buffer after it has been handed to ``jnp.asarray``."""
 
     __slots__ = ("cols", "idsb", "valsb", "rowb", "posb", "o",
                  "id_map", "f_map", "t_map", "v_map")
@@ -397,12 +381,6 @@ class TensorTreeStore:
         self._fields = _Interner()
         self._types = _Interner()
         self._values = ValueInterner()
-        # pow2 wire/map buffer pools for the prepacked wire path, keyed
-        # by bucket size (GIL-atomic list push/pop: the pack worker pops
-        # while the dispatch stage releases — the string store's
-        # _tab_pool discipline)
-        self._wire_pool: Dict[tuple, list] = {}
-        self._map_pool: Dict[int, list] = {}
 
     # --------------------------------------------------------- capacity plane
 
@@ -471,26 +449,11 @@ class TensorTreeStore:
 
     # ------------------------------------------------------- prepacked wire
 
-    def _wire_buffers(self, rb: int, pos_t, id_t, val_t):
-        """Pop (or allocate) one pow2 wire-buffer set; tails are NOT
-        zeroed — ``pack_wire_records`` fills the pos drop sentinel."""
-        key = (rb, np.dtype(pos_t).itemsize, np.dtype(id_t).itemsize,
-               np.dtype(val_t).itemsize)
-        stack = self._wire_pool.get(key)
-        if stack:
-            return stack.pop()
-        return (np.empty((rb, 3), np.uint8), np.empty((rb, 3), id_t),
-                np.empty(rb, val_t), np.empty(rb, np.uint16),
-                np.empty(rb, pos_t))
-
-    def _pad_map(self, items, interner) -> np.ndarray:
-        """Pooled pow2 local-index → interner-handle map. Only
-        ``[0, len(items)]`` is ever gathered by a validated record
-        (handle 0 == none), so the stale tail needs no zeroing."""
-        cap = _pow2_at_least(len(items) + 1, floor=8)
-        stack = self._map_pool.get(cap)
-        m = stack.pop() if stack else np.empty(cap, np.int32)
-        m[0] = 0
+    @staticmethod
+    def _pad_map(items, interner) -> np.ndarray:
+        """Pow2-padded local-index → interner-handle map (handle 0 ==
+        none)."""
+        m = np.zeros(_pow2_at_least(len(items) + 1, floor=8), np.int32)
         if items:
             m[1:len(items) + 1] = interner.bulk(items)
         return m
@@ -498,8 +461,8 @@ class TensorTreeStore:
     def prepack_wire(self, recs: np.ndarray, rec_op: np.ndarray,
                      rows_r: np.ndarray, tables: dict,
                      r_floor: int = 256) -> Optional[PrepackedWire]:
-        """Pack ALL of a wave's records + interner maps into pooled
-        pow2 wire buffers ahead of sequencing (the pipeline's pack
+        """Pack ALL of a wave's records + interner maps into pow2 wire
+        buffers of its own ahead of sequencing (the pipeline's pack
         worker; the ``ops/string_store.prepack_planes`` analog).
         Returns None when the widest doc overflows the u16 pos budget
         (the dense path must take the wave). The id/value index lanes
@@ -507,7 +470,7 @@ class TensorTreeStore:
         waves (one fresh node id per op) stay on the wire instead of
         falling to dense planes."""
         packed = pack_wire_records(
-            recs, rec_op, rows_r, r_floor=r_floor, bufs=self._wire_buffers,
+            recs, rec_op, rows_r, r_floor=r_floor,
             id_t=np.uint16 if len(tables["ids"]) < 0xFFFF else np.uint32,
             val_t=(np.uint16 if len(tables["values"]) < 0xFFFF
                    else np.uint32))
@@ -523,26 +486,11 @@ class TensorTreeStore:
 
     def apply_wire_prepacked(self, pp: PrepackedWire,
                              base: np.ndarray) -> None:
-        """Dispatch a prepacked wave (``base`` arrives post-sequencing)
-        and return its buffers to the pools — the jit boundary copied
-        them."""
+        """Dispatch a prepacked wave (``base`` arrives
+        post-sequencing)."""
         self.apply_wire(pp.cols, pp.idsb, pp.valsb, pp.rowb, pp.posb,
                         base, pp.id_map, pp.f_map, pp.t_map, pp.v_map,
                         pp.o)
-        self.release_wire(pp)
-
-    def release_wire(self, pp: PrepackedWire) -> None:
-        """Return a prepack's pooled buffers (after dispatch, or when a
-        nacked wave discards its prepack for the inline repack)."""
-        key = (len(pp.posb), pp.posb.dtype.itemsize,
-               pp.idsb.dtype.itemsize, pp.valsb.dtype.itemsize)
-        stack = self._wire_pool.setdefault(key, [])
-        if len(stack) < _WIRE_POOL_DEPTH:
-            stack.append((pp.cols, pp.idsb, pp.valsb, pp.rowb, pp.posb))
-        for m in (pp.id_map, pp.f_map, pp.t_map, pp.v_map):
-            s = self._map_pool.setdefault(len(m), [])
-            if len(s) < _WIRE_POOL_DEPTH:
-                s.append(m)
 
     def apply_records(self, rows: np.ndarray, recs: np.ndarray,
                       seqs: np.ndarray) -> None:
@@ -794,6 +742,4 @@ class TensorTreeStore:
         store._fields = _Interner.restore(snap["fields"])
         store._types = _Interner.restore(snap["types"])
         store._values = ValueInterner.restore(snap["values"])
-        store._wire_pool = {}
-        store._map_pool = {}
         return store

@@ -1344,8 +1344,9 @@ class ColumnarAlfred:
         return rows
 
     def drain_stats(self) -> dict:
-        """Decode-stage evidence (bench.py / storm bench): p50 drain
-        pass latency, drained bytes per pass, pass count, decode tier."""
+        """Decode-stage evidence (``perfbench/`` and the chip smoke read
+        its ``tier``): p50 drain pass latency, drained bytes per pass, pass
+        count, decode tier."""
         ms = sorted(self._drain_ms)
         by = sorted(self._drain_bytes)
         return {

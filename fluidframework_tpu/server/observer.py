@@ -194,33 +194,35 @@ class ObserverHub:
             sub = _Sub(sid, name or f"observer-{sid}", sink, bucket,
                        last)
             self._subs[sid] = sub
-        ring_from = ring[0][0] if ring else None
-        catchup_needed = bool(
-            from_wid is not None and ring and from_wid < ring_from)
-        if from_wid is not None and not catchup_needed:
-            for wid, payload, n_ops, _t in ring:
-                if wid < from_wid:
-                    continue
-                # replay rides the same budget as live delivery
-                if sub.bucket is not None:
-                    got = sub.bucket.grant(len(payload),
-                                           time.monotonic())
-                    if got < len(payload):
-                        sub.bucket.tokens += got
-                        sub.sheds += 1
-                        sub.parked = True
-                        REGISTRY.inc("observer_sheds_total")
-                        try:
-                            sub.sink(encode_json({"t": "gap",
-                                                  "wid": wid}))
-                        except Exception:
-                            pass
-                        break
-                sub.sink(payload)
-                sub.last_wid = wid
-                sub.delivered_windows += 1
-                sub.delivered_ops += n_ops
-                sub.delivered_bytes += len(payload)
+            # the replay stays under the lock: a window published
+            # meanwhile reaches this sink after the replayed ones
+            ring_from = ring[0][0] if ring else None
+            catchup_needed = bool(
+                from_wid is not None and ring and from_wid < ring_from)
+            if from_wid is not None and not catchup_needed:
+                for wid, payload, n_ops, _t in ring:
+                    if wid < from_wid:
+                        continue
+                    # replay rides the same budget as live delivery
+                    if sub.bucket is not None:
+                        got = sub.bucket.grant(len(payload),
+                                               time.monotonic())
+                        if got < len(payload):
+                            sub.bucket.tokens += got
+                            sub.sheds += 1
+                            sub.parked = True
+                            REGISTRY.inc("observer_sheds_total")
+                            try:
+                                sub.sink(encode_json({"t": "gap",
+                                                      "wid": wid}))
+                            except Exception:
+                                pass
+                            break
+                    sub.sink(payload)
+                    sub.last_wid = wid
+                    sub.delivered_windows += 1
+                    sub.delivered_ops += n_ops
+                    sub.delivered_bytes += len(payload)
         REGISTRY.inc("observer_subscribes_total")
         return {"sid": sid, "next_wid": sub.last_wid + 1,
                 "ring_from": ring_from, "catchup_needed": catchup_needed}
@@ -232,23 +234,23 @@ class ObserverHub:
     def resume(self, sid: int, from_wid: int) -> bool:
         """Un-park a shed subscriber, replaying [from_wid..] from the
         ring; False when the ring no longer reaches (catch-up needed)."""
-        with self._lock:
+        with self._lock:    # held through the replay, as in subscribe
             sub = self._subs.get(sid)
-            ring = list(self._ring)
-        if sub is None:
-            return False
-        if ring and from_wid < ring[0][0]:
-            return False
-        for wid, payload, n_ops, _t in ring:
-            if wid < from_wid:
-                continue
-            sub.sink(payload)
-            sub.last_wid = wid
-            sub.delivered_windows += 1
-            sub.delivered_ops += n_ops
-            sub.delivered_bytes += len(payload)
-        sub.parked = False
-        return True
+            ring = self._ring
+            if sub is None:
+                return False
+            if ring and from_wid < ring[0][0]:
+                return False
+            for wid, payload, n_ops, _t in ring:
+                if wid < from_wid:
+                    continue
+                sub.sink(payload)
+                sub.last_wid = wid
+                sub.delivered_windows += 1
+                sub.delivered_ops += n_ops
+                sub.delivered_bytes += len(payload)
+            sub.parked = False
+            return True
 
     # ------------------------------------------------------------- health
 

@@ -1,10 +1,10 @@
 """Live operations plane (ISSUE 17): scrape endpoint, latency
 attribution, hot-doc introspection.
 
-Until now every observability surface was post-hoc — ``full_snapshot()``
-embedded in bench records, ``TimeSeriesStore`` ticked only inside
-bench.py, ``tools/healthz.py`` reading JSONL exports after the run. This
-module makes a *running* server observable:
+Every other observability surface is post-hoc — a ``full_snapshot()``
+taken by the caller, a ``TimeSeriesStore`` the caller ticks,
+``tools/healthz.py`` reading JSONL exports after the run. This module
+makes a *running* server observable:
 
 * :class:`OpsServer` — a threaded HTTP façade (``utils.ops_http``) over
   the process singletons: ``/metrics`` (Prometheus text exposition with

@@ -3382,7 +3382,7 @@ class TreeServingEngine(ServingEngineBase):
 
     def _dispatch_wire(self, batch, recs, rec_op, keep, rows, out_seq,
                        nacked):
-        """Pack kept records into pooled width-coded wire buffers and
+        """Pack kept records into width-coded wire buffers and
         dispatch ``apply_tree_wire`` (upload bytes are the bottleneck —
         see tree_kernel). Returns the prep/dispatch split timestamp, or
         None when the dense path must handle the batch (oversized o)."""
@@ -3409,7 +3409,7 @@ class TreeServingEngine(ServingEngineBase):
                         rows: Optional[np.ndarray] = None,
                         prepack: bool = False) -> "_TreeIngestWave":
         """Stage 1 — validation, row resolution, row-handle fill, and
-        (``prepack=True``, pipelined mode) the pooled wire pack +
+        (``prepack=True``, pipelined mode) the wire pack +
         interner maps, all independent of sequencing results."""
         raw = getattr(self.deli, "raw", None)
         if raw is None:
@@ -3496,7 +3496,6 @@ class TreeServingEngine(ServingEngineBase):
         if pp is not None and w.nacked.any():
             # rare: the prepack packed EVERY record; drop it and repack
             # inline below with the keep mask
-            self.store.release_wire(pp)
             pp = w.prepacked = None
         t_prep = None
         if pp is not None:

@@ -18,9 +18,8 @@ from the metric's histogram exemplars (``Histogram.observe(exemplar=)``)
 when it has one, else the thread's current trace context. Subsequent
 ticks in the same breach stay quiet until the spec recovers (re-arm).
 
-``tools/healthz.py`` renders the scorecard; bench.py embeds it in the
-BENCH record so ``tools/perf_sentinel.py`` and humans judge a round by
-the same targets.
+``tools/healthz.py`` renders the scorecard and the ops server's
+``/healthz`` serves it.
 """
 
 from __future__ import annotations
@@ -234,7 +233,7 @@ class SLOEngine:
 
     def scorecard(self, now: Optional[float] = None) -> List[dict]:
         """Side-effect-free evaluation of every spec: the table healthz
-        prints and bench.py embeds (one row per matched series; specs
+        prints and ``/healthz`` serves (one row per matched series; specs
         matching nothing report a single unjudged row so a typo'd metric
         pattern is visible, not silently green)."""
         rows: List[dict] = []

@@ -382,8 +382,8 @@ class MetricsRegistry:
 
     def full_snapshot(self) -> dict:
         """Own snapshot + every live attached component's, prefixed
-        ``{component}.{metric}`` — the process-wide metric set bench.py
-        embeds in BENCH json. Sharded attachments (components labeled
+        ``{component}.{metric}`` — the process-wide metric set
+        ``TimeSeriesStore`` samples. Sharded attachments (components labeled
         ``shard=``) additionally roll up into computed cross-shard skew
         keys: ``{name}.ops_applied_shard_{min,max,skew}`` — the max/min
         ops-applied imbalance is the load-balance health signal."""

@@ -5,14 +5,14 @@ lag/latency alerting — a server is healthy not because a counter exists
 but because its *trajectory* stays inside a target. PR 2 gave this stack
 point-in-time metrics (``telemetry.MetricsRegistry``); this module adds
 the notion of time: a :class:`TimeSeriesStore` samples
-``REGISTRY.full_snapshot()`` on a clock **the caller ticks** (bench.py
-phase boundaries, serving loops, tests — this module itself spawns no
+``REGISTRY.full_snapshot()`` on a clock **the caller ticks** (serving
+loops, ``tools/`` drills, tests — this module itself spawns no
 thread; on live servers the ``server.opsd.OpsServer`` ticker is the
 clock, everywhere else determinism and zero idle cost win),
 keeps a bounded ring of history per metric, derives rates from counters
 (reset-aware), and answers windowed percentile reads. ``utils.slo``
 evaluates burn-rate targets over it; ``tools/healthz.py`` renders it as
-a sparkline dashboard; bench.py exports it as JSONL evidence.
+a sparkline dashboard from a JSONL export (``jsonl_path=``).
 
 Sampling cost is one ``full_snapshot()`` (dict merges) plus one bounded
 ``deque.append`` per metric — safe to tick at phase boundaries of a hot

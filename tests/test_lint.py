@@ -1,7 +1,7 @@
 """Doc-registry lints (ISSUE 19 satellite): the AST sweeps that keep
 docs/OBSERVABILITY.md honest, as a tier-1 gate.
 
-Two lints:
+Three lints:
 
 * metric-name lint — every metric name used anywhere in the tree
   (``.inc(`` / ``.set_gauge(`` / ``.observe(`` with a literal name)
@@ -10,10 +10,16 @@ Two lints:
 * route lint — every ``/debug/*`` route registered in
   ``server/opsd.py`` must appear backtick-quoted in the doc's routes
   table. An undocumented debug route is a debug route nobody curls.
+* path lint — every file or directory a document names in back-ticks
+  exists in the tree. A pointer to a deleted file is a claim nobody can
+  check (PR 30 deleted a benchmark stack that 60 such pointers named).
 """
 
 import ast
+import functools
+import os
 import pathlib
+import re
 
 import pytest
 
@@ -33,9 +39,7 @@ def metric_names_in_tree():
     families), and both arms of a literal conditional. ``observe``
     calls with a non-string first arg are ``Histogram.observe(value)``
     — not a name site. Returns ``{name: "file:line"}``."""
-    roots = [PKG_ROOT,
-             PKG_ROOT.parent / "bench.py",
-             PKG_ROOT.parent / "tools"]
+    roots = [PKG_ROOT, PKG_ROOT.parent / "tools"]
     files = []
     for r in roots:
         files += sorted(r.rglob("*.py")) if r.is_dir() else [r]
@@ -105,3 +109,75 @@ def test_all_debug_routes_documented():
     assert not missing, (
         "/debug routes missing from docs/OBSERVABILITY.md's routes "
         f"table: {missing}")
+
+
+# ------------------------------------------------------------- path lint
+
+REPO = PKG_ROOT.parent
+PATH_DOCS = (["README.md", "PERF.md", "ROADMAP.md", "PARITY.md",
+              "DISTRIBUTED.md", ".claude/skills/verify/SKILL.md"]
+             + sorted(f"docs/{p.name}" for p in (REPO / "docs").glob("*.md")))
+#: names a document may hold though the tree does not: what it records
+#: as deleted (PR 30), and files the program writes at run time
+NOT_IN_TREE = {
+    "bench.py", "benches/", "tools/bench_report.py",
+    "tools/perf_sentinel.py", "BENCHES.md", "ADVICE.md",
+    ".manifest.json", "epoch.json", "fence.json", "out.json",
+}
+_TICKED = re.compile(r"`([^`\n]+)`")
+_PATH_LIKE = re.compile(r"^[\w.\-/]+(\.py|\.md|\.json|/)$")
+
+
+@functools.lru_cache(maxsize=None)
+def _tree():
+    """Every file and directory of the checkout as ``/``-rooted posix
+    names, less ``.git`` and the directories ``.gitignore`` lists."""
+    skip = {".git"} | {
+        ln.strip().rstrip("/")
+        for ln in (REPO / ".gitignore").read_text().splitlines()
+        if ln.strip().endswith("/")}
+    files, dirs = [], []
+    for d, subdirs, names in os.walk(REPO):
+        subdirs[:] = [x for x in subdirs if x not in skip]
+        rel = pathlib.Path(d).relative_to(REPO).as_posix()
+        rel = "/" if rel == "." else f"/{rel}/"
+        dirs += [f"{rel}{x}/" for x in subdirs]
+        files += [f"{rel}{x}" for x in names]
+    return files, dirs
+
+
+def _named_paths(text):
+    """Back-ticked tokens that name a file or directory of this repo:
+    they end in ``.py``, ``.md``, ``.json`` or ``/``, hold no wildcard
+    or placeholder, and are not absolute (``file.py:line`` and
+    ``file.py::test`` name ``file.py``)."""
+    for m in _TICKED.finditer(text):
+        for tok in m.group(1).split():
+            tok = tok.split(":")[0].strip("(),;'\"")
+            if _PATH_LIKE.match(tok) and not tok.startswith("/"):
+                yield tok
+
+
+@pytest.mark.parametrize("doc", PATH_DOCS)
+def test_paths_a_document_names_exist(doc):
+    """A name may be given from the root or from any directory below it
+    (``server/serving.py`` for ``fluidframework_tpu/server/serving.py``).
+    Of ``ROADMAP.md`` only "Open items" is held to it: its history names
+    what earlier PRs deleted."""
+    text = (REPO / doc).read_text()
+    if doc == "ROADMAP.md":
+        text = text[text.index("## Open items"):text.index("## Recent")]
+    files, dirs = _tree()
+    missing = sorted({
+        n for n in _named_paths(text)
+        if n not in NOT_IN_TREE and not any(
+            p.endswith("/" + n.lstrip("./"))
+            for p in (dirs if n.endswith("/") else files))})
+    assert not missing, f"{doc} names what the tree does not hold: {missing}"
+
+
+def test_the_path_lint_sees_a_stale_pointer():
+    text = ("see `server/serving.py:1570`, `python nope/gone.py --x`, "
+            "`perfbench/metrics/<name>.json`, `docs/*.md`, `/root/x.json`")
+    assert list(_named_paths(text)) == ["server/serving.py",
+                                        "nope/gone.py"]

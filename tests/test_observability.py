@@ -356,9 +356,7 @@ def _metric_names_in_tree():
     families), and both arms of a literal conditional. ``observe`` calls
     with a non-string first arg are ``Histogram.observe(value)`` — not a
     name site. Returns {name: "file:line"}."""
-    roots = [PKG_ROOT,
-             PKG_ROOT.parent / "bench.py",
-             PKG_ROOT.parent / "tools"]
+    roots = [PKG_ROOT, PKG_ROOT.parent / "tools"]
     files = []
     for r in roots:
         files += sorted(r.rglob("*.py")) if r.is_dir() else [r]

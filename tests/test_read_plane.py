@@ -21,6 +21,7 @@ Four surfaces under test:
 
 import json
 import random
+import socket
 import threading
 import time
 
@@ -161,6 +162,29 @@ def test_hub_ring_replay_and_catchup_signal():
     assert got2 == []
 
 
+def test_a_window_published_during_a_replay_arrives_after_it():
+    """A resubscriber's ring replay and a concurrent publish: the live
+    window must reach the sink after the replayed ones (before them the
+    client counts a gap and drops the replay as dups)."""
+    hub = ObserverHub(ring=8, tracker=StalenessTracker())
+    for i in (1, 2, 3):
+        hub.publish(hub.next_wid(), b"w%d" % i, 1)
+    got, publisher = [], []
+
+    def sink(payload):
+        got.append(payload)
+        if payload == b"w1":        # mid-replay, another thread publishes
+            t = threading.Thread(
+                target=lambda: hub.publish(hub.next_wid(), b"w4", 1))
+            t.start()
+            publisher.append(t)
+            t.join(0.2)
+
+    hub.subscribe(sink, from_wid=1)
+    publisher[0].join(5)
+    assert got == [b"w1", b"w2", b"w3", b"w4"]
+
+
 def test_hub_dead_sink_unsubscribes():
     hub = ObserverHub(tracker=StalenessTracker())
 
@@ -192,6 +216,7 @@ def test_delivery_all_families(family):
     obs = ResilientObserver("127.0.0.1", door.port, name=family,
                             rng=random.Random(1))
     try:
+        assert obs.wait_subscribed(30)
         rng = random.Random(9)
         gen = OpGen(rng, family, DOCS)
         cseq = {d: 0 for d in DOCS}
@@ -216,6 +241,120 @@ def test_delivery_all_families(family):
         door.stop()
 
 
+def test_wait_subscribed_then_every_op_is_delivered():
+    """``wait_subscribed`` returns once the hub holds the subscriber:
+    every window published from that instant on arrives, the first one
+    included, with no sleep to cover the dial."""
+    eng, hub, plane, door = _start_plane("string")
+    obs = ResilientObserver("127.0.0.1", door.port, name="first",
+                            rng=random.Random(2))
+    try:
+        assert obs.wait_subscribed(30)
+        assert hub.stats()["subscribers"] == 1
+        for d in DOCS:
+            eng.connect(d, 1)
+        n = 32
+        for i in range(n):          # a window an op: 32 publishes at once
+            d = DOCS[i % len(DOCS)]
+            _msg, nack = eng.submit(d, 1, i // len(DOCS) + 1, 0,
+                                    {"mt": "insert", "kind": 0, "pos": 0,
+                                     "text": f"w{i}"})
+            assert not nack, nack
+            eng.flush()
+        assert obs.wait_ops(n, 30), (obs.ops_applied, obs.gave_up)
+        assert obs.ops_applied == n == hub.stats()["ops_published"]
+        assert obs.gaps == 0 and obs.op_gaps == 0
+        assert obs.dups == 0 and obs.window_dups == 0
+    finally:
+        obs.close()
+        door.stop()
+
+
+def test_wait_subscribed_is_false_on_a_dead_port():
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()                       # nothing listens there now
+    obs = ResilientObserver("127.0.0.1", port, attempts=2,
+                            base_delay=0.01, dial_timeout=1.0)
+    assert not obs.wait_subscribed(0.2)
+    obs.close()
+    assert not obs.wait_subscribed(0.05)
+    assert obs.ops_applied == 0
+
+
+@pytest.mark.parametrize("first_dial, from_wid", [
+    ("no_window_yet", 1), ("a_torn_window", 2)])
+def test_a_redial_resumes_from_the_last_whole_window(first_dial,
+                                                     from_wid):
+    """The socket dies (a) after the subscription and before the first
+    window, (b) between a window's header and its last frame. The
+    client's cursor names the last WHOLE window, 0 included, so the
+    redial asks for what it lacks and no op is lost or applied twice."""
+    from fluidframework_tpu.server.columnar_ingress import (
+        encode_json, read_frame)
+
+    def window(wid, seqs):
+        recs = [encode_json({"t": "rec", "fmt": "json", "wid": wid,
+                             "ops": [["d0", s, 1, {"n": s}]]})
+                for s in seqs]
+        head = encode_json({"t": "window", "wid": wid,
+                            "n_ops": len(seqs), "n_frames": len(recs)})
+        return head, recs
+
+    def subscribed(next_wid):
+        return encode_json({"t": "subscribed", "sid": next_wid,
+                            "next_wid": next_wid, "ring_from": 1,
+                            "catchup_needed": False})
+
+    stream = ((1, [1, 2]), (2, [3, 4, 5]), (3, [6]))
+    srv = socket.socket()
+    srv.bind(("127.0.0.1", 0))
+    srv.listen(2)
+    asked = []
+
+    def door():
+        conn, _ = srv.accept()
+        asked.append(json.loads(bytes(read_frame(conn)[1])))
+        sent = subscribed(1)
+        if first_dial == "a_torn_window":
+            h1, r1 = window(*stream[0])
+            h2, r2 = window(*stream[1])
+            sent += h1 + b"".join(r1) + h2 + r2[0]
+        conn.sendall(sent)
+        conn.close()
+        # the redial: the ring replays from the cursor it names
+        conn, _ = srv.accept()
+        sub = json.loads(bytes(read_frame(conn)[1]))
+        asked.append(sub)
+        conn.sendall(subscribed(4))
+        for wid, seqs in stream:
+            if wid >= sub.get("from_wid", 4):
+                h, r = window(wid, seqs)
+                conn.sendall(h + b"".join(r))
+        read_frame(conn)            # the client's close
+        conn.close()
+
+    t = threading.Thread(target=door, daemon=True)
+    t.start()
+    got = []
+    obs = ResilientObserver("127.0.0.1", srv.getsockname()[1],
+                            rng=random.Random(3), base_delay=0.01,
+                            on_op=lambda d, s, c, op: got.append(s))
+    try:
+        assert obs.wait_ops(6, 30), (obs.ops_applied, asked)
+        assert "from_wid" not in asked[0]
+        assert asked[1]["from_wid"] == from_wid
+        assert got == [1, 2, 3, 4, 5, 6]
+        assert obs.windows_applied == 3 and obs.last_wid == 3
+        assert obs.gaps == 0 and obs.op_gaps == 0
+        assert obs.dups == 0 and obs.window_dups == 0
+    finally:
+        obs.close()
+        t.join(5)
+        srv.close()
+
+
 def test_reconnect_mid_storm_exactly_once():
     """Observers killed repeatedly while a writer storms: each redial
     resubscribes from ``last_wid + 1`` and the hub's ring replays the
@@ -229,7 +368,8 @@ def test_reconnect_mid_storm_exactly_once():
     try:
         for d in DOCS:
             eng.connect(d, 1)
-        time.sleep(0.1)
+        for o in obs:
+            assert o.wait_subscribed(30)
         total = 160
         cseq = {d: 0 for d in DOCS}
         stop = threading.Event()

@@ -2,6 +2,8 @@
 encode→decode round-trips, ingest_records vs per-op submit parity,
 durable TreeRecordOps codec, raw-plane recovery, and bounds rejection."""
 
+import random
+
 import numpy as np
 import pytest
 
@@ -113,13 +115,18 @@ def _fuzz_waves(docs, seeds):
     return waves
 
 
-def test_ingest_records_matches_per_op_submit():
+#: the fuzz comparisons below each run over two sets of six sessions
+SEED_SETS = (0, 100)
+
+
+@pytest.mark.parametrize("seed0", SEED_SETS)
+def test_ingest_records_matches_per_op_submit(seed0):
     """The columnar record path and the per-op submit path produce the
     same trees for the same op streams (fuzz corpus incl. transactions,
     nested inserts, moves, removes)."""
     eng_a, docs = _mk()
     eng_b, _ = _mk()
-    waves = _fuzz_waves(docs, range(10, 16))
+    waves = _fuzz_waves(docs, range(seed0 + 10, seed0 + 16))
     for w, (ids, ops) in enumerate(waves):
         cseq = [w + 1] * len(ids)
         res = eng_a.ingest_batch(ids, [1] * len(ids), cseq,
@@ -132,24 +139,77 @@ def test_ingest_records_matches_per_op_submit():
         assert eng_a.to_dict(d) == eng_b.to_dict(d), d
 
 
-def test_ingest_records_oracle_parity_and_log_replay():
-    eng, docs = _mk()
-    waves = _fuzz_waves(docs, range(20, 26))
-    for w, (ids, ops) in enumerate(waves):
-        eng.ingest_batch(ids, [1] * len(ids), [w + 1] * len(ids),
-                         [0] * len(ids), ops)
-    for d in docs[:3]:
+def _assert_oracle_parity(eng, docs):
+    for d in docs:
         oracle = SharedTree(d, 999)
         for m in eng._doc_log_messages(d):
             oracle.process_core(m, local=False)
         assert eng.to_dict(d) == oracle.to_dict(), d
 
 
-def test_tree_records_summary_tail_recovery():
+@pytest.mark.parametrize("seed0", SEED_SETS)
+def test_ingest_records_oracle_parity_and_log_replay(seed0):
+    eng, docs = _mk()
+    waves = _fuzz_waves(docs, range(seed0 + 20, seed0 + 26))
+    for w, (ids, ops) in enumerate(waves):
+        eng.ingest_batch(ids, [1] * len(ids), [w + 1] * len(ids),
+                         [0] * len(ids), ops)
+    _assert_oracle_parity(eng, docs[:3])
+
+
+def test_back_to_back_waves_of_one_shape_lose_no_record():
+    """Many small waves of ONE bucket shape (six one-record ops, a
+    256-record wire bucket) pushed through ``ingest_batch`` with no
+    device sync between them: a wave's wire buffers and table maps must
+    still hold its records when the device reads them, however many
+    waves the host has packed since. Compared at the end with the
+    per-op engine and the oracle."""
+    eng_a, docs = _mk()
+    eng_b, _ = _mk()
+    rng = random.Random(5)
+    live = {d: [] for d in docs}
+    n_waves = 150
+    for w in range(n_waves):
+        ops = []
+        for d in docs:
+            nodes = live[d]
+            k = rng.random() if len(nodes) >= 4 else 0.0
+            if k < 0.6:
+                after = rng.choice(nodes) if nodes and rng.random() < 0.7 \
+                    else None
+                nid = f"{d}-n{w}"
+                ops.append({"op": "insert", "parent": "root",
+                            "field": "kids", "after": after,
+                            "nodes": [{"id": nid, "type": None,
+                                       "value": w}]})
+                nodes.append(nid)
+            elif k < 0.8:
+                ops.append({"op": "setValue", "id": rng.choice(nodes),
+                            "value": f"v{w}"})
+            elif k < 0.9:
+                nid, after = rng.sample(nodes, 2)
+                ops.append({"op": "move", "id": nid, "parent": "root",
+                            "field": "kids", "after": after})
+            else:
+                ops.append({"op": "remove",
+                            "id": nodes.pop(rng.randrange(len(nodes)))})
+        n = len(docs)
+        res = eng_a.ingest_batch(docs, [1] * n, [w + 1] * n, [0] * n, ops)
+        assert res["nacked"] == 0
+        for d, op in zip(docs, ops):
+            _, nack = eng_b.submit(d, 1, w + 1, 0, op)
+            assert nack is None
+    for d in docs:
+        assert eng_a.to_dict(d) == eng_b.to_dict(d), d
+    _assert_oracle_parity(eng_a, docs)
+
+
+@pytest.mark.parametrize("seed0", SEED_SETS)
+def test_tree_records_summary_tail_recovery(seed0):
     """Raw-plane tail replay: summary mid-stream, more record batches,
     then load() rebuilds the same trees (and sequencing continues)."""
     eng, docs = _mk()
-    waves = _fuzz_waves(docs, range(30, 36))
+    waves = _fuzz_waves(docs, range(seed0 + 30, seed0 + 36))
     cut = len(waves) // 2
     for w, (ids, ops) in enumerate(waves[:cut]):
         eng.ingest_batch(ids, [1] * len(ids), [w + 1] * len(ids),
@@ -425,8 +485,7 @@ def test_wire_width_coding_u32_parity():
 def test_pack_wire_records_width_parameters():
     """pack_wire_records' u16 and u32 packings carry identical indices —
     the width is a wire-size knob, not a semantic one — and prepack_wire
-    picks the width from the table sizes (pool buckets keyed by
-    itemsize, so u16 and u32 waves never alias a buffer)."""
+    picks the width from the table sizes."""
     from fluidframework_tpu.ops.tree_store import pack_wire_records
     ops = [{"op": "insert", "parent": "root", "field": "kids",
             "after": None, "nodes": [{"id": f"m{i}", "value": i}]}

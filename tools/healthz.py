@@ -2,8 +2,8 @@
 """Text health dashboard: JSONL exports or a live ops endpoint.
 
 The operator-facing face of the health plane (ISSUE 4, live mode ISSUE
-17): bench.py (and any serving loop ticking a ``TimeSeriesStore`` with
-``jsonl_path=``) leaves a JSONL trail of metric samples; this tool
+17): a serving loop ticking a ``TimeSeriesStore`` with
+``jsonl_path=`` leaves a JSONL trail of metric samples; this tool
 re-loads it and renders the two things an operator checks first:
 
 - ``render_sparklines()`` — one line per active metric, recent shape +
