@@ -39,8 +39,9 @@ connection's accumulated bytes at once: frame split + crc verify
 (``native/ingress.cpp`` fast tier, numpy/zlib fallback), op records
 gathered into contiguous int32 planes, per-frame payload tables interned
 across the pass, and the whole backlog carved into unique-row windows
-(stable sort by row + per-row occurrence level — per-doc FIFO across
-windows is the sort's stability) that feed ``ingest_planes`` directly,
+up to four columns wide (stable sort by row, then each row's next 4 or
+1 pending ops a round — per-doc FIFO across columns and windows is the
+sort's stability) that feed ``ingest_planes`` directly,
 through the ``PipelinedIngestExecutor`` when ``pipeline_depth > 0``.
 Decode cost scales with bytes drained, not frames seen. Control (``J``)
 frames and all resilience contracts (join/resume, epoch, dup_ack via the
@@ -78,6 +79,13 @@ _OP_DTYPE = np.dtype([("row", "<u2"), ("kind", "u1"), ("a0", "<u2"),
                       ("a1", "<u2"), ("tidx", "u1"), ("cseq", "<u4"),
                       ("ref", "<u4")])
 assert _OP_DTYPE.itemsize == 16
+#: the column counts a window may have, widest first. A window is R
+#: unique rows times O columns, dense: column j holds each row's j-th
+#: pending op of the drain pass. ``O`` is static in the store's unpack
+#: and merge programs, so each count is a set of compiled programs, and
+#: a merge program's first use costs seconds of set-up on a mesh: the
+#: set stays closed and as small as it can be
+_WINDOW_COLUMNS = (4, 1)
 
 _FT_J, _FT_B, _FT_R = ord("J"), ord("B"), ord("R")
 
@@ -511,6 +519,10 @@ class ColumnarAlfred:
                             if decode == "auto" else decode == "native")
         self.evictions = 0
         self.windows_flushed = 0
+        #: windows handed to each partition's engine so far: a window's
+        #: number there says whether its merge fuses the zamboni
+        #: (``_build_windows``)
+        self._windows_to = [0] * self.n_partitions
         self.ops_ingested = 0
         self.drain_passes = 0
         self.drained_bytes = 0
@@ -538,6 +550,11 @@ class ColumnarAlfred:
         #: partition so one saturated sequencer never blocks its peers
         self._executors: List[PipelinedIngestExecutor] = []
         self._waves_inflight = [0] * self.n_partitions
+        #: wid → rows of each partition's windows in flight (submitted,
+        #: acks not fanned yet): a wide window waits for those that
+        #: share a row with it (``_wait_capacity``)
+        self._rows_inflight: List[Dict[int, np.ndarray]] = [
+            {} for _ in range(self.n_partitions)]
         self._capacity: Optional[asyncio.Event] = None
         self._pipeline_error: Optional[BaseException] = None
         #: heavy-hitter sketch over (doc, tenant), fed by the drain pass
@@ -591,6 +608,7 @@ class ColumnarAlfred:
             pass
         self._executors[p] = PipelinedIngestExecutor(
             self._engine_of(p), depth=self.pipeline_depth)
+        self._windows_to[p] = 0     # the new engine counts from nothing
 
     # ------------------------------------------------------------ ingest side
 
@@ -933,11 +951,23 @@ class ColumnarAlfred:
                                 counts.tolist())
 
     def _build_windows(self) -> List[dict]:
-        """Carve the pass's decoded backlog into unique-row windows:
-        stable sort by row, split by per-row occurrence level (level k =
-        every row's k-th pending op — per-doc FIFO is the sort's
-        stability), chunk levels to ``window_min_rows``. Each window
-        compacts its own text/props tables from the pass interner."""
+        """Carve the pass's decoded backlog into dense windows of unique
+        rows times 4 or 1 columns (``_WINDOW_COLUMNS``): stable sort
+        by row, cut the rows every ``window_min_rows`` into chunks, then
+        carve each chunk in rounds. A round takes the chunk's rows that
+        still have ops pending and gives them the widest column count
+        every one of them can fill; what a row has left waits for the
+        chunk's next round. Only a full chunk is widened: a window of
+        fewer rows stays one column wide, so the heights that vary are
+        those of one-column windows and each wide shape is one program.
+        And a window whose merge the engine will fuse its zamboni into
+        (every ``compact_every``-th it is handed) stays one column wide
+        too, so the fused merge is a program at one width only.
+        Column j of a row is its j-th pending op (per-doc FIFO is the
+        sort's stability, across columns and across rounds), no slot is
+        padded, and a pass in which no row is pending twice gives
+        one-column windows of its sorted rows. Each window compacts its
+        own text/props tables from the pass interner."""
         parts = self._parts
         if not parts:
             return []
@@ -966,8 +996,8 @@ class ColumnarAlfred:
             srow = row[order]
             # partitioned engine: global row = partition * dpp + local, so
             # after the row sort partition runs are CONTIGUOUS — carve at
-            # partition boundaries FIRST, then occurrence levels per
-            # partition segment (each window then belongs to exactly one
+            # partition boundaries FIRST, then chunks per partition
+            # segment (each window then belongs to exactly one
             # partition's sequencer/executor)
             if self.n_partitions > 1:
                 pids = srow // self._dpp
@@ -976,6 +1006,8 @@ class ColumnarAlfred:
                         for seg in np.split(np.arange(n), pcuts)]
             else:
                 segs = [(0, np.arange(n))]
+            # a chunk is an (R, O) matrix of op indices into the pass's
+            # planes: row i's columns are its next O ops in arrival order
             chunks: List[Tuple[int, np.ndarray]] = []
             for part, seg in segs:
                 so = srow[seg]
@@ -983,21 +1015,47 @@ class ColumnarAlfred:
                 new = np.empty(m, bool)
                 new[0] = True
                 new[1:] = so[1:] != so[:-1]
-                starts = np.flatnonzero(new)
-                occ = np.arange(m) - np.repeat(starts,
-                                               np.diff(np.append(starts, m)))
-                lvl_order = np.argsort(occ, kind="stable")
-                cuts = np.flatnonzero(np.diff(occ[lvl_order])) + 1
+                first = np.flatnonzero(new)     # a row's next pending op
+                left = np.diff(np.append(first, m))     # ops it has left
                 oseg = order[seg]
-                for lvl in np.split(oseg[lvl_order], cuts):
-                    for s in range(0, lvl.size, self.window_min_rows):
-                        chunks.append((part, lvl[s:s + self.window_min_rows]))
+                full = self.window_min_rows
+                # the engine counts the windows it is handed and fuses
+                # its zamboni into every ``compact_every``-th one's merge
+                # (``serving.py:_ingest_sequence``); this door hands it
+                # every window it gets, so the count is the door's own
+                every = getattr(self._engine_of(part), "compact_every", 0)
+                nth = self._windows_to[part]
+                for s in range(0, first.size, full):
+                    at, todo = first[s:s + full], left[s:s + full]
+                    while at.size:
+                        cols = 1
+                        fused = every > 1 and (nth + 1) % every == 0
+                        if at.size == full and not fused:
+                            least = int(todo.min())
+                            cols = next(c for c in _WINDOW_COLUMNS
+                                        if c <= least)
+                        w = oseg[at[:, None] + np.arange(cols)]
+                        if cols > 1:
+                            # an op and its resubmit never ride one
+                            # window: the second is re-acked from the
+                            # dedup ledger, which learns of the first
+                            # when its window's acks are fanned
+                            key = (f["client"][w].astype(np.int64) << 32) \
+                                | f["cseq"][w]
+                            if any((key[:, i] == key[:, j]).any()
+                                   for i in range(cols)
+                                   for j in range(i + 1, cols)):
+                                cols, w = 1, w[:, :1]
+                        chunks.append((part, w))
+                        nth += 1
+                        more = todo > cols
+                        at, todo = at[more] + cols, todo[more] - cols
             texts_g, props_g = self._texts, self._props
             windows = []
             for part, w in chunks:
                 kind_w = f["kind"][w]
                 gidx_w = f["gidx"][w]
-                tidx_w = np.zeros(w.size, np.int32)
+                tidx_w = np.zeros(w.shape, np.int32)
                 ins = kind_w == _K_INS
                 texts_w: List[str] = []
                 if ins.any():
@@ -1011,14 +1069,10 @@ class ColumnarAlfred:
                     tidx_w[ann] = inv.astype(np.int32)
                     props_w = [props_g[i] for i in u.tolist()]
                 windows.append({
-                    "rows": row[w], "kind": kind_w.reshape(-1, 1),
-                    "a0": f["a0"][w].reshape(-1, 1),
-                    "a1": f["a1"][w].reshape(-1, 1),
-                    "tidx": tidx_w.reshape(-1, 1),
-                    "cseq": f["cseq"][w].reshape(-1, 1),
-                    "ref": f["ref"][w].reshape(-1, 1),
-                    "client": f["client"][w].reshape(-1, 1),
-                    "cseq_flat": f["cseq"][w], "sessi": sessi[w],
+                    "rows": row[w[:, 0]], "kind": kind_w,
+                    "a0": f["a0"][w], "a1": f["a1"][w], "tidx": tidx_w,
+                    "cseq": f["cseq"][w], "ref": f["ref"][w],
+                    "client": f["client"][w], "sessi": sessi[w],
                     "texts": texts_w or [""], "props": props_w or None,
                     "tab": tab, "tl": self._pass_tl, "part": part,
                     "rec": tracing.new_record(pid=self._pass_tl["pid"],
@@ -1031,8 +1085,8 @@ class ColumnarAlfred:
                 # interleave submission round-robin across partitions: the
                 # per-partition depth wait then parks on the SATURATED
                 # partition only after its peers' windows are already in
-                # flight (within a partition, level order — per-doc FIFO —
-                # is preserved: stable grouping keeps relative order)
+                # flight (within a partition, the carve's order — per-doc
+                # FIFO — is preserved: stable grouping keeps relative order)
                 byp: Dict[int, List[dict]] = {}
                 for w in windows:
                     byp.setdefault(w["part"], []).append(w)
@@ -1050,7 +1104,8 @@ class ColumnarAlfred:
         return windows
 
     def _submit_window(self, w: dict) -> None:
-        n = int(w["rows"].size)
+        n = int(w["kind"].size)             # ops: rows times columns
+        cols = w["kind"].shape[1]
         part = w.get("part", 0)
         # the engine stages speak partition-LOCAL rows; the wire (acks,
         # shed fences, hotdocs) keeps the door's global rows
@@ -1072,6 +1127,7 @@ class ColumnarAlfred:
                     w["kind"], w["a0"], w["a1"], texts=w["texts"],
                     tidx=w["tidx"], props=w["props"], marks=rec)
                 self._waves_inflight[part] += 1
+                self._rows_inflight[part][rec["wid"]] = w["rows"]
                 loop = getattr(self, "_loop", None) or \
                     asyncio.get_running_loop()
                 ticket.add_done_callback(
@@ -1084,14 +1140,20 @@ class ColumnarAlfred:
             self._fan_acks(w, np.asarray(res["seq"]).reshape(-1),
                            marks=res.get("marks"))
         self.windows_flushed += 1
+        self._windows_to[part] += 1
         self.ops_ingested += n
         self._pending_ops -= n
         REGISTRY.inc("columnar_windows_flushed")
+        REGISTRY.inc("columnar_window_columns", cols)
+        if cols > 1:
+            REGISTRY.inc("columnar_windows_wide")
         REGISTRY.inc("columnar_ops_ingested", n)
 
     def _fan_acks(self, w: dict, seqs: np.ndarray,
                   marks: Optional[dict] = None) -> None:
-        """Fan a window's acks back, one frame per participating session.
+        """Fan a window's acks back: for each participating session one
+        frame a column, in column order, so a frame never names a row
+        twice and a row's acks arrive in its sequence order.
 
         Runs AFTER the durable append (serial path: ingest_planes
         returned; pipelined path: the ticket resolved past the log
@@ -1103,31 +1165,37 @@ class ColumnarAlfred:
         attribute each ack to a doc."""
         rec = w["rec"]
         with tracing.stage(rec, "door.fan_acks") as sp:
-            rows, cseq = w["rows"], w["cseq_flat"]
+            rows, cseq = w["rows"], w["cseq"]
             sessi, tab = w["sessi"], w["tab"]
-            self.engine.note_acked_planes(rows, w["client"].reshape(-1),
-                                          cseq, seqs)
+            n_cols = cseq.shape[1]
+            seqs = seqs.reshape(cseq.shape)
+            # per op, row-major as the planes are: the row of each
+            op_rows = np.repeat(rows, n_cols)
+            self.engine.note_acked_planes(op_rows, w["client"].reshape(-1),
+                                          cseq.reshape(-1),
+                                          seqs.reshape(-1))
             if self.digest_tap is not None:
                 # fold the sequenced window into the replicated shadow and
                 # assert cross-replica digest parity (ISSUE 18): the tap's
                 # on_window runs the shard_map step and records agreement
                 self.digest_tap.on_window(
-                    rows, w["kind"], w["a0"], w["a1"], seqs,
+                    op_rows, w["kind"], w["a0"], w["a1"], seqs,
                     w["client"], w["ref"])
             if self.admission is not None:
                 # service-rate feedback for the deadline estimator: these
                 # ops just finished sequencing + durable append
-                self.admission.note_served(int(rows.size))
-            order = np.argsort(sessi, kind="stable")
-            ss = sessi[order]
-            cuts = np.flatnonzero(np.diff(ss)) + 1
-            for g in np.split(order, cuts):
-                pairs = np.empty((g.size, 2), np.int64)
-                pairs[:, 0] = cseq[g]
-                pairs[:, 1] = seqs[g]
-                tab[int(sessi[g[0]])]._push_json(
-                    {"t": "acks", "acks": pairs.tolist(),
-                     "rows": rows[g].tolist()})
+                self.admission.note_served(int(cseq.size))
+            for j in range(n_cols):
+                sj = sessi[:, j]
+                order = np.argsort(sj, kind="stable")
+                cuts = np.flatnonzero(np.diff(sj[order])) + 1
+                for g in np.split(order, cuts):
+                    pairs = np.empty((g.size, 2), np.int64)
+                    pairs[:, 0] = cseq[g, j]
+                    pairs[:, 1] = seqs[g, j]
+                    tab[int(sj[g[0]])]._push_json(
+                        {"t": "acks", "acks": pairs.tolist(),
+                         "rows": rows[g].tolist()})
         # the ack fan completes the window's record: file it (a slow
         # window is kept whole, and is then the e2e histogram's exemplar)
         # and attribute rx → ack to consecutive stage segments
@@ -1160,6 +1228,7 @@ class ColumnarAlfred:
             tracing.wait(rec, "door.ack_bounce", rec["log1"],
                          time.perf_counter(), mark="ack0")
         self._waves_inflight[w.get("part", 0)] -= 1
+        self._rows_inflight[w.get("part", 0)].pop(rec["wid"], None)
         if self._capacity is not None:
             self._capacity.set()
         err = ticket.error()
@@ -1176,16 +1245,30 @@ class ColumnarAlfred:
         self._fan_acks(w, np.asarray(res["seq"]).reshape(-1),
                        marks=res.get("marks"))
 
-    async def _wait_capacity(self, part: int = 0) -> None:
+    async def _wait_capacity(self, w: dict) -> None:
         """Depth backpressure, per partition: park the flusher (event
         loop stays free to accumulate more socket bytes) until one of
         THIS partition's in-flight waves logs — a saturated partition
         never holds back windows already interleaved behind it for its
-        peers (they were submitted first by the round-robin order)."""
+        peers (they were submitted first by the round-robin order).
+
+        A wide window also waits for every window in flight that shares
+        a row with it. One column at a time, a row's j-th pending op was
+        sequenced j windows after its first, by when the ack fan had
+        told the dedup ledger of every earlier window: a resubmit riding
+        in a later column keeps finding its original there."""
         if not self._executors:
             return
-        while self._waves_inflight[part] >= self._executors[part].depth \
-                and self._pipeline_error is None:
+        part = w.get("part", 0)
+        wide = w["kind"].shape[1] > 1
+
+        def shares_a_row() -> bool:
+            return wide and any(
+                np.isin(rows, w["rows"], assume_unique=True).any()
+                for rows in self._rows_inflight[part].values())
+
+        while (self._waves_inflight[part] >= self._executors[part].depth
+               or shares_a_row()) and self._pipeline_error is None:
             self._capacity.clear()
             await self._capacity.wait()
 
@@ -1206,7 +1289,7 @@ class ColumnarAlfred:
                 self._drain()
                 for w in self._build_windows():
                     t = time.perf_counter()
-                    await self._wait_capacity(w.get("part", 0))
+                    await self._wait_capacity(w)
                     tracing.wait(w["rec"], "door.capacity_wait", t,
                                  time.perf_counter())
                     if self._pipeline_error is not None:

@@ -437,7 +437,10 @@ class TestScrapeUnderIngestStorm:
         try:
             for t in threads:
                 t.start()
-            n_clients, docs_per, waves = 3, 4, 10
+            # a door that carves wide needs a quarter of the windows
+            # for the same waves: more waves, so the storm still outlasts
+            # a few scrapes
+            n_clients, docs_per, waves = 3, 4, 30
             clients = []
             for c in range(n_clients):
                 cl = ColumnarClient("127.0.0.1", srv.port)
@@ -458,6 +461,11 @@ class TestScrapeUnderIngestStorm:
                     assert resp["t"] == "acks", resp
                     acked += len(resp["acks"])
                 cl.close()
+            # how long the storm lasted is the machine's business: the
+            # scrapers get their ten samples, of which the storm's are some
+            t_end = time.monotonic() + 10
+            while len(lat) < 10 and time.monotonic() < t_end:
+                time.sleep(0.01)
             stop.set()
             for t in threads:
                 t.join(timeout=10)

@@ -219,6 +219,9 @@ def test_a_slow_window_is_kept_whole(door, monkeypatch):
 
     door.window()
     tracing.RECENT.clear()
+    # the ring is the process's: a long window of an earlier test's door
+    # may have left a trace under a ``wid`` this door will use
+    tracing.TRACER.clear()
     t0 = _table()
     monkeypatch.setattr(eng, "_append_columnar", slow_once)
     door.window()
