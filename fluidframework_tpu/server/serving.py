@@ -950,6 +950,12 @@ class ServingEngineBase:
         for coll, c in zip(self.shard_metrics, per):
             if c:
                 coll.inc("ops_applied", float(c))
+        if counts is not None:
+            # a columnar window: the shards it has ops in and the ops of
+            # its fullest one; over the windows flushed and over their ops
+            # these give a window's spread and its skew over the mesh
+            REGISTRY.inc("mesh_window_shards", int(np.count_nonzero(per)))
+            REGISTRY.inc("mesh_window_ops_fullest_shard", float(per.max()))
 
     def flush(self) -> int:
         """Template: time the subclass's device apply, record batch-size

@@ -43,6 +43,25 @@ def _bound_compile_arena():
     jax.clear_caches()
 
 
+# ``tests/perfbench/`` belongs to the benchmark, and a PR that adds a cell
+# may add files there and edit none. ``test_string_deli_62k.py`` fetches
+# its cell as ``BENCH["workloads"][-1]``; the manifest's contract has every
+# new cell appended last, so the assertion fails from the first cell added
+# after it (PR 32's ``richtext-marks-62k-mesh4.typing``). What it holds is
+# held by name in ``test_richtext_marks_62k_mesh4.py``. Strict: the marker
+# turns red, and goes, when a ``benchmark`` PR looks the cell up by name.
+_HOLDS_ITS_CELL_TO_LAST_PLACE = (
+    "test_string_deli_62k.py::test_manifest_states_the_file")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid.endswith(_HOLDS_ITS_CELL_TO_LAST_PLACE):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="asserts string-deli-62k.replay is the last entry "
+                       "of workloads; new cells are appended after it"))
+
 # Hard-exit machinery: full-suite runs have died in XLA's C++ teardown
 # (atexit destructors) AFTER every test passed, eating the terminal
 # summary and the exit status — CI could not prove the green run. The
