@@ -2,12 +2,28 @@
 
 The XLA path (``apply_string_batch``) scans the op axis with the state planes
 round-tripping through HBM on every op: one 64-op batch moves the whole
-(D, S) state 128 times. This kernel tiles the doc axis across the grid,
-loads one tile's planes into VMEM ONCE, applies the entire op batch with a
-``fori_loop`` inside the kernel, and writes the planes back ONCE — turning
-O(ops) HBM traffic into O(1) per batch. The per-op math is literally the
-same ``_insert_one`` / ``_range_one`` helpers as the XLA path (vmapped over
-the tile's docs), so semantics are shared by construction, not re-derived.
+(D, S) state 128 times. This kernel tiles the doc axis, loads one tile's
+planes into VMEM ONCE, applies the entire op batch with a ``fori_loop``
+inside the kernel, and writes the planes back ONCE — turning O(ops) HBM
+traffic into O(1) per batch. The per-op math is literally the same
+``_insert_one`` / ``_range_one`` helpers as the XLA path (vmapped over the
+tile's docs), so semantics are shared by construction, not re-derived.
+
+Which tiles: the grid has one step a tile, but the plain merge visits only
+the tiles that hold an op. The pass is bound by arithmetic (every column is
+applied to every row of a visited tile, op or no op), so its cost is tiles ×
+columns, and a door's window touches a few hundred neighbouring rows of a
+store of tens of thousands. ``_touched_tiles`` derives the list on the
+device from the ``kind`` plane the call already gets (no host work, nothing
+new on the wire, shard-local under ``shard_map``); it is scalar-prefetched,
+the block index maps read ``tiles[i]``, and the steps past the list's end
+skip the body and name the block of the step before them, so the pipeline
+moves nothing for them. The state planes are aliased in place: a tile no
+step visits is neither read nor written. The grid's length stays static
+(``D // tile``), so the program's key is what it was. A batch with an op in
+every tile visits every tile, as before. The variants with the zamboni
+fused in walk every tile (their list is the identity): compaction rewrites
+tiles that have no op.
 
 Two specializations: no-props (stores that have never seen an annotate —
 ``TensorStringStore._has_props`` False, the mode the north-star benchmark
@@ -99,10 +115,37 @@ def _excl_cumsum_last(x):
     return jnp.where(_iota2(x.shape) == 0, 0, jnp.roll(c, 1, axis=-1))
 
 
-def _kernel(*refs, compact: bool, n_props: int):
-    """n_props=0: the no-props specialization (property planes untouched
+def _touched_tiles(kind, tile: int):
+    """(tiles, n_active) of a (D, O) ``kind`` plane: the indices of the
+    tiles holding a non-NOOP op, ascending, padded to ``D // tile`` with the
+    last of them (a padded step then names the block its predecessor named),
+    and their number, at least 1: a plane without a valid op — three shards
+    in four of a mesh whose window lies in one — runs tile 0, whose NOOPs
+    leave it as it was (``pick``'s last branch), so that step 0's output
+    block is written back from VMEM the body filled."""
+    n_tiles = kind.shape[0] // tile
+    has = (kind != int(OpKind.NOOP)).reshape(n_tiles, -1).any(axis=1)
+    n_active = jnp.maximum(jnp.sum(has, dtype=jnp.int32), 1)
+    found, = jnp.nonzero(has, size=n_tiles, fill_value=0)
+    found = found.astype(jnp.int32)
+    step = jnp.arange(n_tiles, dtype=jnp.int32)
+    return jnp.where(step < n_active, found, found[n_active - 1]), \
+        n_active.reshape(1)
+
+
+def _kernel(tiles_ref, n_ref, *refs, compact: bool, n_props: int):
+    """The body of one grid step, run while the step lies inside the tile
+    list (``n_ref``; the index maps read ``tiles_ref``).
+
+    n_props=0: the no-props specialization (property planes untouched
     host-side). n_props=K: the K property planes ride along in VMEM as K
     extra (T, S) refs, moved by the same split/shift/compact passes."""
+    del tiles_ref
+    pl.when(pl.program_id(0) < n_ref[0])(
+        functools.partial(_merge_tile, refs, compact, n_props))
+
+
+def _merge_tile(refs, compact: bool, n_props: int):
     if compact:
         ms_ref, refs = refs[0], refs[1:]
     np_ = _NP + n_props
@@ -172,6 +215,10 @@ def apply_string_batch_pallas(state: StringState, kind, a0, a1, a2, seq,
     the K property planes into VMEM alongside the rest, so annotate-bearing
     stores stay on the fused path too.
 
+    Without ``min_seq`` only the tiles that hold a non-NOOP op are visited
+    (module docstring): every other row comes out bit for bit as it went
+    in, in every slot. A batch without any valid op runs one tile of NOOPs.
+
     D must divide by ``tile``; S should be a multiple of 128 (lane width).
     ``interpret=True`` runs the Pallas interpreter (CPU tests)."""
     D, S = state.seq.shape
@@ -181,16 +228,22 @@ def apply_string_batch_pallas(state: StringState, kind, a0, a1, a2, seq,
     K = state.prop_val.shape[2] if with_props else 0
     np_ = _NP + K
 
-    op_spec = pl.BlockSpec((tile, O), lambda i: (i, 0),
-                           memory_space=pltpu.VMEM)
-    plane_spec = pl.BlockSpec((tile, S), lambda i: (i, 0),
-                              memory_space=pltpu.VMEM)
-    col_spec = pl.BlockSpec((tile, 1), lambda i: (i, 0),
+    n_tiles = D // tile
+    if compact:
+        tiles = jnp.arange(n_tiles, dtype=jnp.int32)
+        n_active = jnp.full((1,), n_tiles, jnp.int32)
+    else:
+        tiles, n_active = _touched_tiles(kind, tile)
+
+    def block(width):
+        return pl.BlockSpec((tile, width), lambda i, tiles, n: (tiles[i], 0),
                             memory_space=pltpu.VMEM)
 
+    op_spec, plane_spec, col_spec = block(O), block(S), block(1)
     n_lead = 1 if compact else 0
-    grid_spec = pl.GridSpec(
-        grid=(D // tile,),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_tiles,),
         in_specs=[col_spec] * n_lead + [op_spec] * _OPS
         + [plane_spec] * np_ + [col_spec] * 2,
         out_specs=tuple([plane_spec] * np_ + [col_spec] * 2),
@@ -199,8 +252,9 @@ def apply_string_batch_pallas(state: StringState, kind, a0, a1, a2, seq,
         [jax.ShapeDtypeStruct((D, S), jnp.int32)] * np_
         + [jax.ShapeDtypeStruct((D, 1), jnp.int32)] * 2)
 
-    # donate the state planes into the outputs (in-place update in HBM)
-    aliases = {n_lead + _OPS + i: i for i in range(np_ + 2)}
+    # donate the state planes into the outputs (in-place update in HBM);
+    # operand numbers count the two prefetched scalars
+    aliases = {2 + n_lead + _OPS + i: i for i in range(np_ + 2)}
     lead = (jnp.asarray(min_seq, jnp.int32)[:, None],) if compact else ()
     prop_in = tuple(state.prop_val[:, :, i] for i in range(K))
     # a stable name by variant, so that a device trace tells the plain
@@ -211,7 +265,7 @@ def apply_string_batch_pallas(state: StringState, kind, a0, a1, a2, seq,
         functools.partial(_kernel, compact=compact, n_props=K),
         grid_spec=grid_spec, out_shape=out_shape,
         input_output_aliases=aliases, interpret=interpret, name=name,
-    )(*lead, kind, a0, a1, a2, seq, client, ref_seq,
+    )(tiles, n_active, *lead, kind, a0, a1, a2, seq, client, ref_seq,
       *(getattr(state, k) for k in _PLANES), *prop_in,
       state.count[:, None], state.overflow[:, None])
 

@@ -1054,6 +1054,10 @@ class TensorStringStore(StringOpInterner):
             np.maximum(lag, 1, out=lag)
             ref_wide = bool((lag > 65535).any())
             use_pallas, tile, interpret = self._pallas_choice()
+            # the tiles the plain merge walks (pallas_string_kernel: those
+            # holding one of the window's rows), from the rows held here
+            tiles_total = self.n_docs // tile
+            tiles_touched = np.unique(rows // tile).size
             scatter_rows = not (R == self.n_docs
                                 and np.array_equal(rows, np.arange(R)))
             fuse = min_seq is not None and not self._iv_docs
@@ -1230,6 +1234,10 @@ class TensorStringStore(StringOpInterner):
                             self.state, planes, ms_dev, use_pallas=use_pallas,
                             tile=tile, interpret=interpret,
                             with_props=self._has_props, fuse_compact=fuse_seg)
+                if use_pallas:  # a fused zamboni walks every tile
+                    REGISTRY.inc("merge_tiles_visited",
+                                 tiles_total if fuse_seg else tiles_touched)
+                    REGISTRY.inc("merge_tiles_total", tiles_total)
                 pack_ms += pack.ms
                 dispatch_ms += sp_up.ms + sp_unp.ms + sp_mrg.ms
                 # drop the segment's device buffers here, inside the

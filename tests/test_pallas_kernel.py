@@ -2,6 +2,10 @@
 the CPU mesh; the compiled kernel is checked against the scan on the chip
 by ``chip_smoke.py``)."""
 
+import dataclasses
+import functools
+
+import jax
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -274,3 +278,80 @@ def test_conflict_storm_pallas_matches_xla(seed):
         assert np.array_equal(np.asarray(string_state_digest(sp)),
                               np.asarray(string_state_digest(sx)))
     assert not np.asarray(sp.overflow).any()
+
+
+# -- the touched-tile walk (PR 33): the plain merge visits only the tiles
+# -- that hold an op; every other row has to come out as it went in
+
+WALK_TILE, WALK_DOCS, WALK_CAP = 8, 64, 128     # a store of 8 tiles
+_WALK_ROWS = {
+    "one_tile": np.arange(16, 24),
+    "off_the_grid_straddling": np.arange(12, 36),
+    "two_tiles_not_neighbours": np.r_[8:16, 50:54],
+    "every_tile": np.arange(WALK_DOCS),
+    "last_tile_only": np.arange(56, 64),
+    "no_valid_op": np.arange(0),
+}
+_scan = jax.jit(apply_string_batch, static_argnames=("with_props",))
+_walk = jax.jit(functools.partial(apply_string_batch_pallas, tile=WALK_TILE,
+                                  interpret=True),
+                static_argnames=("with_props",))
+
+
+def walk_case(rows, with_props):
+    """(state, ops, touched): a prefilled store of ``WALK_DOCS`` rows and a
+    four-column window with ops on ``rows`` alone. The store's slots at or
+    beyond ``count`` (semantically ignored) hold a pattern, so that a
+    rewritten row shows."""
+    from fluidframework_tpu.ops.schema import OpKind
+
+    def storm(n_ops, seed):
+        if with_props:
+            return dict(zip(ORDER, _annotate_ops(seed, WALK_DOCS, n_ops)))
+        planes, _ = typing_storm(WALK_DOCS, n_ops, seed=seed)
+        return {k: jnp.asarray(planes[k]) for k in ORDER}
+
+    state = _scan(StringState.create(WALK_DOCS, WALK_CAP),
+                  *storm(12, 3).values(), with_props=with_props)
+    slot = np.arange(WALK_CAP)[None, :]
+    dead = slot >= np.asarray(state.count)[:, None]
+    fill = {}
+    for i, k in enumerate(CHECK[:-2] + ("prop_val",)):
+        junk = (slot * 7 + i + np.arange(WALK_DOCS)[:, None]) % 5 + 1
+        was = np.asarray(getattr(state, k))
+        if was.ndim == 3:
+            junk, hole = junk[..., None], dead[..., None]
+        else:
+            hole = dead
+        fill[k] = jnp.asarray(np.where(hole, junk, was).astype(np.int32))
+    state = dataclasses.replace(state, **fill)
+
+    touched = np.zeros(WALK_DOCS, bool)
+    touched[rows] = True
+    ops = storm(4, 11)
+    ops["seq"] = ops["seq"] + 12 * WALK_DOCS    # after the fill's
+    ops["ref_seq"] = ops["ref_seq"] + 12 * WALK_DOCS
+    ops["kind"] = jnp.where(touched[:, None], ops["kind"], int(OpKind.NOOP))
+    return state, tuple(ops.values()), touched
+
+
+def assert_walked(before, after, expected, touched):
+    """``after`` equals ``expected`` in every plane, and the rows without
+    an op equal ``before`` bit for bit, beyond ``count`` too."""
+    _assert_equal_with_props(expected, after)
+    for k in CHECK + ("prop_val",):
+        was, got = np.asarray(getattr(before, k)), np.asarray(
+            getattr(after, k))
+        assert np.array_equal(was[~touched], got[~touched]), k
+    if touched.any():   # the window did something
+        assert not np.array_equal(np.asarray(before.seq),
+                                  np.asarray(after.seq))
+
+
+@pytest.mark.parametrize("with_props", (False, True),
+                         ids=("no_props", "props"))
+@pytest.mark.parametrize("where", list(_WALK_ROWS))
+def test_touched_tile_walk_matches_xla_and_leaves_the_rest(where, with_props):
+    state, ops, touched = walk_case(_WALK_ROWS[where], with_props)
+    assert_walked(state, _walk(state, *ops, with_props=with_props),
+                  _scan(state, *ops, with_props=with_props), touched)

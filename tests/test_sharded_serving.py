@@ -358,3 +358,71 @@ def test_mesh_programs_carry_their_names(program):
         args = (state, ms)
     assert fn.__name__ == name
     assert f"@jit_{name} " in fn.lower(*args).as_text()
+
+
+# ------------------------------------------- the touched-tile walk on a mesh
+# (ISSUE 33) Each shard derives its own tile list from its own op planes: a
+# shard the window has no row in runs one tile of NOOPs and keeps its state.
+
+_WALK_SHARD_ROWS = {   # of 64 rows: 4 shards of 16, tiles of 8
+    "one_shard_of_four": np.arange(18, 30),
+    "all_four_shards": np.r_[4:8, 20:24, 36:41, 52:57],
+}
+
+
+@pytest.mark.parametrize("with_props", (False, True),
+                         ids=("no_props", "props"))
+@pytest.mark.parametrize("where", list(_WALK_SHARD_ROWS))
+def test_sharded_merge_walks_each_shards_own_tiles(where, with_props):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+    from fluidframework_tpu.parallel import sharded
+    from tests.test_pallas_kernel import (
+        WALK_TILE, _scan, assert_walked, walk_case,
+    )
+    before, ops, touched = walk_case(_WALK_SHARD_ROWS[where], with_props)
+    mesh = make_doc_mesh(CHIPS)
+    merge = sharded.sharded_merge(mesh, True, WALK_TILE, True, with_props,
+                                  False)
+    by_row = NamedSharding(mesh, PartitionSpec("docs", None))
+    # the merge donates its state: give it a copy of its own
+    on_mesh = merge(
+        sharded.shard_store_state(jax.tree.map(jnp.copy, before), mesh),
+        tuple(jax.device_put(p, by_row) for p in ops))
+    assert_walked(before, on_mesh,
+                  _scan(before, *ops, with_props=with_props), touched)
+    for x in jax.tree.leaves(on_mesh):
+        assert len(x.sharding.device_set) == CHIPS
+
+
+@pytest.mark.parametrize("placement", ("one_chip", "mesh"))
+def test_apply_planes_counts_the_tiles_its_merge_walks(placement):
+    """A 512-row window 16 off the grid at tile 64 lies in 9 tiles of the
+    store's 32; the window with the zamboni fused in walks all of them; a
+    full-store batch too; the XLA scan has no tiles and counts nothing."""
+    from fluidframework_tpu.ops.string_store import TensorStringStore
+    from fluidframework_tpu.utils.telemetry import REGISTRY
+    mesh = make_doc_mesh(CHIPS) if placement == "mesh" else None
+    store = TensorStringStore(N_DOCS, 512, mesh=mesh)
+    store.pallas = "interpret"
+    assert store._pallas_choice()[:2] == (True, 64)
+
+    def counted():
+        return tuple(REGISTRY.counters.get(f"merge_tiles_{k}", 0)
+                     for k in ("visited", "total"))
+
+    rows = np.arange(528, 1040, dtype=np.int32)
+    plain, fused = _windows(rows, 1, "B", True, n=2)
+    for win, walked in ((plain, 9), (fused, 32)):
+        before = counted()
+        store.apply_planes(**win)
+        after = counted()
+        assert (after[0] - before[0], after[1] - before[1]) == (walked, 32)
+    before = counted()
+    store.apply_planes(**next(_windows(_window_rows(N_DOCS, "full"), 1, "B",
+                                       True, n=1)))
+    assert counted() == (before[0] + 32, before[1] + 32)
+    store.pallas = "off"
+    store.apply_planes(**plain)
+    assert counted() == (before[0] + 32, before[1] + 32)
