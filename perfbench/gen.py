@@ -26,7 +26,7 @@ from .refdoc import RefDoc
 from .traffic import (Layout, OpMaker, Vocabulary, cut_probability,
                       programs)
 
-RING = 16
+RING = 16        # the least ops of one document that may be in flight
 now = time.monotonic
 
 
@@ -51,6 +51,8 @@ class Generator:
         self.texts = self.vocab.texts
         self.props = config["wire"]["props"] or None
         self.W = dep["door"]["window_min_rows"]
+        # a closed loop has a document's op in every frame in flight
+        self.ring = max(RING, traffic.get("frames_in_flight", 0))
         self.conns = [Conn(c) for c in range(self.lay.C)]
         self.failures = collections.Counter()
         self.notes = []
@@ -111,7 +113,7 @@ class Generator:
                            self.vocab)
             c.ref = np.zeros(c.n, np.int64)
             c.last_seq = np.ones(c.n, np.int64)       # the join took seq 1
-            c.slot = np.full((c.n, RING), -1, np.int64)
+            c.slot = np.full((c.n, self.ring), -1, np.int64)
         self.name_row = name_row
 
     def _recv_json(self, c: Conn, timeout: float) -> dict:
@@ -170,18 +172,31 @@ class Generator:
         return (row, kind, a0, a1, tidx, cseq, doc.seq)
 
     def send_frame(self, c: Conn, li: np.ndarray, shared=(), fill=False,
-                   due=None, table=0) -> int:
+                   due=None, table=0, frames=1) -> None:
         """One frame: an op for each of the connection's own documents
         ``li`` and for each multi-writer row in ``shared``. ``table``
         (set-up only) makes the frame's inserts use as many distinct
-        characters as fill a payload table of that size."""
+        characters as fill a payload table of that size; ``frames``
+        (set-up only) writes that many such frames on the same documents
+        in one send, so that one drain pass holds them all."""
+        data, skip = [], 0
+        for _ in range(frames):
+            frame, skip = self._frame(c, li, shared, fill, due, table, skip)
+            data.append(frame)
+        c.sock.sendall(b"".join(data))
+        if due is not None:
+            self.frame_late.append((due, now() - due))
+
+    def _frame(self, c: Conn, li, shared, fill, due, table, skip):
+        """Draw, book and encode one frame; returns its bytes and the
+        inserts the send holds so far (``_fill_table``)."""
         rows = c.rows[li]
         # a table above the least size, 8, needs more than half as many
         # distinct entries: a small frame's draw may hold fewer inserts
         solo = c.mk.make(li, rows, fill,
                          inserts=table // 2 + 1 if table > 8 else 0)
         if table:
-            self._fill_table(solo, table)
+            skip = self._fill_table(solo, table, skip)
         solo["ref"] = c.ref[li]
         sh = np.asarray([self._shared_op(c, r, fill) for r in shared],
                         wire.OP_DTYPE) if len(shared) \
@@ -195,7 +210,7 @@ class Generator:
         self.op_seq[base:base + n] = 0
         self.op_fid[base:base + n] = fid
         self.op_conn[base:base + n] = c.idx
-        c.slot[li, solo["cseq"] % RING] = g[:len(solo)]
+        c.slot[li, solo["cseq"] % self.ring] = g[:len(solo)]
         for j, o in enumerate(sh):
             self.sh_sent[c.idx, int(o["row"]), int(o["cseq"])] = \
                 base + len(solo) + j
@@ -204,24 +219,22 @@ class Generator:
         self.frame_conn.append(c.idx)
         c.inflight += 1
         prefix, recs = wire.frame_tables(ops, self.texts, self.props)
-        data = wire.encode_ops(prefix, recs, self.rich)
-        t_send = now()
-        self.op_due[base:base + n] = t_send if due is None else due
-        c.sock.sendall(data)
-        if due is not None:
-            self.frame_late.append((due, now() - due))
-        return fid
+        self.op_due[base:base + n] = now() if due is None else due
+        return wire.encode_ops(prefix, recs, self.rich), skip
 
-    def _fill_table(self, ops: np.ndarray, table: int) -> None:
+    def _fill_table(self, ops: np.ndarray, table: int, skip: int = 0) -> int:
         """Swap the characters of a frame's inserts (every one is a single
         character, so no length changes) for the first ``n`` of the
         alphabet in turn, ``n`` chosen so that the distinct characters and
-        marks of the frame pad to a payload table of ``table`` entries."""
+        marks of the window pad to a payload table of ``table`` entries
+        whether it holds every mark or none. ``skip``: the inserts of the
+        window's earlier columns (frames on the same rows), which this
+        frame's carry on from; returns it with this frame's added."""
         ins = np.flatnonzero(ops["kind"] == wire.INS)
-        marks = len(np.unique(ops["tidx"][ops["kind"] == wire.ANN]))
-        n = min(table - marks, len(ins), self.vocab.fill)
+        n = min(table - len(self.vocab.props), self.vocab.fill)
         if n > 0:
-            ops["tidx"][ins] = np.arange(len(ins)) % n
+            ops["tidx"][ins] = (skip + np.arange(len(ins))) % n
+        return skip + len(ins)
 
     # --------------------------------------------------------------- acks
     def pump(self, timeout: float) -> None:
@@ -266,7 +279,7 @@ class Generator:
         mine = self.owner_of[rows] == c.idx
         g = np.full(len(rows), -1, np.int64)
         li = self.local[rows[mine]]
-        g[mine] = c.slot[li, cseq[mine] % RING]
+        g[mine] = c.slot[li, cseq[mine] % self.ring]
         for j in np.flatnonzero(~mine).tolist():
             g[j] = self.sh_sent.get((c.idx, int(rows[j]), int(cseq[j])), -1)
         known = g >= 0
@@ -328,27 +341,32 @@ class Generator:
 
     # ------------------------------------------------------------ phases
     def sweep(self) -> list:
-        """Dispatch every (height, payload-table size) the mix can meet,
-        each through a whole compaction cycle so that its fused-zamboni
-        program is met too: one frame at a time, so that each frame is
-        one window."""
+        """Dispatch every (height, columns, payload-table size) the mix
+        can meet, each through a whole compaction cycle so that its
+        fused-zamboni program is met too: one frame at a time, so that
+        each frame is one window. A wide window is one send of as many
+        frames on the same rows as it has columns (one drain pass then
+        holds that many ops for every row of a full chunk); it has no
+        fused form, so it is sent twice with a lone frame between: a send
+        that falls on the fused slot goes one column wide, and the lone
+        window moves the next off that slot."""
         progs = programs(self.lay, self.tr, self.W, self.vocab, self.rich)
         cyc = self.cfg["deployment"]["engine"]["compact_every"]
         turn = 0
         self.sweep_ms = {}
-        for h, tab in progs:
+        for h, cols, tab in progs:
             t = now()
-            for _ in range(cyc):
+            for frames in [1] * cyc if cols == 1 else [cols, 1, cols]:
                 c = self.conns[turn % (self.lay.C - 1)]
                 turn += 1
                 if h > c.n:
                     raise ValueError(f"height {h} exceeds a connection")
                 li = (np.arange(h) + c.q) % c.n
                 c.q = (c.q + h) % c.n
-                self.send_frame(c, li, table=tab)
+                self.send_frame(c, li, table=tab, frames=frames)
                 if not self.wait_frames(300.0):
                     raise TimeoutError("a set-up frame was never acked")
-            self.sweep_ms[f"{h}x{tab}"] = round((now() - t) * 1e3, 1)
+            self.sweep_ms[f"{h}x{cols}x{tab}"] = round((now() - t) * 1e3, 1)
         for c in self.conns:
             c.q = 0
         return progs

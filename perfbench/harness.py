@@ -253,9 +253,18 @@ def _serve(cell, config, traffic, gen, door, engine, log, devs, mesh, device,
     gen.wait("filled", 600)
     note(f"filled ({now() - t_start:.1f}s)")
     swept = gen.wait("swept", 1200)
-    note(f"programs dispatched on purpose (height x payload-table size), "
-         f"ms for a compaction cycle of each: {swept['ms']} "
+    note(f"programs dispatched on purpose (height x columns x payload-table "
+         f"size), ms for a compaction cycle of each: {swept['ms']} "
          f"({now() - t_start:.1f}s)")
+    # each is an unpack program plain and, one column wide, fused as well:
+    # what the sweep was sent for and the store never saw
+    met = set(map(_shape, engine.store.unpack_variants))
+    missed = [(h, cols, tab, fused) for h, cols, tab in swept["programs"]
+              for fused in ((False, True) if cols == 1 else (False,))
+              if (h, cols, tab, fused) not in met]
+    if missed:
+        note(f"WARNING: swept and not met (height, columns, table, fused): "
+             f"{missed}")
     gen.wait("streaming", 120)
     warm = traffic["warmup"]
     t_stream = now()
@@ -367,9 +376,19 @@ def _serve(cell, config, traffic, gen, door, engine, log, devs, mesh, device,
     note("end to end: " + ", ".join(
         f"{m['name']}={reduce.read_metric(m['name'], raw)}"
         for m in end_to_end))
+    result["programs"] = {"swept": len(swept["programs"]),
+                          "swept_not_met": missed,
+                          "new_in_window": list(map(_shape, new_variants))}
     result["compared"] = {k: {"value": v, "limit": lim}
                           for k, (v, lim) in compared.items()}
     return result
+
+
+def _shape(variant) -> tuple:
+    """(height, columns, payload-table size, fused) of one of the store's
+    ``unpack_variants`` (``string_store.apply_planes``'s static key, in
+    its order: R, O, ..., fuse_compact at 6, ..., tab_n last)."""
+    return (variant[0], variant[1], variant[-1], bool(variant[6]))
 
 
 def _instrument(door, engine, windows_seen, chips):

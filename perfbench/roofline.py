@@ -1,12 +1,15 @@
 """The least time the chip could take for a window's merge, from the bytes
 and operations the window NEEDS — whatever implements it.
 
-A window of R rows (one op each through the door) needs each touched
-document's row of every state plane read once and written once, plus its
-op buffer; a zamboni needs the rows touched since the last one, on the
-same footing. The program's kernel today passes over every row of the
-store for each window; that is its cost, not the window's need, and is
-why the share reads low.
+A window of R rows (one to four ops each through the door) needs each
+touched document's row of every state plane read once and written once,
+plus its op buffer; a zamboni needs the rows touched since the last one,
+on the same footing. The program's kernel since PR 33 visits only the
+tiles of 64 rows that hold one of the window's rows (a plain merge; the
+fused zamboni still passes over every row of the store), and inside a
+tile it is bound by arithmetic, a column at a time; a launch also pays a
+tile list and a grid step for every tile it skips. Those are its costs,
+not the window's need, and are why the share reads low (2-14%).
 
 On several chips the store's rows lie in contiguous blocks, one a chip
 (``parallel/sharded.py:shard_of_rows``), the chips work side by side, and

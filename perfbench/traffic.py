@@ -6,12 +6,14 @@ Layout (``n_docs`` documents, ``C`` connections, ``S`` multi-writer
 documents): every connection owns ``P = n_docs / C`` documents. The last
 connection's first ``S`` are the multi-writer ones; connection 0 co-writes
 all of them and connection 1 the odd half, so half have two writers and
-half three. A frame's rows are unique, so the door's windows — a drain
-pass sorted by row, split by per-row occurrence and cut every
-``window_min_rows`` — have heights from a closed set that
-:func:`heights` enumerates; set-up dispatches each on purpose.
+half three. A frame's rows are unique and a frame holds a fixed number
+of them, so the door's windows have shapes from a closed set: :func:`carve`
+is the door's rule (a copy), :func:`heights` and :func:`programs` derive
+from it every shape a pass of the mix can be carved into, and set-up
+dispatches each on purpose.
 """
 
+import itertools
 import json
 import os
 
@@ -20,6 +22,13 @@ import numpy as np
 from .wire import ANN, INS, OP_DTYPE, REM
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+#: the door's column counts, widest first (a copy of
+#: ``columnar_ingress._WINDOW_COLUMNS``; a test holds the two together)
+WINDOW_COLUMNS = (4, 1)
+#: the longest stall set-up prepares an open loop's door for: the records'
+#: episodes last 0.1-0.4 s, and what is sent meanwhile lands in one pass
+STALL_S = 0.5
+EVERY = range(1 << 62)      # ``carve``: a pass whose every window is fused
 
 
 def load_json(kind: str, name: str) -> dict:
@@ -123,20 +132,90 @@ class Layout:
         return self.shared_names() if c == self.owner and self.S \
             else self.co_written(c)
 
+    def runs(self):
+        """The rows in row order, as runs of (how many, the connections
+        that write them): the door gives out rows in join order, so
+        connection 0's documents come first, then the multi-writer ones
+        (it joins them next), then each later connection's own: every
+        later connection's rows begin ``S`` off the grid."""
+        shared = [frozenset(k for k in range(self.C)
+                            if d in self.writes_shared(k))
+                  for d in self.shared_names()]
+        out = []
+        for c in range(self.C):
+            out.append((self.P - (self.S if c == self.owner else 0),
+                        frozenset([c])))
+            if c == 0:
+                out += [(1, writers) for writers in shared]
+        return out
+
     def n_joins(self, name: str) -> int:
         if name not in self.shared_names():
             return 1
         return 2 + (self.shared_names().index(name) % 2)
 
 
+def carve(pending, window_rows: int, fused=()):
+    """The door's carving of one drain pass, as it is since PR 31
+    (``columnar_ingress._build_windows``), on the pass's rows in row order,
+    ``pending[i]`` ops waiting on the i-th: cut the rows every
+    ``window_rows`` into chunks, then carve each chunk in rounds. A round
+    takes the chunk's rows that still have ops pending; a full chunk goes
+    as wide as every one of its rows can fill (``WINDOW_COLUMNS``), unless
+    the engine fuses its zamboni into this window (``fused``: the windows,
+    counted from 0 in the pass, that it does), what is left goes one wide.
+    Returns the windows as (height, columns), in order."""
+    left = np.asarray(pending, np.int64)
+    out = []
+    for s in range(0, left.size, window_rows):
+        todo = left[s:s + window_rows]
+        while todo.size:
+            cols = 1
+            if todo.size == window_rows and len(out) not in fused:
+                cols = next(c for c in WINDOW_COLUMNS if c <= todo.min())
+            out.append((int(todo.size), cols))
+            todo = todo[todo > cols] - cols
+    return out
+
+
+def deepest(lay: Layout, traffic: dict) -> int:
+    """How many ops a pass can hold for one row that a single connection
+    writes. A closed loop: its frames in flight. An open loop writes a row
+    once a cycle of ``P / ops_per_frame`` ticks: one, and one more for
+    every cycle a stall of ``STALL_S`` spans (none in the committed mixes;
+    the tests' tiny cycle of 80 ms wraps six times)."""
+    if traffic["loop"] == "closed":
+        return traffic["frames_in_flight"]
+    cycle_s = lay.P // traffic["ops_per_frame"] * traffic["tick_ms"] / 1e3
+    return 1 + int(STALL_S / cycle_s)
+
+
 def heights(lay: Layout, traffic: dict, window_rows: int):
-    """Every window height this mix can meet, smallest first."""
-    W = window_rows
-    extras = sorted({0, lay.S // 2, lay.S})
-    per = lay.P if traffic["loop"] == "closed" else traffic["ops_per_frame"]
+    """Every height a one-column window of this mix can have, smallest
+    first, derived from :func:`carve`'s rule."""
+    if traffic["loop"] == "closed":
+        return _closed_heights(lay, traffic, window_rows)
+    return _open_heights(lay, traffic, window_rows)
+
+
+def _open_heights(lay: Layout, traffic: dict, W: int):
+    """An open loop's pass holds frames of ``ops_per_frame`` rows that are
+    all different, and the multi-writer rows once for each writer: chunks
+    are full or what is left, a second round the multi-writer rows alone.
+    Where a stall can wrap its cycle (:func:`deepest`) frames repeat in a
+    pass and the rounds cut them anywhere: every height up to the
+    window's, which only a small window can have swept."""
+    per, deep = traffic["ops_per_frame"], deepest(lay, traffic)
+    if deep > 1:
+        if W > 32:
+            raise ValueError(
+                f"an open loop that writes a row every {lay.P // per} ticks "
+                f"piles its rows {deep} deep in a stall of {STALL_S} s: no "
+                f"closed set of heights to sweep")
+        return list(range(1, W + 1))
     out = set()
     for k in range(0, 2 * max(lay.C, W // per) + 1):
-        for e in extras:
+        for e in sorted({0, lay.S // 2, lay.S}):
             n = per * k + e
             if n >= W:
                 out.add(W)
@@ -145,26 +224,74 @@ def heights(lay: Layout, traffic: dict, window_rows: int):
     return sorted(out)
 
 
+def _closed_heights(lay: Layout, traffic: dict, W: int):
+    """A closed loop's pass holds ``f[c]`` whole frames of connection
+    ``c``, 0 to its frames in flight, so a row has the sum of its writers'
+    ``f`` pending. A chunk is ``W`` of the present rows: enumerated over
+    every set of connections present and, chunk by chunk, carved for every
+    count of the few connections that write the chunk's rows (counts up to
+    one more than a row's most writers tell every pattern of "more pending
+    than" apart, so more frames in flight add no height)."""
+    runs, out, seen = lay.runs(), set(), set()
+    levels = range(1, min(deepest(lay, traffic),
+                          max(len(w) for _, w in runs) + 1) + 1)
+    for present in itertools.product((False, True), repeat=lay.C):
+        # the present rows' runs, cut into chunks: {writers: rows} each
+        chunks, room = [{}], W
+        for size, writers in runs:
+            here = frozenset(c for c in writers if present[c])
+            while here and size:
+                n = min(size, room)
+                chunks[-1][here] = chunks[-1].get(here, 0) + n
+                size, room = size - n, room - n
+                if not room:
+                    chunks.append({})
+                    room = W
+        for chunk in chunks:
+            key = frozenset(chunk.items())
+            if not chunk or key in seen:
+                continue
+            seen.add(key)
+            conns = sorted(frozenset().union(*chunk))
+            sizes = list(chunk.values())
+            for f in itertools.product(levels, repeat=len(conns)):
+                n = dict(zip(conns, f))
+                # every window fused: the rounds then stop at every depth,
+                # the ones a wide round would skip too
+                out.update(h for h, _ in carve(np.repeat(
+                    [sum(n[c] for c in w) for w in chunk], sizes), W, EVERY))
+    return sorted(out)
+
+
+def shapes(lay: Layout, traffic: dict, window_rows: int):
+    """Every (height, columns) a window of this mix can have: the
+    one-column heights, and a full window at each wider column count that
+    a row's pending ops can fill."""
+    deep = deepest(lay, traffic)
+    return [(h, 1) for h in heights(lay, traffic, window_rows)] + [
+        (window_rows, c) for c in sorted(WINDOW_COLUMNS) if 1 < c <= deep]
+
+
 def programs(lay: Layout, traffic: dict, window_rows: int,
              vocab: Vocabulary, rich: bool):
-    """Every (height, payload-table size) a window of this mix can meet:
-    for each height, the table sizes that the distinct characters of its
-    inserts plus its distinct marks can pad to, between a settled
+    """Every (height, columns, payload-table size) a window of this mix
+    can meet: for each shape, the table sizes that the distinct characters
+    of its inserts plus its distinct marks can pad to, between a settled
     document's share of inserts (half of the text ops) and a growing
     one's (the source's)."""
     m = traffic["mix"]
     text = 1.0 - (m["annotate_share"] if rich else 0.0)
     marks = np.full(len(vocab.props), 1.0 / max(len(vocab.props), 1))
     out = []
-    for h in heights(lay, traffic, window_rows):
-        lo, hi = distinct_range(vocab.p, h, text * 0.5,
+    for h, cols in shapes(lay, traffic, window_rows):
+        lo, hi = distinct_range(vocab.p, h * cols, text * 0.5,
                                 text * m["insert_share"])
         if rich:
-            m_lo, m_hi = distinct_range(marks, h, m["annotate_share"],
+            m_lo, m_hi = distinct_range(marks, h * cols, m["annotate_share"],
                                         m["annotate_share"])
-            lo, hi = lo + m_lo, min(hi + m_hi, h)
-        out += [(h, t) for t in sorted({table_size(n) for n in
-                                        range(max(lo, 1), hi + 1)})]
+            lo, hi = lo + m_lo, min(hi + m_hi, h * cols)
+        out += [(h, cols, t) for t in sorted({table_size(n) for n in
+                                              range(max(lo, 1), hi + 1)})]
     return out
 
 

@@ -3,6 +3,7 @@
 correct), and the yardstick's arithmetic checked against hand-worked cases.
 No test here gives a device number."""
 
+import itertools
 import json
 import os
 import re
@@ -20,9 +21,9 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
 from perfbench import faults, reduce, roofline, trace, wire  # noqa: E402
 from perfbench.refdoc import RefDoc  # noqa: E402
-from perfbench.traffic import (Layout, OpMaker, Vocabulary, heights,  # noqa: E402
-                               load_json, programs, select_metrics,
-                               table_size)
+from perfbench.traffic import (WINDOW_COLUMNS, Layout, OpMaker,  # noqa: E402
+                               Vocabulary, carve, heights, load_json,
+                               programs, select_metrics, shapes, table_size)
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
@@ -76,6 +77,10 @@ def test_rehearsal_of_a_cell(config, traffic, trace_on, chips):
     assert {"platform", "kind", "count", "memory_peak_bytes", "memory"} \
         <= set(r["device"])
     assert [m["id"] for m in r["device"]["memory"]] == list(range(chips))
+    # set-up met every program it was sent for, and the window none new
+    assert r["programs"]["swept"] > 0
+    assert r["programs"]["swept_not_met"] == r["programs"][
+        "new_in_window"] == []
     family = traffic.split("-")[1]
     names = set(r["metrics"])
     if trace_on:
@@ -85,9 +90,11 @@ def test_rehearsal_of_a_cell(config, traffic, trace_on, chips):
         assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
         assert not any("roofline" in n for n in names)   # no CPU roofline
         if chips == 4:
-            want = {m["name"] for m in BENCH["per_layer"]
-                    if m["name"].endswith(".replay")} - CHIP_ONLY
-            assert len(want) == 11 and names == want
+            # what the committed mesh replay cell reports, by the command's
+            # own rule: the entries that list it or list no cell
+            want = {m["name"] for m in select_metrics(
+                BENCH, "string-deli-10k-mesh4.replay")[1]} - CHIP_ONLY
+            assert names == want and want
             assert 0 < r["metrics"]["device.busy_min_over_max.replay"][
                 "value"] <= 1
             assert r["metrics"]["device.chip0_busy_over_mean.replay"][
@@ -203,13 +210,24 @@ def test_every_traced_cell_line_has_its_metrics():
     nothing and move a metric it reports: the mesh's replay cell reports
     every metric of the one-chip replay cell, under the same names, and
     the two that exist only across chips."""
+    def listed(cell):
+        return {m["name"] for m in BENCH["per_layer"]
+                if m["name"].endswith(".replay")
+                and cell in m.get("workloads", [cell])}
     one = {m["name"] for m in select_metrics(
         BENCH, "string-deli-10k.replay")[1]}
     four = {m["name"] for m in select_metrics(
         BENCH, "string-deli-10k-mesh4.replay")[1]}
-    assert len(one) == 12 and four - one == {
-        "device.chip0_busy_over_mean.replay",
-        "device.busy_min_over_max.replay"} and one <= four
+    # the rule and the manifest agree: an entry with no list is every
+    # replay cell's, one with a list its cells' alone (a later PR's
+    # metric that lists its own cell changes neither of these two)
+    assert one == listed("string-deli-10k.replay") and one
+    assert four == listed("string-deli-10k-mesh4.replay")
+    assert {"device.chip0_busy_over_mean.replay",
+            "device.busy_min_over_max.replay"} <= four - one
+    shared = {m["name"] for m in BENCH["per_layer"]
+              if m["name"].endswith(".replay") and "workloads" not in m}
+    assert shared <= one and shared <= four
     e2e = select_metrics(BENCH, "string-deli-10k-mesh4.replay")[0]
     assert [m["name"] for m in e2e] == ["acked_ops_per_s", "setup_s"]
 
@@ -340,40 +358,50 @@ def test_frames_carry_the_tables_they_use():
         == [v.props[i] for i in ops["tidx"][ann]]
 
 
-@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
-def test_programs_cover_the_table_sizes_a_window_meets(cell):
-    """Draw windows of every height from the mix itself and see that the
-    payload-table size each pads to is one set-up dispatches on purpose."""
+def _cell(cell):
     cfg = load_json("configs", cell["config"])
     tr = load_json("traffic", cell["traffic"])
-    dep, rich = cfg["deployment"], bool(cfg["wire"]["props"])
+    dep = cfg["deployment"]
     lay = Layout(dep["n_docs"], tr["connections"], tr["multi_writer_docs"])
-    v = Vocabulary(cfg)
-    progs = programs(lay, tr, dep["door"]["window_min_rows"], v, rich)
+    return cfg, tr, dep, lay, dep["door"]["window_min_rows"]
+
+
+def _table_of(frames):
+    ops = np.concatenate(frames)
+    return table_size(len(set(ops["tidx"][ops["kind"] == wire.INS]))
+                      + len(set(ops["tidx"][ops["kind"] == wire.ANN])))
+
+
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
+def test_programs_cover_the_table_sizes_a_window_meets(cell):
+    """Draw windows of every shape from the mix itself (a column is a
+    frame's op on each of the window's rows) and see that the
+    payload-table size each pads to is one set-up dispatches on purpose."""
+    cfg, tr, dep, lay, W = _cell(cell)
+    rich, v = bool(cfg["wire"]["props"]), Vocabulary(cfg)
+    progs = programs(lay, tr, W, v, rich)
     assert len(progs) <= 64
+    assert {(h, c) for h, c, _ in progs} == set(shapes(lay, tr, W))
     assert table_size(0) == table_size(8) == 8 and table_size(9) == 16
     mk = OpMaker(3, 0, 1280, tr["mix"], rich, v)
     li = np.arange(1280)
     for _ in range(tr["mix"]["fill_rounds"]):
         mk.make(li, li, fill=True)
-    for h in sorted({h for h, _ in progs}):
+    for h, cols in shapes(lay, tr, W):
         for _ in range(40):
-            ops = mk.make(li[:h], li[:h])
-            n = len(set(ops["tidx"][ops["kind"] == wire.INS])) \
-                + len(set(ops["tidx"][ops["kind"] == wire.ANN]))
-            assert (h, table_size(n)) in progs, (h, n)
+            tab = _table_of([mk.make(li[:h], li[:h]) for _ in range(cols)])
+            assert (h, cols, tab) in progs, (h, cols, tab)
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 def test_setup_frames_fill_the_table_they_are_sent_for(cell):
-    """Every frame of a program's compaction cycle in set-up has to pad to
-    that program's table size, the small heights too: the fused zamboni
-    falls on one frame of the sixteen, whichever it is."""
+    """Every window of a program's compaction cycle in set-up has to pad
+    to that program's table size, the small heights too: the fused zamboni
+    falls on one window of the sixteen, whichever it is. A window's
+    columns are as many frames on the same rows."""
     from perfbench.gen import Generator
-    cfg = load_json("configs", cell["config"])
-    tr = load_json("traffic", cell["traffic"])
-    dep, rich = cfg["deployment"], bool(cfg["wire"]["props"])
-    lay = Layout(dep["n_docs"], tr["connections"], tr["multi_writer_docs"])
+    cfg, tr, dep, lay, W = _cell(cell)
+    rich = bool(cfg["wire"]["props"])
 
     class Stub:
         vocab = Vocabulary(cfg)
@@ -381,63 +409,144 @@ def test_setup_frames_fill_the_table_they_are_sent_for(cell):
     li = np.arange(1280)
     for _ in range(tr["mix"]["fill_rounds"]):
         mk.make(li, li, fill=True)
-    for h, tab in programs(lay, tr, dep["door"]["window_min_rows"],
-                           Stub.vocab, rich):
+    for h, cols, tab in programs(lay, tr, W, Stub.vocab, rich):
         for _ in range(dep["engine"]["compact_every"]):
-            ops = mk.make(li[:h], li[:h],
-                          inserts=tab // 2 + 1 if tab > 8 else 0)
-            Generator._fill_table(Stub, ops, tab)
-            n = len(set(ops["tidx"][ops["kind"] == wire.INS])) \
-                + len(set(ops["tidx"][ops["kind"] == wire.ANN]))
-            assert table_size(n) == tab, (h, tab, n)
+            frames, skip = [], 0
+            for _ in range(cols):
+                ops = mk.make(li[:h], li[:h],
+                              inserts=tab // 2 + 1 if tab > 8 else 0)
+                skip = Generator._fill_table(Stub, ops, tab, skip)
+                frames.append(ops)
+            assert _table_of(frames) == tab, (h, cols, tab)
 
 
-def _carve(level_rows, window_rows):
-    n = len(level_rows)
-    return [min(window_rows, n - s) for s in range(0, n, window_rows)]
+def _door_windows(rows, window_rows, fused=()):
+    """A drain pass's rows (one entry an op) carved as the door carves
+    since PR 31, row by row: [(the window's rows, its columns)]. The
+    shapes are ``traffic.carve``'s, which keeps no rows."""
+    uniq, pending = np.unique(rows, return_counts=True)
+    out = []
+    for s in range(0, len(uniq), window_rows):
+        at, todo = uniq[s:s + window_rows], pending[s:s + window_rows]
+        while len(at):
+            cols = 1
+            if len(at) == window_rows and len(out) not in fused:
+                cols = next(c for c in WINDOW_COLUMNS if c <= todo.min())
+            out.append((at, cols))
+            at, todo = at[todo > cols], todo[todo > cols] - cols
+    assert [(len(r), c) for r, c in out] == carve(pending, window_rows, fused)
+    return out
+
+
+def _rows_in_join_order(lay):
+    """Row of every document as the door gives them out: in the order the
+    connections join (``Layout.doc_names``), so the multi-writer rows
+    follow connection 0's own."""
+    row = {}
+    for c in range(lay.C):
+        for d in lay.doc_names(c):
+            row.setdefault(d, len(row))
+    return row
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 def test_window_heights_come_from_the_closed_set(cell):
-    """Carve random drain passes the way the door does (rows sorted, split
-    by per-row occurrence, cut every window_min_rows) and see that no
-    height outside ``heights`` can come up."""
-    dep = load_json("configs", cell["config"])["deployment"]
-    tr = load_json("traffic", cell["traffic"])
-    lay = Layout(dep["n_docs"], tr["connections"], tr["multi_writer_docs"])
-    W = dep["door"]["window_min_rows"]
-    hs = set(heights(lay, tr, W))
-    if tr["loop"] == "closed":
-        assert hs == {8, 16, 256, 264, 272, 512}
-    assert len(hs) <= 24 and max(hs) == W
+    """Carve random drain passes the way the door does (rows sorted, cut
+    every window_min_rows, a full chunk whose every row has four pending
+    four wide unless the zamboni is fused into it, what is left one wide)
+    and see that no shape outside ``shapes`` can come up: closed loop or
+    open, whatever the connections' frames in the pass."""
+    cfg, tr, dep, lay, W = _cell(cell)
+    hs = heights(lay, tr, W)
+    ok = set(shapes(lay, tr, W))
+    assert {h for h, c in ok if c == 1} == set(hs) and max(hs) == W
+    assert len(hs) <= 24 and len(ok) <= len(hs) + len(WINDOW_COLUMNS) - 1
+    # the runs Layout states are the rows in join order
+    row = _rows_in_join_order(lay)
+    wrote = [set(lay.doc_names(c)[:lay.P]) | set(lay.writes_shared(c))
+             for c in range(lay.C)]
+    writers = [frozenset(c for c in range(lay.C) if d in wrote[c])
+               for d in sorted(row, key=row.get)]
+    runs = [(len(list(g)), w) for w, g in itertools.groupby(writers)]
+    assert runs == lay.runs()
     rng = np.random.default_rng(0)
-    shared = np.arange(lay.owner * lay.P, lay.owner * lay.P + lay.S)
-    per = lay.P if tr["loop"] == "closed" else tr["ops_per_frame"]
+    closed = tr["loop"] == "closed"
+    per = lay.P if closed else tr["ops_per_frame"]
+    most = tr["frames_in_flight"] if closed else 2
+    shared = set(lay.shared_names())
+    solo = [np.asarray([row[d] for d in lay.doc_names(c)[:lay.P]
+                        if d not in shared]) for c in range(lay.C)]
+    co = [np.asarray([row[d] for d in lay.writes_shared(c)], np.int64)
+          for c in range(lay.C)]
+    seen = set()
     for _ in range(300):
         rows = []
+        # few frames a connection tell the shapes apart; more add none
+        deep = most if rng.random() < 0.3 else min(most, 4)
         for c in range(lay.C):
-            for f in range(int(rng.integers(0, 3))):
-                first = tr["loop"] == "closed" or rng.random() < 0.2
-                start = 0 if first else per * int(rng.integers(
-                    1, lay.P // per))
-                own = np.arange(c * lay.P + start, c * lay.P + start + per)
-                rows.append(own)
-                if first and c == 0:
-                    rows.append(shared)
-                if first and c == 1:
-                    rows.append(shared[1::2])
+            # a closed loop's frame is every document it writes; an open
+            # loop's the next ``per`` (no frame twice in a pass: no stall
+            # wraps the cycle), its first with the shared
+            q0 = 0 if closed or rng.random() < 0.2 else \
+                int(rng.integers(1, lay.P // per - most))
+            for f in range(int(rng.integers(0, deep + 1))):
+                q = q0 if closed else q0 + f
+                lo = q * per - (lay.S if c == lay.owner and q else 0)
+                hi = (q + 1) * per - (lay.S if c == lay.owner else 0)
+                rows += [solo[c][lo:hi]] + ([co[c]] if q == 0 else [])
         if not rows:
             continue
-        rows = np.sort(np.concatenate(rows))
-        occ = np.arange(len(rows)) - np.searchsorted(rows, rows)
-        for lvl in range(int(occ.max()) + 1):
-            got = _carve(rows[occ == lvl], W)
-            assert set(got) <= hs, (got, sorted(hs))
+        fused = {int(rng.integers(0, 16))} if rng.random() < 0.5 else ()
+        got = {(len(r), c) for r, c in _door_windows(
+            np.concatenate(rows), W, fused)}
+        assert got <= ok, (sorted(got - ok), sorted(ok))
+        seen |= got
+    if closed:
+        # and the closed loop meets every one of them
+        assert seen == ok, sorted(ok - seen)
+
+
+@pytest.mark.parametrize("handed,every", [(0, 16), (13, 16), (2, 3),
+                                          (0, 0)])
+def test_carve_is_the_doors_carving(handed, every):
+    """``traffic.carve`` is a copy of the door's rule: the door's own
+    ``_build_windows`` cuts random passes into the same shapes, whichever
+    windows the engine (``every``-th it is handed, ``handed`` so far)
+    fuses its zamboni into."""
+    from fluidframework_tpu.server.columnar_ingress import ColumnarAlfred
+    from fluidframework_tpu.utils import tracing
+    W, n_rows = 8, 50
+    rng = np.random.default_rng([handed, every])
+    eng = type("Eng", (), {"n_docs": n_rows, "compact_every": every})()
+    for most in (1, 3, 4, 9):
+        door = ColumnarAlfred(eng, window_min_rows=W)
+        door._pass_tl = tracing.new_record(pid=0, frames=0, ops=0,
+                                           admit_ms=0.0)
+        door._windows_to = [handed]
+        pending = rng.integers(0, most + 1, n_rows)
+        pending[:W] = most                  # a chunk that can widen
+        rows = rng.permutation(np.repeat(np.arange(n_rows), pending))
+        door._texts, door._props = ["t"], []
+        zeros = np.zeros(rows.size, np.int32)
+        door._parts = [dict(
+            {k: zeros for k in ("kind", "gidx", "a0", "a1", "ref")},
+            sess=object(), row=rows.astype(np.int32),
+            cseq=np.arange(rows.size, dtype=np.int32),
+            client=np.full(rows.size, 7, np.int32))]
+        got = [w["kind"].shape for w in door._build_windows()]
+        fused = {i for i in range(len(got))
+                 if every > 1 and (handed + i + 1) % every == 0}
+        assert got == carve(pending[pending > 0], W, fused)
+        # wide only where a chunk's every row had four, and then surely
+        # once a fused window no longer stands in its way
+        wide = max(c for _, c in got) > 1
+        assert wide <= (most >= 4) and (wide or most < 9)
 
 
 def test_wire_copy_speaks_the_doors_protocol():
     from fluidframework_tpu.server import columnar_ingress as door
     assert wire.OP_DTYPE == door._OP_DTYPE
+    assert WINDOW_COLUMNS == door._WINDOW_COLUMNS
     texts, props = ["a", "bee"], [{"bold": True}, {"bold": None}]
     ops = np.zeros(3, wire.OP_DTYPE)
     ops["row"], ops["tidx"] = [1, 2, 3], [1, 0, 1]

@@ -67,6 +67,9 @@ def test_result_keeps_its_keys_and_gains_program_spans(traced):
     assert ps["table"]["engine.log"][0] * 1e3 == pytest.approx(
         ps["spans"]["engine.log"][1] * windows, rel=0.25)
     assert r["correct"] is True
+    # the wide windows a stalled open loop piles up were swept in set-up
+    assert r["programs"]["swept_not_met"] == r["programs"][
+        "new_in_window"] == []
     json.dumps(r)
     # every metric that reads the span table has a value (the kernel's
     # name is a device op's: no such op in a CPU rehearsal)

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from test_perfbench import BENCH, _carve  # noqa: E402
+from test_perfbench import BENCH, _door_windows  # noqa: E402
 
 from perfbench.traffic import (Layout, load_json,  # noqa: E402
                                select_metrics)
@@ -123,7 +123,7 @@ def test_the_62k_replay_cell_keeps_its_manifest_entry():
     cell = next(w for w in BENCH["workloads"]
                 if w["name"] == "string-deli-62k.replay")
     assert (cell["config"], cell["traffic"], cell["chips"]) \
-        == (BIG["name"], "replay", 1)
+        == (BIG["name"], "replay-62k", 1)
     assert select_metrics(BENCH, cell["name"]) == select_metrics(
         BENCH, "string-deli-10k.replay")
     # the accepted cell stands where it stood, and this one after it
@@ -160,18 +160,15 @@ def _turns(lay, tr):
 
 
 def _windows(rows, dep):
-    """A drain pass carved the way the door does (rows sorted, split by
-    per-row occurrence, cut every ``window_min_rows``): each window's rows
-    by shard, placed with the program's own ``shard_of_rows``."""
+    """A drain pass carved the way the door does (rows sorted, cut every
+    ``window_min_rows``, each chunk in rounds; no row of a typing pass has
+    four ops pending, so every window is one column wide): each window's
+    rows by shard, placed with the program's own ``shard_of_rows``."""
     from fluidframework_tpu.parallel.sharded import shard_of_rows
-    rows = np.sort(rows)
-    occ = np.arange(len(rows)) - np.searchsorted(rows, rows)
-    for lvl in range(int(occ.max()) + 1):
-        level, at = rows[occ == lvl], 0
-        for h in _carve(level, dep["door"]["window_min_rows"]):
-            yield np.bincount(shard_of_rows(level[at:at + h], dep["n_docs"],
-                                            CHIPS), minlength=CHIPS).tolist()
-            at += h
+    for got, cols in _door_windows(rows, dep["door"]["window_min_rows"]):
+        assert cols == 1
+        yield np.bincount(shard_of_rows(got, dep["n_docs"], CHIPS),
+                          minlength=CHIPS).tolist()
 
 
 def test_every_window_of_a_whole_turn_lies_in_all_four_shards():

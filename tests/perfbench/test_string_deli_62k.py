@@ -20,7 +20,7 @@ from perfbench.traffic import (Layout, heights, load_json,  # noqa: E402
 CELL = "string-deli-62k.replay"
 BIG, SMALL = (load_json("configs", n) for n in ("string-deli-62k",
                                                 "string-deli-10k"))
-REPLAY = load_json("traffic", "replay")
+REPLAY = load_json("traffic", "replay-62k")
 
 
 # ------------------------------------------------------ the committed file
@@ -47,6 +47,16 @@ def test_a_frame_is_a_whole_number_of_half_windows():
     assert (dep["n_docs"] // C) % W == W // 2
 
 
+def test_its_traffic_is_replay_but_for_the_frames_in_flight():
+    """``replay-62k.json`` is ``replay.json`` with another count of frames
+    in flight (each population sits on its own plateau: PERF.md, section
+    4) and nothing else: same family, connections, mix and warm-up."""
+    ten, big = dict(load_json("traffic", "replay")), dict(REPLAY)
+    assert (ten.pop("name"), big.pop("name")) == ("replay", "replay-62k")
+    assert ten.pop("frames_in_flight") > big.pop("frames_in_flight") >= 4
+    assert ten == big and big["family"] == "replay"
+
+
 @pytest.mark.parametrize("group", ["deployment", "guarantees", "wire"])
 def test_differs_from_string_deli_10k_by_the_population_alone(group):
     big, small = dict(BIG[group]), dict(SMALL[group])
@@ -63,7 +73,7 @@ def test_manifest_states_the_file():
     assert set(SMALL["assumed"]) | {"population"} == set(BIG["assumed"])
     cell = BENCH["workloads"][-1]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == (CELL, BIG["name"], "replay", 1)
+        == (CELL, BIG["name"], "replay-62k", 1)
     # it reports what the 10k replay cell reports, under the same names
     # (so ``_rehearse``, which picks the first replay cell, reports it too)
     assert select_metrics(BENCH, CELL) == select_metrics(
@@ -121,8 +131,12 @@ def test_the_last_row_the_wire_names_is_served():
 
 def _half_window_heights(W, S):
     """What a frame of whole windows and a half can be cut into: the
-    multi-writer extras alone, the half with and without them, a whole."""
-    return {S // 2, S, W // 2, W // 2 + S // 2, W // 2 + S, W}
+    multi-writer extras alone, the half with and without them, a whole;
+    and, since the door cuts a pass's rows before it carves them in rounds
+    (PR 31) and every later connection's rows begin ``S`` off the grid,
+    the half and the whole less the extras."""
+    return {S // 2, S, W // 2, W // 2 + S // 2, W // 2 + S, W,
+            W // 2 - S // 2, W // 2 - S, W - S // 2, W - S} - {0}
 
 
 def test_tiny_wide_has_the_cells_frame_shape():
@@ -131,7 +145,7 @@ def test_tiny_wide_has_the_cells_frame_shape():
     per = dep["n_docs"] // C
     assert per % W == W // 2 and per // W >= 3      # many windows, and a half
     assert dep["n_docs"] % 8 == 0 and dep["capacity"] % 128 == 0   # a tile
-    # the same six kinds of window at both sizes
+    # the same kinds of window at both sizes
     for d, t in ((dep, tr), (BIG["deployment"], REPLAY)):
         w, s = d["door"]["window_min_rows"], t["multi_writer_docs"]
         lay = Layout(d["n_docs"], t["connections"], s)
