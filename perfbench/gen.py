@@ -27,6 +27,13 @@ from .traffic import (Layout, OpMaker, Vocabulary, cut_probability,
                       programs)
 
 RING = 16        # the least ops of one document that may be in flight
+#: entries of the record of ops (45 bytes each over the six arrays) reserved
+#: at start: 200 s of the fastest cell's acks. ``np.zeros`` leaves the pages
+#: untouched until an op is booked there, so this is address space, and no
+#: array is copied while clients wait for the generator to read their acks
+RECORD = 1 << 25
+RECORD_ARRAYS = ("ops", "op_seq", "op_trecv", "op_due", "op_fid", "op_conn")
+ACK_BIN_S = 0.1  # ``ack_gap_share``: the width of a bin of acks
 now = time.monotonic
 
 
@@ -57,14 +64,14 @@ class Generator:
         self.failures = collections.Counter()
         self.notes = []
         # every op ever sent, in send order
-        cap = 1 << 16
-        self.ops = np.zeros(cap, wire.OP_DTYPE)
-        self.op_seq = np.zeros(cap, np.int64)
-        self.op_trecv = np.zeros(cap, np.float64)
-        self.op_due = np.zeros(cap, np.float64)
-        self.op_fid = np.zeros(cap, np.int32)
-        self.op_conn = np.zeros(cap, np.int8)
+        self.ops = np.zeros(RECORD, wire.OP_DTYPE)
+        self.op_seq = np.zeros(RECORD, np.int64)
+        self.op_trecv = np.zeros(RECORD, np.float64)
+        self.op_due = np.zeros(RECORD, np.float64)
+        self.op_fid = np.zeros(RECORD, np.int32)
+        self.op_conn = np.zeros(RECORD, np.int8)
         self.n_ops = 0
+        self.grew_in_window = 0     # times ``_grow`` copied the record there
         self.frame_left = []        # per frame: ops not yet acked
         self.frame_conn = []
         self.frame_late = []        # (due, seconds late) per stream frame
@@ -73,25 +80,34 @@ class Generator:
         self.stop_at = None
         self.cpu = {}
         self._stdin = bytearray()
+        # every read of acks lands here: a buffer of 1 MiB asked of the
+        # allocator for each ``recv`` is mapped and unmapped each time
+        # (glibc keeps a block that large out of its heap until one as
+        # large has been freed, which the record's doublings used to do)
+        self._rxbuf = memoryview(bytearray(1 << 20))
 
     # ------------------------------------------------------------ set-up
     def connect(self, port: int) -> None:
-        lay = self.lay
-        n = lay.n_docs
-        self.local = np.full(n, -1, np.int64)     # row → index in its owner
-        self.owner_of = np.full(n, -1, np.int64)
-        self.shared_rows = []
         name_row = {}
         for c in self.conns:
             c.sock = socket.create_connection(("127.0.0.1", port))
             c.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            names = lay.doc_names(c.idx)
+            names = self.lay.doc_names(c.idx)
             c.sock.sendall(wire.encode_json({"t": "join", "docs": names}))
             resp = self._recv_json(c, 120.0)
             if resp.get("t") != "joined":
                 raise RuntimeError(f"join refused: {resp}")
             c.client_id = resp["client_id"]
             name_row.update(resp["rows"])
+        self.place(name_row)
+
+    def place(self, name_row: dict) -> None:
+        """The generator's books, from the rows the door gave the joined
+        documents."""
+        lay = self.lay
+        n = lay.n_docs
+        self.local = np.full(n, -1, np.int64)     # row → index in its owner
+        self.owner_of = np.full(n, -1, np.int64)
         shared = lay.shared_names()
         self.shared_rows = [name_row[d] for d in shared]
         self.docs = {r: RefDoc(lay.n_joins(d))
@@ -130,11 +146,16 @@ class Generator:
 
     # ------------------------------------------------------------- frames
     def _grow(self, need: int) -> None:
+        """Room for ``need`` more ops: nothing to do within ``RECORD``; a
+        run that outlasts it doubles the six arrays, and a doubling that
+        falls inside the window (the clients' acks wait for it) is
+        counted."""
         if self.n_ops + need <= len(self.ops):
             return
+        if self.t0 is not None and self.t0 <= now() < self.t1:
+            self.grew_in_window += 1
         cap = max(2 * len(self.ops), self.n_ops + need)
-        for name in ("ops", "op_seq", "op_trecv", "op_due", "op_fid",
-                     "op_conn"):
+        for name in RECORD_ARRAYS:
             old = getattr(self, name)
             new = np.zeros(cap, old.dtype)
             new[:len(old)] = old
@@ -245,11 +266,11 @@ class Generator:
         for c in self.conns:
             if c.sock not in ready:
                 continue
-            chunk = c.sock.recv(1 << 20)
-            if not chunk:
+            n = c.sock.recv_into(self._rxbuf)
+            if not n:
                 raise ConnectionError("door closed a connection")
             t = now()
-            c.rx += chunk
+            c.rx += self._rxbuf[:n]
             for ftype, payload in wire.split_frames(c.rx):
                 self._on_frame(c, json.loads(payload), t)
         if sys.stdin.fileno() in ready:
@@ -499,6 +520,10 @@ class Generator:
         if "t0" in self.cpu and "t1" in self.cpu:
             (c0, a), (c1, b) = self.cpu["t0"], self.cpu["t1"]
             out["cpu_share"] = (c1 - c0) / (b - a)
+        gaps = ack_gap_share(trecv[inw], self.t0, self.t1)
+        if gaps is not None:
+            out["ack_gap_share"] = gaps
+        out["grew_in_window"] = self.grew_in_window
         return out
 
     def report(self, path: str, sample) -> None:
@@ -517,6 +542,19 @@ class Generator:
                  visible_len=np.concatenate(
                      [c.mk.length for c in self.conns]),
                  visible_rows=np.concatenate([c.rows for c in self.conns]))
+
+
+def ack_gap_share(trecv: np.ndarray, t0: float, t1: float):
+    """The share, in percent, of the window's bins of ``ACK_BIN_S`` that
+    hold under a quarter of the median bin's acks: the stretches in which
+    the clients heard (next to) nothing, whoever stalled. Nothing where no
+    bin or no ack is."""
+    n = int(round((t1 - t0) / ACK_BIN_S))
+    if n < 1 or not len(trecv):
+        return None
+    bins = np.bincount(np.minimum(((trecv - t0) / ACK_BIN_S).astype(np.int64),
+                                  n - 1), minlength=n)
+    return float((bins < np.median(bins) / 4.0).mean() * 100.0)
 
 
 def say(obj: dict) -> None:

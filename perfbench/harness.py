@@ -379,6 +379,9 @@ def _serve(cell, config, traffic, gen, door, engine, log, devs, mesh, device,
     result["programs"] = {"swept": len(swept["programs"]),
                           "swept_not_met": missed,
                           "new_in_window": list(map(_shape, new_variants))}
+    # times the generator copied its record of ops inside the window, its
+    # clients' acks unread meanwhile: 0 in a sound run
+    result["notes"] = {"grew_in_window": found["grew_in_window"]}
     result["compared"] = {k: {"value": v, "limit": lim}
                           for k, (v, lim) in compared.items()}
     return result

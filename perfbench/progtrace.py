@@ -38,7 +38,6 @@ from .traffic import HERE, select_metrics
 
 PREFIX = "fluid."
 CLOCK = PREFIX + "clock"
-KERNEL = "string_merge"          # the Pallas merge's name, by variant
 UNATTRIBUTED = "_unattributed_"
 
 
@@ -317,43 +316,20 @@ def stamp_error_us(events, recs, offset):
             "max": float(np.max(errs))}
 
 
-def kernel_raw(events) -> dict:
-    """Device time and count of the Pallas merge's ops, the plain and the
-    one with the zamboni fused in (with or without props), by the names
-    the program gives them."""
-    raw = {}
-    for _p, line, name, _s, d, _w in events:
-        if line != trace.OPS_LINE:
-            continue
-        k = trace.stable(name)
-        if not k.startswith(KERNEL):
-            continue
-        kind = "zamboni" if "_zamboni" in k else "plain"
-        raw[f"trace.kernel_s.{kind}"] = raw.get(
-            f"trace.kernel_s.{kind}", 0.0) + d / 1e9
-        raw[f"trace.kernel_n.{kind}"] = raw.get(
-            f"trace.kernel_n.{kind}", 0) + 1
-    return raw
-
-
 def reduce_dir(trace_dir: str, out_dir: str, closed,
                rehearsal: bool = False):
     """The traced slice read once more for what the program put there:
-    ``raw`` readings for the metric files, and the result's
-    ``program_spans``. ``closed``: the measured window's two ends on
-    ``time.perf_counter()``'s clock; the records of the windows closed
-    between them go to ``out_dir/windows.jsonl``, and ``long`` and
+    the result's ``program_spans``. ``closed``: the measured window's two
+    ends on ``time.perf_counter()``'s clock; the records of the windows
+    closed between them go to ``out_dir/windows.jsonl``, and ``long`` and
     ``spans`` are theirs."""
     paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
-    out = {"raw": {}, "idle_gaps": [], "long": {}, "clock_drift_us": None}
-    if len(paths) != 1:
+    out = {"idle_gaps": [], "long": {}, "clock_drift_us": None}
+    tr = _tracing()
+    if len(paths) != 1 or tr is None:
         return out
     events, marks = load(paths[0])
-    out["raw"] = kernel_raw(events)
-    tr = _tracing()
-    if tr is None:
-        return out
     recs = records(closed)
     dump(os.path.join(out_dir, "windows.jsonl"), recs)
     offset, drift = clock_map(marks)
@@ -382,8 +358,8 @@ def attached(harness, seen: dict):
     readings: the span table and the harness's own outside spans at the
     measured window's two ends (when the harness tells the generator of
     them), a clock mark inside each end of the profile, and, where the
-    harness reduces the trace, the program's part of it, whose ``raw``
-    readings join the harness's before the metric files are read.
+    harness reduces the trace, the program's part of it; the span table's
+    differences join the harness's ``raw`` before the metric files are read.
     ``seen["program_spans"]`` is the result's new key. Three names of the
     harness and two of ``jax.profiler`` are wrapped, as ``_instrument``
     wraps the program's, and put back on the way out."""
@@ -431,7 +407,6 @@ def attached(harness, seen: dict):
         outside.update({f"d.span.{k}.n": n1[k] - n0.get(k, 0) for k in n1})
         prog = reduce_dir(tr_dir, os.path.dirname(tr_dir), (p0, p1),
                           rehearsal)
-        raw.update(prog.pop("raw"))
         seen["program_spans"] = dict(
             prog, table=table(raw), outside=table(outside, "d.span."))
         red["raw"].update(raw)

@@ -15,6 +15,7 @@ import numpy as np
 
 DEVICE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+KERNEL = "string_merge"          # the Pallas merge's name, by variant
 SPAN_NAMES = ("store.apply_planes", "door.drain", "door.build_windows",
               "door.fan_acks", "engine.prepare", "engine.sequence",
               "engine.dispatch", "engine.log")
@@ -83,7 +84,7 @@ def reduce_events(events, rehearsal: bool = False, devices=None) -> dict:
     hi = max(e[3] + e[4] for e in events)
     window_ns = hi - lo
     busy_ns, raw = [], {}
-    op_s, mod_s, mod_n = {}, {}, {}
+    op_s, op_n, mod_s, mod_n = {}, {}, {}, {}
     gaps = []
     for p in planes:
         ops = [(s, s + d) for pl, ln, _n, s, d in events
@@ -102,6 +103,7 @@ def reduce_events(events, rehearsal: bool = False, devices=None) -> dict:
             if ln == OPS_LINE:
                 k = stable(name)
                 op_s[k] = op_s.get(k, 0.0) + d / 1e9
+                op_n[k] = op_n.get(k, 0) + 1
             elif ln == MODULES_LINE:
                 k = stable(name)
                 mod_s[k] = mod_s.get(k, 0.0) + d / 1e9
@@ -110,6 +112,8 @@ def reduce_events(events, rehearsal: bool = False, devices=None) -> dict:
     for k, v in mod_s.items():
         raw[f"trace.module_s.{k}"] = v / n
         raw[f"trace.module_n.{k}"] = mod_n[k] / n
+    for k, v in kernel_raw(op_s, op_n).items():
+        raw[k] = v / n
     raw["trace.window_s"] = window_ns / 1e9
     raw["trace.busy_s"] = sum(busy_ns) / n / 1e9
     for i, b in enumerate(busy_ns):
@@ -123,6 +127,21 @@ def reduce_events(events, rehearsal: bool = False, devices=None) -> dict:
                     op_s.items(), key=lambda kv: -kv[1])[:10]],
                 "idle_gaps": [[k, v / n] for k, v in
                               idle_by_span(events, gaps)[:10]]}}
+
+
+def kernel_raw(op_s: dict, op_n: dict) -> dict:
+    """Device time and count of the Pallas merge's ops, from the sums by
+    stable op name: the plain one and the one with the zamboni fused in
+    (with or without props), by the names the program gives them
+    (``string_merge[_zamboni][_props]``). Both kinds where the trace has
+    either, nothing where it has neither."""
+    kernels = [k for k in op_s if k.startswith(KERNEL)]
+    raw = {}
+    for kind in ("plain", "zamboni") if kernels else ():
+        mine = [k for k in kernels if ("_zamboni" in k) == (kind == "zamboni")]
+        raw[f"trace.kernel_s.{kind}"] = sum(op_s[k] for k in mine)
+        raw[f"trace.kernel_n.{kind}"] = sum(op_n[k] for k in mine)
+    return raw
 
 
 def idle_by_span(events, gaps):

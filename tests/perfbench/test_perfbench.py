@@ -60,8 +60,11 @@ def _rehearse(config, traffic, trace_on, plant="", seed=2_147_483_659,
 
 # what a CPU's trace cannot give: it has no line of modules, the roofline
 # needs the chip's peaks, and the CPU reports no memory
+# (nor an op by the Pallas kernel's name: the interpreter runs it as XLA ops)
 CHIP_ONLY = {"kernel.merge_ms_per_window.replay", "merge_roofline.replay",
-             "device.peak_hbm_bytes.replay"}
+             "device.peak_hbm_bytes.replay",
+             "kernel.zamboni_merge_ms_per_window.replay",
+             "kernel.merge_outside_kernel_share.replay"}
 
 
 @pytest.mark.parametrize("config,traffic,trace_on,chips", [
@@ -81,6 +84,8 @@ def test_rehearsal_of_a_cell(config, traffic, trace_on, chips):
     assert r["programs"]["swept"] > 0
     assert r["programs"]["swept_not_met"] == r["programs"][
         "new_in_window"] == []
+    # and the generator copied no array of its record inside the window
+    assert r["notes"] == {"grew_in_window": 0}
     family = traffic.split("-")[1]
     names = set(r["metrics"])
     if trace_on:

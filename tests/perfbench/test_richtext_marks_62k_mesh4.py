@@ -102,7 +102,13 @@ def test_manifest_states_the_file():
     assert e2e == one[0] and [m["name"] for m in e2e] == ["ack_p50_ms",
                                                           "setup_s"]
     names = {m["name"] for m in layers}
-    assert len(one[1]) == 14 and names - {m["name"] for m in one[1]} \
+    # (the one-chip cell's two by-kernel readings of PR 36 list it alone:
+    # ``tests/test_richtext_marks_62k_mesh4_rehearsal.py`` holds this
+    # cell's traced line to fourteen names)
+    BY_KERNEL = {"kernel.zamboni_merge_ms_per_window.typing",
+                 "kernel.merge_outside_kernel_share.typing"}
+    assert len(one[1]) == 16 and {m["name"] for m in one[1]} - names \
+        == BY_KERNEL and names - {m["name"] for m in one[1]} \
         == ACROSS_CHIPS and len(names) == 18
     assert all(m["workloads"] == [CELL] for m in layers
                if m["name"] in ACROSS_CHIPS)
