@@ -58,6 +58,17 @@ class DedupLedger:
     client's in-flight window is far smaller than ``window``); snapshots
     ride the engine summary so the ledger survives restarts, and
     ``_replay_tail`` re-records the tail.
+
+    Two write paths, one read interface. ``record`` (submit, log-tail
+    replay) keeps a key's window as an ``OrderedDict``. ``record_planes``
+    (the columnar door's ack fan, ISSUE 37) keeps it by ``(row, client)``
+    in arrays: a ring of ``window`` slots a key, slot-major, filled in
+    insertion order (slot = insertions % window, so the slot written
+    next holds the oldest entry — the ``OrderedDict``'s eviction order).
+    A key lives in one form at a time: ``record_planes`` adopts a key
+    the per-op form holds, and ``record``, ``merge`` and ``release_row``
+    hand a ring key back to it under its document, so every read answers
+    exactly as one ``OrderedDict`` ledger would.
     """
 
     def __init__(self, window: int = 512):
@@ -70,14 +81,29 @@ class DedupLedger:
         self._entries = 0
         # the ack fan records on the ingress event loop while the
         # pipelined executor's sequencing worker looks up dup slots —
-        # off the hot path (records are small per-window loops, lookups
-        # only happen for rare DUPLICATE nacks), so a plain lock is fine
+        # off the hot path (a record is a few array operations a window,
+        # lookups only happen for rare DUPLICATE nacks), so a plain lock
+        # is fine
         self._lock = threading.Lock()
+        # ---- the array form: key id k names column k of the rings
+        # (clients are the sequencer's int32, so a code names one key)
+        self._code = np.empty(0, np.int64)      # sorted row << 32 | client
+        self._code_kid = np.empty(0, np.int64)  # key id of each code
+        self._ring_of: Dict[Tuple[str, int], int] = {}
+        self._ring_key: List[Optional[Tuple[str, int]]] = []
+        self._free_kids: List[int] = []
+        self._cs = np.zeros((window, 0), np.int64)   # client seq
+        self._sq = np.zeros((window, 0), np.int64)   # seq
+        self._n = np.zeros(0, np.int64)    # insertions into a key's ring
+        self._hi = np.zeros(0, np.int64)   # last(): highest client seq
+        self._ring_entries = 0
 
     def record(self, doc_id: str, client_id: int, client_seq: int,
                seq: int) -> None:
         key = (doc_id, int(client_id))
         with self._lock:
+            if key in self._ring_of:
+                self._spill(key)
             led = self._led.get(key)
             if led is None:
                 led = self._led[key] = collections.OrderedDict()
@@ -90,35 +116,179 @@ class DedupLedger:
             if client_seq > self._last.get(key, 0):
                 self._last[key] = int(client_seq)
 
-    def record_many(self, items) -> None:
-        """Record a whole ack window's ``(doc, client, client_seq, seq)``
-        tuples under ONE lock acquisition — the batch front door fans a
-        window's acks in one pass and a per-op lock round-trip there costs
-        more than the record itself."""
+    def record_planes(self, rows, clients, client_seqs, seqs,
+                      row_doc) -> int:
+        """Record a whole ack window by row: the ops' ``rows``,
+        ``clients``, ``client_seqs`` and ``seqs`` in record order (a
+        row's ops in its sequence order), ``seqs <= 0`` (nacks) left
+        out; ``row_doc[row]`` names a row's document and is read only
+        for a key met for the first time. A fixed number of array
+        operations for each depth, where the depth is a key's most ops
+        in the window (a door's window holds at most four ops a row).
+        Returns the ops recorded."""
+        seqs = np.asarray(seqs, np.int64)
+        rows = np.asarray(rows, np.int64)
+        clients = np.asarray(clients, np.int64)
+        cs = np.asarray(client_seqs, np.int64)
+        ok = seqs > 0
+        if not ok.all():
+            rows, clients, cs, seqs = rows[ok], clients[ok], cs[ok], seqs[ok]
+        n = seqs.size
+        if not n:
+            return 0
+        code = (rows << 32) | (clients & 0xFFFFFFFF)
+        if not (code[1:] >= code[:-1]).all():
+            # group each key's ops (a multi-writer row's clients
+            # interleave), every key's in its record order
+            order = np.argsort(code, kind="stable")
+            code, rows, clients, cs, seqs = (
+                x[order] for x in (code, rows, clients, cs, seqs))
+        head = np.empty(n, bool)
+        head[0] = True
+        np.not_equal(code[1:], code[:-1], out=head[1:])
+        at = np.flatnonzero(head)       # each key's first op
+        deep = np.diff(np.append(at, n))    # and how many it has
         with self._lock:
-            for doc_id, client_id, client_seq, seq in items:
-                key = (doc_id, int(client_id))
-                led = self._led.get(key)
-                if led is None:
-                    led = self._led[key] = collections.OrderedDict()
-                if int(client_seq) not in led:
-                    self._entries += 1
-                led[int(client_seq)] = int(seq)
-                while len(led) > self.window:
-                    led.popitem(last=False)
-                    self._entries -= 1
-                if client_seq > self._last.get(key, 0):
-                    self._last[key] = int(client_seq)
+            kid = self._kids(code[at], rows[at], clients[at], row_doc)
+            for d in range(int(deep.max())):
+                if d:
+                    more = deep > d
+                    kid, at, deep = kid[more], at[more], deep[more]
+                self._put(kid, cs[at + d], seqs[at + d])
+        return n
+
+    def _kids(self, code, rows, clients, row_doc) -> np.ndarray:
+        """Key id of each ``(row, client)`` (``code`` ascending, no
+        repeats); a key met for the first time gets a ring column."""
+        pos = np.searchsorted(self._code, code)
+        found = np.zeros(code.size, bool)
+        if self._code.size:
+            found = self._code[np.minimum(pos, self._code.size - 1)] == code
+        if not found.all():
+            miss = np.flatnonzero(~found)
+            kids = np.array([self._new_kid((row_doc[r], c)) for r, c in zip(
+                rows[miss].tolist(), clients[miss].tolist())], np.int64)
+            at = np.searchsorted(self._code, code[miss])
+            self._code = np.insert(self._code, at, code[miss])
+            self._code_kid = np.insert(self._code_kid, at, kids)
+            pos = np.searchsorted(self._code, code)
+        return self._code_kid[pos]
+
+    def _new_kid(self, key: Tuple[str, int]) -> int:
+        """A ring column for ``key``, holding the window the per-op form
+        held for it, in its order, if it held one."""
+        if self._free_kids:
+            k = self._free_kids.pop()
+        else:
+            k = len(self._ring_key)
+            self._ring_key.append(None)
+            if k >= self._n.size:
+                self._grow(max(64, 2 * self._n.size))
+        self._ring_key[k] = key
+        self._ring_of[key] = k
+        led = self._led.pop(key, None)
+        m = 0 if led is None else len(led)
+        if m:
+            self._cs[:m, k] = np.fromiter(led.keys(), np.int64, m)
+            self._sq[:m, k] = np.fromiter(led.values(), np.int64, m)
+            self._entries -= m
+            self._ring_entries += m
+        self._n[k] = m
+        self._hi[k] = self._last.pop(key, 0)
+        return k
+
+    def _grow(self, cap: int) -> None:
+        """Widen the rings to ``cap`` keys. Only the slots some key has
+        filled are copied: the rest are zeros never written, pages the
+        process has not touched."""
+        old = self._n.size
+        depth = int(min(self._n.max(initial=0), self.window))
+        for name in ("_cs", "_sq"):
+            a = np.zeros((self.window, cap), np.int64)
+            a[:depth, :old] = getattr(self, name)[:depth]
+            setattr(self, name, a)
+        for name in ("_n", "_hi"):
+            a = np.zeros(cap, np.int64)
+            a[:old] = getattr(self, name)
+            setattr(self, name, a)
+
+    def _put(self, k, cs, sq) -> None:
+        """One op a key (``k`` distinct) into the rings, as an
+        ``OrderedDict`` assignment: a client seq still in its key's
+        window keeps its place and takes the new seq; any other goes to
+        slot ``n % window``, over the oldest entry once the window is
+        full."""
+        W = self.window
+        n_k, hi = self._n[k], self._hi[k]
+        self._hi[k] = np.maximum(hi, cs)
+        back = np.flatnonzero(cs <= hi)   # may be in its key's window
+        if back.size:
+            kb = k[back]
+            live = np.arange(W)[:, None] < np.minimum(n_k[back], W)
+            hit = (self._cs[:, kb] == cs[back]) & live
+            found = hit.any(axis=0)
+            if found.any():
+                self._sq[hit.argmax(axis=0)[found], kb[found]] = \
+                    sq[back[found]]
+                keep = np.ones(k.size, bool)
+                keep[back[found]] = False
+                k, cs, sq, n_k = k[keep], cs[keep], sq[keep], n_k[keep]
+        slot = n_k % W
+        self._cs[slot, k] = cs
+        self._sq[slot, k] = sq
+        self._n[k] = n_k + 1
+        self._ring_entries += int(np.count_nonzero(n_k < W))
+
+    def _window_of(self, k: int) -> Tuple[np.ndarray, np.ndarray]:
+        """A ring key's (client seqs, seqs), oldest first."""
+        n, W = int(self._n[k]), self.window
+        idx = np.arange(n) if n <= W else (n + np.arange(W)) % W
+        return self._cs[idx, k], self._sq[idx, k]
+
+    def _spill(self, key: Tuple[str, int]) -> None:
+        """Hand a ring key back to the per-op form, under its document:
+        its window in the same order, and its ``last``."""
+        k = self._ring_of.pop(key)
+        cs, sq = self._window_of(k)
+        self._led[key] = collections.OrderedDict(
+            zip(cs.tolist(), sq.tolist()))
+        self._last[key] = int(self._hi[k])
+        self._entries += cs.size
+        self._ring_entries -= cs.size
+        keep = self._code_kid != k
+        self._code, self._code_kid = self._code[keep], self._code_kid[keep]
+        self._ring_key[k] = None
+        self._free_kids.append(k)
+
+    def release_row(self, row: int) -> None:
+        """``row`` is about to name another document: every key recorded
+        by it goes back to the per-op form, under the document it
+        named."""
+        with self._lock:
+            lo, hi = np.searchsorted(self._code,
+                                     [row << 32, (row + 1) << 32])
+            for k in self._code_kid[lo:hi].tolist():
+                self._spill(self._ring_key[k])
 
     def lookup(self, doc_id: str, client_id: int,
                client_seq: int) -> Optional[int]:
+        key = (doc_id, int(client_id))
         with self._lock:
-            led = self._led.get((doc_id, int(client_id)))
+            k = self._ring_of.get(key)
+            if k is not None:
+                live = int(min(self._n[k], self.window))
+                at = np.flatnonzero(self._cs[:live, k] == int(client_seq))
+                return int(self._sq[at[0], k]) if at.size else None
+            led = self._led.get(key)
             return None if led is None else led.get(int(client_seq))
 
     def last(self, doc_id: str, client_id: int) -> int:
+        key = (doc_id, int(client_id))
         with self._lock:
-            return self._last.get((doc_id, int(client_id)), 0)
+            k = self._ring_of.get(key)
+            if k is not None:
+                return int(self._hi[k])
+            return self._last.get(key, 0)
 
     def snapshot(self, docs=None) -> dict:
         """Full snapshot, or — ``docs`` given — only those docs' entries
@@ -131,6 +301,12 @@ class DedupLedger:
                 out.setdefault(doc, {})[str(cid)] = {
                     "last": self._last.get((doc, cid), 0),
                     "acked": [[cs, sq] for cs, sq in led.items()]}
+            for (doc, cid), k in self._ring_of.items():
+                if docs is not None and doc not in docs:
+                    continue
+                out.setdefault(doc, {})[str(cid)] = {
+                    "last": int(self._hi[k]),
+                    "acked": np.stack(self._window_of(k), 1).tolist()}
         return out
 
     def merge(self, partial: Optional[dict]) -> None:
@@ -141,6 +317,8 @@ class DedupLedger:
             for cid, ent in clients.items():
                 key = (doc, int(cid))
                 with self._lock:
+                    if key in self._ring_of:
+                        self._spill(key)
                     self._last[key] = max(self._last.get(key, 0),
                                           int(ent.get("last", 0)))
                     old = self._led.get(key)
@@ -168,19 +346,29 @@ class DedupLedger:
 
     def mem_stats(self) -> dict:
         """O(1) capacity roll-up: acked rows, (doc, client) keys, and
-        the host-byte estimate (OrderedDict windows of boxed-int
-        entries plus the two key-tuple'd index dicts)."""
+        the host-byte estimate — the per-op form's OrderedDict windows
+        of boxed-int entries plus their two key-tuple'd index dicts, and
+        the array form's rings and key index as allocated
+        (``array_bytes``: a ring slot no key has reached is a page never
+        touched, so resident memory may be less)."""
         from ..utils import capacity as _cap
         with self._lock:
-            n_keys = len(self._led)
-            n_entries = self._entries
-        return {
-            "keys": n_keys,
-            "entries": n_entries,
-            "bytes": int(n_entries * _cap.ODICT_ENTRY_BYTES
-                         + n_keys * (_cap.ODICT_EMPTY_BYTES
-                                     + 2 * _cap.DICT_ENTRY_BYTES + 120)),
-        }
+            n_keys, ring_keys = len(self._led), len(self._ring_of)
+            arrays = sum(a.nbytes for a in (
+                self._cs, self._sq, self._n, self._hi, self._code,
+                self._code_kid))
+            return {
+                "keys": n_keys + ring_keys,
+                "entries": self._entries + self._ring_entries,
+                "bytes": int(
+                    self._entries * _cap.ODICT_ENTRY_BYTES
+                    + n_keys * (_cap.ODICT_EMPTY_BYTES
+                                + 2 * _cap.DICT_ENTRY_BYTES + 120)
+                    + arrays
+                    + _cap.dict_nbytes(ring_keys, _cap.DICT_ENTRY_BYTES + 120)
+                    + _cap.list_nbytes(len(self._ring_key))),
+                "array_bytes": int(arrays),
+            }
 
     def per_doc_entries(self) -> Dict[str, int]:
         """Acked-row count per doc (census-time walk of the key space —
@@ -189,6 +377,9 @@ class DedupLedger:
         with self._lock:
             for (doc, _cid), led in self._led.items():
                 out[doc] = out.get(doc, 0) + len(led)
+            live = np.minimum(self._n, self.window).tolist()
+            for (doc, _cid), k in self._ring_of.items():
+                out[doc] = out.get(doc, 0) + live[k]
         return out
 
 
@@ -511,9 +702,9 @@ class ServingEngineBase:
         n_docs = max(1, int(getattr(self, "n_docs", 0) or 0))
         row_share = sum(device.values()) // n_docs
         per_doc = self._dedup.per_doc_entries()
+        per_entry = dd["bytes"] // max(1, dd["entries"])
         ranked = sorted(
-            ((doc, row_share + per_doc.get(doc, 0)
-              * capacity.ODICT_ENTRY_BYTES)
+            ((doc, row_share + per_doc.get(doc, 0) * per_entry)
              for doc in self._doc_rows),
             key=lambda kv: kv[1], reverse=True)[:8]
         return capacity.report(host=host, device=device,
@@ -799,18 +990,12 @@ class ServingEngineBase:
 
     def note_acked_planes(self, rows, clients, client_seqs, seqs) -> None:
         """Vectorized ``note_acked``: one call (and one ledger lock) per
-        ack window. ``seqs <= 0`` entries are nacks — never recorded."""
-        seqs = np.asarray(seqs)
-        ok = seqs > 0
-        if not bool(ok.any()):
-            return
-        rdi = self._row_doc_id
-        self._dedup.record_many(
-            (rdi[r], c, cs, sq) for r, c, cs, sq in zip(
-                np.asarray(rows)[ok].tolist(),
-                np.asarray(clients)[ok].tolist(),
-                np.asarray(client_seqs)[ok].tolist(),
-                seqs[ok].tolist()))
+        ack window, recorded by row in the ledger's arrays. ``seqs <= 0``
+        entries are nacks — never recorded."""
+        n = self._dedup.record_planes(rows, clients, client_seqs, seqs,
+                                      self._row_doc_id)
+        if n:
+            REGISTRY.inc("dedup_planes_recorded", n)
 
     # --------------------------------------------------------------- ingress
 
@@ -1992,6 +2177,7 @@ class StringServingEngine(ServingEngineBase):
         """Return a graduated doc's flat row to the allocator (and clear
         the columnar caches so a reused row can't hit a stale handle)."""
         row = self._doc_rows.pop(doc_id)
+        self._dedup.release_row(row)
         self._free_rows.append(row)
         self._row_doc_id[row] = None
         self._row_handle[row] = -1
@@ -3913,6 +4099,7 @@ class TreeServingEngine(ServingEngineBase):
                 # _fill_row_handles instead of silently sequencing under
                 # a stale doc handle (live vs recovery divergence)
                 self._free_rows.append(self._doc_rows.pop(doc_id))
+                self._dedup.release_row(row)
                 self._row_doc_id[row] = None
                 self._row_handle[row] = -1
                 report[doc_id] = "graduated"
