@@ -473,9 +473,10 @@ class MetricsRegistry:
                  for k, v in sorted((labels or {}).items())]
             lab = "{" + ",".join(pairs) + "}" if pairs else ""
             comp = ",".join(pairs) + "," if pairs else ""
-            for k in sorted(reg.counters):
+            counters = reg.counters         # one reading of the registry
+            for k in sorted(counters):
                 lines.append(f"# TYPE {_prom_name(k)} counter")
-                lines.append(f"{_prom_name(k)}{lab} {reg.counters[k]}")
+                lines.append(f"{_prom_name(k)}{lab} {counters[k]}")
             for k in sorted(reg.gauges):
                 lines.append(f"# TYPE {_prom_name(k)} gauge")
                 lines.append(f"{_prom_name(k)}{lab} {reg.gauges[k]}")

@@ -177,18 +177,12 @@ def test_span_error_recorded_and_stack_unwound():
     assert tracer.current() is None   # the stack unwound despite the raise
 
 
-def test_record_complete_and_maybe_root_sampling():
+def test_record_complete_and_disabled_tracer():
     tracer = tracing.Tracer()
     ctx = tracer.record_complete("hot.batch", 12.5, ops=64)
     e, = tracer.events(ctx.trace_id)
     assert e["dur"] == pytest.approx(12.5e3)  # µs
     assert e["args"]["ops"] == 64
-    opened = 0
-    for _ in range(8):
-        with tracer.maybe_root_span("srv", every=4):
-            pass
-    opened = len([e for e in tracer.events() if e["name"] == "srv"])
-    assert opened == 2                # 1-in-4 sampling over 8 calls
     tracer.enabled = False
     assert tracer.record_complete("off", 1.0) is None
 

@@ -19,11 +19,14 @@ from perfbench.traffic import select_metrics  # noqa: E402
 
 CELL = "richtext-marks-62k-mesh4.typing"
 CHIPS = 4
-# what a CPU's trace cannot give (``test_perfbench.CHIP_ONLY``, for typing)
-# and what only the mesh's module line gives
+# what a CPU's trace cannot give (``test_perfbench.CHIP_ONLY``, for typing:
+# the interpreter runs the Pallas kernel as XLA ops, so no op bears the
+# kernel's name) and what only the mesh's module line gives
 CHIP_ONLY = {"kernel.merge_ms_per_window.typing", "merge_roofline.typing",
              "device.peak_hbm_bytes.typing",
-             "kernel.unpack_ms_per_window.typing"}
+             "kernel.unpack_ms_per_window.typing",
+             "kernel.zamboni_merge_ms_per_window.typing",
+             "kernel.merge_outside_kernel_share.typing"}
 
 
 def _mesh_counters():
@@ -47,7 +50,7 @@ def test_rehearsal_of_the_cell_on_four_chips(trace_on):
     names = set(r["metrics"])
     if trace_on:
         want = {m["name"] for m in select_metrics(BENCH, CELL)[1]}
-        assert names == want - CHIP_ONLY and len(names) == 14
+        assert names == want - CHIP_ONLY
         assert 0 < r["metrics"]["device.busy_min_over_max.typing"][
             "value"] <= 1
         assert r["metrics"]["device.chip0_busy_over_mean.typing"][
