@@ -186,8 +186,8 @@ class OplogFollower:
             eng._absorb_resilience(msg)
             if msg.type == MessageType.OP:
                 eng._enqueue(msg.doc_id, msg)
-                eng._min_seq[msg.doc_id] = max(
-                    eng._min_seq.get(msg.doc_id, 0), msg.min_seq)
+                eng._set_min_seq(msg.doc_id, max(
+                    eng._min_seq.get(msg.doc_id, 0), msg.min_seq))
             self._applied[msg.doc_id] = msg.seq
             n += 1
         if n:

@@ -586,6 +586,10 @@ class ServingEngineBase:
         self._queue: List[Tuple[int, SequencedDocumentMessage]] = []
         self._flushes_since_compact = 0
         self._min_seq: Dict[str, int] = {}
+        # the same floor by flat row, for engines whose compaction reads
+        # it as one array (StringServingEngine); every write of a flat
+        # row's floor goes through _set_min_seq to keep the two in step
+        self._row_floor: Optional[np.ndarray] = None
         # read plane (ISSUE 20): attach_read_plane() hangs a pump here;
         # _after_flush pokes it so observer windows are carved at
         # device-flush pace (encode-once fanout, server/read_plane.py)
@@ -833,6 +837,19 @@ class ServingEngineBase:
         if self._row_doc_id[row] is None:
             self._row_doc_id[row] = doc_id
             self._row_part[row] = partition_of(doc_id, self.log.n_partitions)
+            if self._row_floor is not None:
+                self._row_floor[row] = self._min_seq.get(doc_id, 0)
+
+    def _set_min_seq(self, doc_id: str, min_seq: int) -> None:
+        """Record ``doc_id``'s MSN floor, in the dict and, where the
+        engine keeps one and the doc holds a flat row, in ``_row_floor``:
+        ``_row_floor[r] == _min_seq.get(doc, 0)`` for every flat row,
+        0 on every free one."""
+        self._min_seq[doc_id] = min_seq
+        if self._row_floor is not None:
+            row = self._doc_rows.get(doc_id)
+            if row is not None:
+                self._row_floor[row] = min_seq
 
     def _fill_row_handles(self, rows: np.ndarray, raw) -> None:
         if (self._row_handle[rows] < 0).any():
@@ -1050,7 +1067,7 @@ class ServingEngineBase:
             self._dedup.record(doc_id, client_id, client_seq, msg.seq)
             self._record_attribution(msg)
             self._enqueue(doc_id, msg)
-            self._min_seq[doc_id] = msg.min_seq
+            self._set_min_seq(doc_id, msg.min_seq)
             if self._queued() >= self.batch_window:
                 self.flush()
         return msg, None
@@ -1259,6 +1276,10 @@ class ServingEngineBase:
         self._free_rows = [r for r in range(self._next_row)
                            if r not in used]
         self._min_seq = dict(summary["min_seq"])
+        if self._row_floor is not None:
+            self._row_floor[:] = 0
+            for doc_id, row in self._doc_rows.items():
+                self._row_floor[row] = self._min_seq.get(doc_id, 0)
         # resilience state (absent from pre-resilience summaries): a
         # delta chain carries the full ledger/roster only in its base
         # full summary plus an O(changed) slice per delta — resolve
@@ -1344,8 +1365,8 @@ class ServingEngineBase:
                 continue
             if msg.type == MessageType.OP:
                 self._enqueue(msg.doc_id, msg)
-                self._min_seq[msg.doc_id] = max(
-                    self._min_seq.get(msg.doc_id, 0), msg.min_seq)
+                self._set_min_seq(msg.doc_id, max(
+                    self._min_seq.get(msg.doc_id, 0), msg.min_seq))
         self._queue.sort(key=lambda dm: dm[1].seq)
 
     def _absorb_resilience(self, msg: SequencedDocumentMessage) -> None:
@@ -1407,6 +1428,7 @@ class StringServingEngine(ServingEngineBase):
         super().__init__(batch_window, n_partitions, compact_every, log,
                          sequencer=sequencer)
         self._init_row_caches(n_docs)
+        self._row_floor = np.zeros(n_docs, np.int32)
         if store is not None and mesh is not None \
                 and getattr(store, "mesh", None) is not mesh:
             raise ValueError("mesh given with a store that is not sharded "
@@ -1558,7 +1580,7 @@ class StringServingEngine(ServingEngineBase):
         msg, _ = self.deli.sequence(
             doc_id, client_id, 0, ref_seq, MessageType.NOOP, None)
         if msg is not None:
-            self._min_seq[doc_id] = msg.min_seq
+            self._set_min_seq(doc_id, msg.min_seq)
             # a heartbeat-only MSN advance must still slide interval anchors
             # at the crossing (the op stream won't carry this advance).
             # Only docs that already hold a row can have intervals — looking
@@ -1746,27 +1768,22 @@ class StringServingEngine(ServingEngineBase):
             w.seq_base = (np.max(np.where(valid_rs, w.seq_rs, 0), axis=1)
                           - w.n_valid).astype(np.int32)
             # window-floor tracking for zamboni: fold this batch's MSN advance
-            # in BEFORE building the fused compaction floor, so a compaction-due
+            # in BEFORE taking the fused compaction floor, so a compaction-due
             # batch zambonis at the post-batch floor (not one batch stale)
             w.min_rs = out_min.reshape(R, O)
             last_min = w.min_rs[:, -1]
             # C-level dict bulk update (zip over plain-int lists), not a
-            # 10k-iteration Python loop with an int() per row
+            # 10k-iteration Python loop with an int() per row; the row
+            # array takes the same floors in one scatter
             rdi = self._row_doc_id
             self._min_seq.update(zip((rdi[r] for r in w.rows.tolist()),
                                      last_min.tolist()))
+            self._row_floor[w.rows] = last_min
             w.compact_due = \
                 self._flushes_since_compact + 1 >= self.compact_every
-            w.ms_arr = None
-            if w.compact_due:
-                ms_arr = np.zeros((self.n_docs,), np.int32)
-                dr = self._doc_rows
-                if dr:
-                    g = self._min_seq.get
-                    ms_arr[np.fromiter(dr.values(), np.int32, count=len(dr))] \
-                        = np.fromiter((g(d, 0) for d in dr), np.int64,
-                                      count=len(dr))
-                w.ms_arr = ms_arr
+            # a copy: later windows write the array while this one's
+            # merge may still be reading its floor
+            w.ms_arr = self._row_floor.copy() if w.compact_due else None
         # the native call; the plane math around it counts as prep
         w.seq_ms = sp_deli.ms
         w.prep_ms += sp.ms - sp_deli.ms
@@ -1798,6 +1815,7 @@ class StringServingEngine(ServingEngineBase):
             if w.compact_due:
                 self._flushes_since_compact = 0
                 self.metrics.inc("compactions")
+                self.metrics.inc("compaction_floors_from_rows")
                 if self.mega_store is not None and self._mega_rows:
                     mms = np.zeros((self.mega_store.n_docs,), np.int32)
                     for doc_id, row in self._mega_rows.items():
@@ -1961,10 +1979,8 @@ class StringServingEngine(ServingEngineBase):
     def compact(self) -> None:
         """Zamboni at each doc's MSN (collaboration-window floor); checks
         overflow flags and runs recovery on the same cadence."""
-        min_seq = np.zeros((self.n_docs,), np.int32)
-        for doc_id, row in self._doc_rows.items():
-            min_seq[row] = self._min_seq.get(doc_id, 0)
-        self.store.compact(min_seq)
+        self.store.compact(self._row_floor.copy())
+        self.metrics.inc("compaction_floors_from_rows")
         if self.mega_store is not None and self._mega_rows:
             ms = np.zeros((self.mega_store.n_docs,), np.int32)
             for doc_id, row in self._mega_rows.items():
@@ -2181,6 +2197,7 @@ class StringServingEngine(ServingEngineBase):
         self._free_rows.append(row)
         self._row_doc_id[row] = None
         self._row_handle[row] = -1
+        self._row_floor[row] = 0
 
     @staticmethod
     def _readd_intervals(store, row: int, ivs: dict) -> None:
